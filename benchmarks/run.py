@@ -25,8 +25,7 @@ CPU-only host it sets ``XLA_FLAGS=--xla_force_host_platform_device_count=N``
 for you (all imports of jax are deferred until after the flag is in
 place, so one plain invocation measures real multi-core scaling). The
 run also asserts that no buffer-donation ("aliasing") warnings escaped
-the jitted fast paths — donation is platform-gated in ``repro.compat``
-and must stay silent on hosts without it. ``--kernel pallas`` adds a
+the jitted fast paths, which donate nothing. ``--kernel pallas`` adds a
 ``rounds_pallas`` column: the fused round-step backend
 (``repro.kernels.round_step``, interpret mode off-TPU) timed with
 separated ``compile_s``/``run_s`` walls and held to the same rounds
@@ -97,6 +96,14 @@ def _derived(name, rows):
     except Exception as e:              # pragma: no cover
         return f"derived_error:{type(e).__name__}"
     return f"rows={len(rows)}"
+
+
+def _enable_compile_cache() -> None:
+    """Turn on the persistent compilation cache for this run. Each
+    subcommand calls it after any host-device forcing: importing jax
+    before ``force_host_device_count`` would defeat the flag."""
+    from repro.compat import enable_compile_cache
+    enable_compile_cache()
 
 
 def rounds_contract_ok(rounds_fidelity: dict, donation_warnings,
@@ -232,8 +239,8 @@ def sweep_benchmark(tiny: bool = False, devices: int = 0,
     from repro.sim.rounds import COALESCE_BATCH
     coalesce_opts = ScanOptions(coalesce=COALESCE_BATCH)
 
-    # Any donation ("aliasing") warning from the jitted fast paths means
-    # the compat platform gate failed — record them and gate below.
+    # Any donation ("aliasing") warning from the jitted fast paths is a
+    # regression (they donate nothing) — record them and gate below.
     pallas_opts = (ScanOptions(kernel="pallas") if kernel == "pallas"
                    else None)
 
@@ -526,6 +533,7 @@ def run_sweep_bench(argv) -> int:
     if args.devices >= 2:
         from repro.hostdev import force_host_device_count
         force_host_device_count(args.devices)
+    _enable_compile_cache()
     out = sweep_benchmark(tiny=args.tiny, devices=args.devices,
                           kernel=args.kernel)
     os.makedirs(os.path.dirname(args.out) or ".", exist_ok=True)
@@ -783,6 +791,7 @@ def run_scenarios_bench(argv) -> int:
     if args.devices >= 2:
         from repro.hostdev import force_host_device_count
         force_host_device_count(args.devices)
+    _enable_compile_cache()
     out = scenarios_benchmark(widths=tuple(args.widths), tiny=args.tiny,
                               devices=args.devices, sample_n=args.sample)
     os.makedirs(os.path.dirname(args.out) or ".", exist_ok=True)
@@ -893,6 +902,7 @@ def run_roundstep_bench(argv) -> int:
                     help="vmapped lane counts to time")
     ap.add_argument("--out", default="results/BENCH_roundstep.json")
     args = ap.parse_args(argv)
+    _enable_compile_cache()
     out = roundstep_benchmark(lane_widths=tuple(args.lanes))
     os.makedirs(os.path.dirname(args.out) or ".", exist_ok=True)
     with open(args.out, "w") as fjson:
@@ -992,6 +1002,7 @@ def run_live_bench(argv) -> int:
                     "CONTRACTS['live']")
     ap.add_argument("--out", default="results/BENCH_live.json")
     args = ap.parse_args(argv)
+    _enable_compile_cache()
     out = live_benchmark(tiny=args.tiny, serve_dt=args.serve_dt)
     os.makedirs(os.path.dirname(args.out) or ".", exist_ok=True)
     with open(args.out, "w") as f:
@@ -1167,6 +1178,7 @@ def run_faults_bench(argv) -> int:
                     "lost job, or live-vs-event ledger mismatch")
     ap.add_argument("--out", default="results/BENCH_faults.json")
     args = ap.parse_args(argv)
+    _enable_compile_cache()
     out = faults_benchmark(tiny=args.tiny, serve_dt=args.serve_dt)
     os.makedirs(os.path.dirname(args.out) or ".", exist_ok=True)
     with open(args.out, "w") as f:
@@ -1416,6 +1428,7 @@ def run_capacity_bench(argv) -> int:
     if args.devices >= 2:
         from repro.hostdev import force_host_device_count
         force_host_device_count(args.devices)
+    _enable_compile_cache()
     out = capacity_benchmark(tiny=args.tiny, devices=args.devices)
     os.makedirs(os.path.dirname(args.out) or ".", exist_ok=True)
     with open(args.out, "w") as f:
@@ -1480,6 +1493,7 @@ def main() -> int:
     atomically (tmp + rename) and a write failure is also nonzero.
     """
     # Deferred so `sweep --devices N` can set XLA_FLAGS first.
+    _enable_compile_cache()
     from benchmarks.tables import ALL_TABLES
     from benchmarks import roofline
     os.makedirs("results", exist_ok=True)

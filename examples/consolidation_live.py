@@ -11,6 +11,10 @@ Run:  PYTHONPATH=src python examples/consolidation_live.py
 import os, sys, tempfile
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
+from repro.compat import enable_compile_cache
+
+enable_compile_cache()
+
 from repro.core.runtime_bridge import LiveCloud
 from repro.launch.mesh import make_local_mesh
 

@@ -9,6 +9,10 @@ Run:  PYTHONPATH=src python examples/serve_lm.py
 import os, sys
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
+from repro.compat import enable_compile_cache
+
+enable_compile_cache()
+
 import numpy as np
 from repro.configs.base import get_config, reduced_config
 from repro.launch.mesh import make_local_mesh

@@ -5,7 +5,7 @@ policy (Fig. 13, the ~40 % configuration-size headline), coordinated
 pool size B for FLB-NUB (Fig. 14), and the lease unit L for both
 PhoenixCloud and EC2+RightScale (Fig. 18) — through
 ``repro.sim.sweep.run_sweep``. DCS and EC2 points are evaluated on the
-exact vectorized jnp fast path in every mode; ``--mode`` picks how the
+exact vectorized host fast path in every mode; ``--mode`` picks how the
 stateful PhoenixCloud policies run:
 
   auto   (default) FB / FLB-NUB on the event-round engine — same as
@@ -55,6 +55,10 @@ if args.devices >= 2:
         ap.error("--devices requires a batched mode (auto, scan, rounds)")
     from repro.hostdev import force_host_device_count
     force_host_device_count(args.devices)
+
+from repro.compat import enable_compile_cache
+
+enable_compile_cache()
 
 import numpy as np
 

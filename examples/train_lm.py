@@ -10,6 +10,10 @@ Run:  PYTHONPATH=src python examples/train_lm.py [--full] [--steps N]
 import argparse, os, sys
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
+from repro.compat import enable_compile_cache
+
+enable_compile_cache()
+
 from repro.configs.base import get_config, reduced_config
 from repro.launch.mesh import make_local_mesh
 from repro.train.trainer import TrainJob, TrainJobConfig

@@ -1,84 +1,41 @@
-"""Version-compat shims for the JAX API surface we use.
-
-``shard_map`` moved twice across JAX releases: it lives at
-``jax.experimental.shard_map.shard_map`` (with a ``check_rep`` kwarg)
-up to ~0.4.x and graduates to ``jax.shard_map`` (kwarg renamed
-``check_vma``) in newer releases. Import it from here so model and test
-code runs on both.
-
-``jit`` here additionally normalizes *buffer donation*: XLA only
-implements input-output aliasing on some backends, and donating on the
-others (plain CPU most notably) makes every jitted call emit a
-"donated buffers were not usable" warning. The shim keeps
-``donate_argnums`` on backends that honor it and silently drops it
-elsewhere, so callers can donate their large carry/lane buffers
-unconditionally.
+"""Small helpers around the JAX API surface the engines share: the
+packing dtype under the x64 switch, the ``devices`` option of the
+sharded entry points, and the persistent compilation cache that entry
+points turn on before their first compile.
 """
 
 from __future__ import annotations
 
-import functools
+import os
 from typing import List, Optional, Sequence, Union
 
 import jax
 
-try:
-    _shard_map = jax.shard_map            # jax >= 0.6 top-level API
-    _CHECK_KWARG = "check_vma"
-except AttributeError:
-    from jax.experimental.shard_map import shard_map as _shard_map
-    _CHECK_KWARG = "check_rep"
+__all__ = ["resolve_devices", "resolve_pack_dtype", "enable_compile_cache",
+           "COMPILE_CACHE_DIR"]
 
-__all__ = ["shard_map", "axis_size", "resolve_devices", "jit",
-           "supports_donation", "resolve_pack_dtype"]
-
-# Backends with working input-output aliasing. XLA:CPU parses the
-# aliasing hint but does not consume it — every donated call would warn
-# and nothing would be saved — so donation is gated to these platforms.
-_DONATING_PLATFORMS = ("gpu", "tpu", "cuda", "rocm")
+# The fixed in-checkout cache path used when JAX_COMPILATION_CACHE_DIR is
+# not set. The path is part of the cache key, so it never varies by run.
+COMPILE_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_cache")
 
 
-def supports_donation(platform: Optional[str] = None) -> bool:
-    """True when ``donate_argnums`` buys in-place reuse on ``platform``
-    (default: the default jax backend) instead of a warning per call."""
-    if platform is None:
-        platform = jax.default_backend()
-    return platform.lower() in _DONATING_PLATFORMS
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache for an entry point and
+    return its directory. Call before the first compile; library import
+    never calls it.
 
-
-def jit(fn=None, *, donate_argnums=(), platform: Optional[str] = None,
-        **kwargs):
-    """``jax.jit`` with ``donate_argnums`` dropped on backends that do
-    not implement buffer donation (see module docstring). All other
-    keyword arguments pass through; usable as a decorator or a call.
-
-    The backend probe is deferred to the first call: module-level
-    decoration must not initialize the XLA backend, or merely importing
-    a module would freeze the host device count before
-    ``XLA_FLAGS=--xla_force_host_platform_device_count`` (the
-    ``repro.hostdev`` flow) can take effect.
-
-    ``platform`` overrides the backend probe (tests use it to pin the
-    gate's behavior without a real accelerator).
+    When ``JAX_COMPILATION_CACHE_DIR`` is set, JAX already reads it and
+    nothing is set here. Otherwise the cache lives at
+    :data:`COMPILE_CACHE_DIR` (``<checkout>/.jax_cache``, gitignored).
     """
-    if fn is None:
-        return lambda f: jit(f, donate_argnums=donate_argnums,
-                             platform=platform, **kwargs)
-    if not donate_argnums:
-        return jax.jit(fn, **kwargs)
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    jax.config.update("jax_compilation_cache_dir", COMPILE_CACHE_DIR)
+    return COMPILE_CACHE_DIR
 
-    jitted: List = []
-
-    @functools.wraps(fn)
-    def wrapper(*args, **kw):
-        if not jitted:
-            jit_kwargs = dict(kwargs)
-            if supports_donation(platform):
-                jit_kwargs["donate_argnums"] = donate_argnums
-            jitted.append(jax.jit(fn, **jit_kwargs))
-        return jitted[0](*args, **kw)
-
-    return wrapper
 
 def resolve_pack_dtype(dtype=None):
     """Default a packing dtype to the active jax x64 setting; reject a
@@ -93,7 +50,7 @@ def resolve_pack_dtype(dtype=None):
         raise ValueError(
             "dtype=float64 requested with jax x64 disabled — jnp.asarray "
             "would silently downcast to float32; wrap the call in "
-            "jax.experimental.enable_x64()")
+            "`with jax.enable_x64(True):`")
     return np.dtype(dtype)
 
 
@@ -130,22 +87,3 @@ def resolve_devices(devices: Devices) -> Optional[List["jax.Device"]]:
     else:
         devs = list(devices)
     return devs if len(devs) > 1 else None
-
-
-def axis_size(axis: str) -> int:
-    """Static size of a named mesh axis, from inside ``shard_map``.
-
-    ``jax.lax.axis_size`` only exists in newer releases; on older ones
-    ``psum(1, axis)`` of a Python constant folds to a static int.
-    """
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis)
-    return jax.lax.psum(1, axis)
-
-
-def shard_map(f, mesh, in_specs, out_specs, check_vma=None):
-    """``jax.shard_map`` with the replication-check kwarg normalized to
-    the new-API name (``check_vma``); ``None`` keeps the default."""
-    kwargs = {} if check_vma is None else {_CHECK_KWARG: check_vma}
-    return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, **kwargs)

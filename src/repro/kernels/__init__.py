@@ -20,7 +20,9 @@ program:
   ``ScanOptions(kernel="pallas")``. No separate reference module: the
   kernel body calls the engine's own ``_chunk_core``, so the unfused
   engine IS the reference (``round_step.chunk_step_ref``), bit-identical
-  rows by construction (tests/test_round_step_kernel.py).
+  rows by construction (tests/test_round_step_kernel.py). The exception
+  to the compiled-on-TPU rule: Mosaic cannot lower this body, so on a
+  TPU it raises ``NotImplementedError`` (tests/test_tpu_compile.py).
 
 Add further kernels ONLY for hot-spots the paper itself optimizes.
 """

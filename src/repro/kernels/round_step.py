@@ -36,9 +36,18 @@ start / end per lane. Inputs per lane: ``jobs`` (3, Jp) job table,
 tables, ``prm`` policy scalars ((2,) fb: lease, C; (6,) flb_nub: lease,
 B, lb_ws, U, V, G).
 
-``interpret`` defaults to True off-TPU (validation mode, the only mode
-CI exercises) and False on TPU — the target regime, where the fused
-program runs from VMEM without per-op dispatch.
+Mosaic does not lower the kernel
+-------------------------------
+Compiled for a TPU (``interpret=False``), the body is refused: it is
+``_chunk_core`` unchanged, and Pallas TPU has no lowering rule for the
+``cumsum``, ``dynamic_slice`` and ``rev`` it uses (prefix sums,
+admission slices, reversed class sums) nor for ``searchsorted``'s
+``le_to`` (window compaction), on top of gathers over 1-D lane vectors.
+A port means rewriting the round math for Mosaic's 2-D tiles, a second
+implementation beside the XLA one. So the kernel runs in interpret mode
+only: ``interpret`` defaults to True off-TPU, and on a TPU the compiled
+kernel raises :class:`NotImplementedError` instead of interpreting
+silently — use ``kernel="xla"`` there.
 """
 
 from __future__ import annotations
@@ -158,10 +167,18 @@ def chunk_step(jobs, rises, wstab, prm, sc, win, *, policy: str,
                ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """One fused outer step: compaction + admission + size classes +
     ``spec.compact_every`` rounds, as a single ``pallas_call``. Under
-    vmap the lane axis becomes the Pallas grid."""
+    vmap the lane axis becomes the Pallas grid. The compiled kernel
+    (``interpret=False``, the default on a TPU) is refused, see the
+    module docstring."""
     if interpret is None:
         from repro.kernels.ops import _default_interpret
         interpret = _default_interpret()
+    if not interpret:
+        raise NotImplementedError(
+            "the fused Pallas round step does not compile for TPU: Mosaic "
+            "has no lowering for cumsum, dynamic_slice, rev and "
+            "searchsorted (le_to) in rounds._chunk_core; run the rounds "
+            "engine with kernel=\"xla\"")
     return pl.pallas_call(
         _chunk_kernel(policy, spec),
         out_shape=[jax.ShapeDtypeStruct(sc.shape, sc.dtype),

@@ -1,7 +1,7 @@
 """Gated MLP and Mixture-of-Experts layers.
 
 The MoE uses capacity-based top-k routing with an explicit
-``shard_map`` dispatch (version-guarded via ``repro.compat``): tokens
+``jax.shard_map`` dispatch: tokens
 are routed *locally per data shard*
 (scatter into an (E, C, d) buffer), expert FFNs run with d_ff
 tensor-parallel over the 'model' axis, and the partial outputs are
@@ -24,7 +24,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.configs.base import ArchConfig
 from repro.models.common import AxisSizes, KeyGen, normal_init, shard
 
@@ -135,7 +134,7 @@ def moe_mlp(p: Dict, x: jax.Array, cfg: ArchConfig, ax: AxisSizes,
         P(None, "model", None) if f_sharded else P(None, None, None),
     )
     fn = functools.partial(_moe_local, cfg=cfg, model_sharded=f_sharded)
-    out = shard_map(
+    out = jax.shard_map(
         fn, mesh=mesh, in_specs=in_specs, out_specs=P(batch, None),
         check_vma=False,
     )(xf, p["router"], p["w1"], p["w3"], p["w2"])

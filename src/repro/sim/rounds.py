@@ -204,9 +204,10 @@ class RoundsSpec:
     the outer-loop body as plain traced jnp ops; ``"pallas"`` fuses the
     whole body — compaction, admission, size classes and the unrolled
     ``compact_every`` rounds — into one Pallas kernel per lane
-    (``repro.kernels.round_step``), with interpret mode auto-selected
-    off-TPU. Both backends execute the SAME ``_chunk_core`` math, so
-    their rows are bit-identical (tests/test_round_step_kernel.py).
+    (``repro.kernels.round_step``), in interpret mode; Mosaic does not
+    lower it, so on a TPU it raises ``NotImplementedError``. Both
+    backends execute the SAME ``_chunk_core`` math, so their rows are
+    bit-identical (tests/test_round_step_kernel.py).
     The field is part of the spec hash, so the jit caches key on
     ``(policy, spec-incl-kernel)`` and switching backends never reuses
     a stale compiled program."""
@@ -248,8 +249,8 @@ class PackedEventWorkloads:
     n_jobs: jnp.ndarray       # (W,) real (unpadded) job counts
     # Chaos tier (repro.sim.faults), FB only. None (the default) leaves
     # the pack structurally identical to the pre-fault format: a None
-    # data field flattens to an empty pytree, so vmap axes, buffer
-    # donation and every existing construction site are untouched.
+    # data field flattens to an empty pytree, so vmap axes and every
+    # existing construction site are untouched.
     fault_times: Optional[jnp.ndarray] = None   # (W, NF) stop times, +inf
     fault_failed: Optional[jnp.ndarray] = None  # (W, NF) failed count
     #                                             in effect AFTER each stop
@@ -715,7 +716,12 @@ def _round_body(policy: str, ctx: Dict, spec: RoundsSpec, carry, szcls):
                    axis=-1)                      # one packed reduction
     next_sub = jnp.minimum(mins[0],
                            jnp.where(row_sub > t, row_sub, inf))
+    # The next lease boundary k·L strictly after t, with (k-1)·L <= t.
+    # A division that is not correctly rounded (TPU f32) can put
+    # floor(t / L) one off at a boundary; the exact products correct it.
     k_next = jnp.floor(t / L) + 1.0
+    k_next = jnp.where(k_next * L <= t, k_next + 1.0, k_next)
+    k_next = jnp.where((k_next - 1.0) * L > t, k_next - 1.0, k_next)
     t_tick = k_next * L
     b0 = jnp.minimum(t_tick,
                      jnp.minimum(jnp.where(row_sub > t, row_sub, inf),
@@ -858,13 +864,16 @@ def _round_body(policy: str, ctx: Dict, spec: RoundsSpec, carry, szcls):
         leap = jnp.min(jnp.where(jnp.any(fits, axis=0), tau_v, inf))
         # ...and at each arrival instant: net freed mass before the
         # arrival, ignoring arrival-triggered consumption (an
-        # overestimate), one (K,k) @ (k,) contraction.
+        # overestimate). An elementwise masked sum, not a (K,k) @ (k,)
+        # matmul: a TPU matmul at default precision rounds its inputs
+        # to bf16, inexact for node counts above 256.
         net = jnp.concatenate([freedcum[:1],
                                jnp.diff(freedcum)]) \
             - jnp.concatenate([started_by[:1],
                                jnp.diff(started_by)])
-        free_arr = free0 + (tau_v[None, :]
-                            < w_sub[:, None]).astype(f) @ net
+        free_arr = free0 + jnp.sum(
+            jnp.where(tau_v[None, :] < w_sub[:, None], net[None, :], zero),
+            axis=-1)
         arr_leap = pend & (w_sub > t) & (start_at > w_sub) \
             & (w_sz <= free_arr)
         leap = jnp.minimum(leap, jnp.min(jnp.where(arr_leap, w_sub,
@@ -1166,17 +1175,16 @@ def _rounds_lane(policy: str, spec: RoundsSpec):
     return lane
 
 
-@functools.partial(compat.jit, static_argnames=("fb_spec", "flb_spec"),
-                   donate_argnums=(2, 3))
+@functools.partial(jax.jit, static_argnames=("fb_spec", "flb_spec"))
 def _rounds_grids_single(fb: Optional[FBGrid], flb: Optional[FLBGrid],
                          fb_packed: Optional[PackedEventWorkloads],
                          flb_packed: Optional[PackedEventWorkloads], *,
                          fb_spec: Optional[RoundsSpec] = None,
                          flb_spec: Optional[RoundsSpec] = None
                          ) -> Dict[str, Dict[str, jnp.ndarray]]:
-    """Single-device execution: the (trace, point) grid as nested vmaps,
-    with the packed event buffers donated where the backend supports it
-    (``repro.compat.jit``) — callers repack per invocation."""
+    """Single-device execution: the (trace, point) grid as nested vmaps.
+    Nothing is donated: the outputs are per-lane metrics, so no packed
+    buffer could be reused for them."""
     def run(policy, prm_tree, packed, spec):
         lane = _rounds_lane(policy, spec)
         over_points = jax.vmap(lane, in_axes=(0, None))
@@ -1209,11 +1217,7 @@ def rounds_grids(fb: Optional[FBGrid], flb: Optional[FLBGrid],
     ``None`` / one device runs the nested-vmap program, two or more
     shard the flattened (trace × point) lanes via the shared
     ``sharded_grid_map`` — bit-identical rows either way, since every
-    lane runs the identical per-lane program. On backends with buffer
-    donation (GPU/TPU — ``repro.compat.jit``) the packed event buffers
-    are DONATED: re-pack per call rather than reusing one
-    ``PackedEventWorkloads`` across calls (on CPU donation is dropped
-    and reuse is safe).
+    lane runs the identical per-lane program.
     """
     devs = compat.resolve_devices(devices)
     if devs is None:

@@ -83,7 +83,6 @@ import numpy as np
 from jax.sharding import Mesh, PartitionSpec
 
 from repro import compat
-from repro.compat import shard_map
 from repro.core.jobs import Job
 from repro.core.pbj_manager import PBJPolicyParams
 from repro.core.profiles import sample_steps, step_points
@@ -288,9 +287,14 @@ def _size_classes(size):
     first). Returns ``(cls, class_masks)`` where ``class_masks`` is the
     ``(_KILL_CLASSES, K)`` membership mask — the per-class sums reduce
     over a masked stack, which XLA:CPU executes an order of magnitude
-    faster inside a loop body than the equivalent (K, C) matmul."""
-    cls = jnp.clip(jnp.ceil(jnp.log2(jnp.maximum(size, 1.0))),
-                   0, _KILL_CLASSES - 1).astype(jnp.int32)
+    faster inside a loop body than the equivalent (K, C) matmul.
+
+    The class is ``ceil(log2(size))`` clipped to the class range, counted
+    by exact comparisons with the powers of two: a transcendental
+    ``log2`` is not exact at powers of two on every backend (TPU), and a
+    class off by one reorders the kills."""
+    cls = sum((size > float(2 ** c)).astype(jnp.int32)
+              for c in range(_KILL_CLASSES - 1))
     class_masks = cls[None, :] == jnp.arange(_KILL_CLASSES)[:, None]
     return cls, class_masks
 
@@ -587,20 +591,14 @@ def _scan_lane(policy: str, spec: ScanSpec):
     return lane
 
 
-@functools.partial(compat.jit, static_argnames=("fb_spec", "flb_spec"),
-                   donate_argnums=(2, 3))
+@functools.partial(jax.jit, static_argnames=("fb_spec", "flb_spec"))
 def _scan_grids_single(fb: Optional[FBGrid], flb: Optional[FLBGrid],
                        fb_packed: Optional[PackedWorkloads],
                        flb_packed: Optional[PackedWorkloads], *,
                        fb_spec: Optional[ScanSpec] = None,
                        flb_spec: Optional[ScanSpec] = None
                        ) -> Dict[str, Dict[str, jnp.ndarray]]:
-    """Single-device execution: the (trace, point) grid as nested vmaps.
-
-    The packed-workload buffers are donated (on backends with buffer
-    donation — ``repro.compat.jit``) so a large (point × trace) grid
-    never holds the lane tables twice; callers repack per invocation.
-    """
+    """Single-device execution: the (trace, point) grid as nested vmaps."""
     def run(policy, prm_tree, packed, spec):
         lane = _scan_lane(policy, spec)
         over_points = jax.vmap(lane, in_axes=(0, None))
@@ -623,8 +621,7 @@ def _prm_tree(policy: str, grid) -> Dict[str, jnp.ndarray]:
             "G": grid.G, "lease": grid.lease}
 
 
-@functools.partial(compat.jit, static_argnames=("lane_fn", "mesh"),
-                   donate_argnums=(1,))
+@functools.partial(jax.jit, static_argnames=("lane_fn", "mesh"))
 def _sharded_lanes(prm_tree, packed, w_idx, p_idx, *, lane_fn, mesh):
     """Flattened (trace, point) lanes split across ``mesh``, for any
     per-lane program ``lane_fn(prm, packed_row) -> metrics``.
@@ -633,8 +630,7 @@ def _sharded_lanes(prm_tree, packed, w_idx, p_idx, *, lane_fn, mesh):
     point; they are sharded over the mesh's ``lanes`` axis while the
     grid and the packed workloads stay replicated, so each device
     gathers just its own lane slice and runs the plain vmapped program
-    on it — no collectives, the lanes are embarrassingly parallel. The
-    packed buffers are donated where the backend supports it.
+    on it — no collectives, the lanes are embarrassingly parallel.
     """
     def lanes(w_l, p_l, prm, pk):
         prm_l = jax.tree_util.tree_map(lambda a: a[p_l], prm)
@@ -643,8 +639,8 @@ def _sharded_lanes(prm_tree, packed, w_idx, p_idx, *, lane_fn, mesh):
 
     lane = PartitionSpec("lanes")
     rep = PartitionSpec()
-    fn = shard_map(lanes, mesh, in_specs=(lane, lane, rep, rep),
-                   out_specs=lane, check_vma=False)
+    fn = jax.shard_map(lanes, mesh=mesh, in_specs=(lane, lane, rep, rep),
+                       out_specs=lane, check_vma=False)
     return fn(w_idx, p_idx, prm_tree, packed)
 
 
@@ -723,12 +719,6 @@ def scan_grids(fb: Optional[FBGrid], flb: Optional[FLBGrid],
     sharded path computes the identical per-lane program, only placed
     differently, so its rows are bit-identical to the single-device
     path's (tests/test_sweep_sharded.py pins this).
-
-    On backends with buffer donation (GPU/TPU — see ``repro.compat.jit``)
-    the packed-workload buffers are DONATED so large grids never hold
-    the lane tables twice: re-pack per call rather than reusing one
-    ``PackedWorkloads`` across calls. On CPU donation is dropped and
-    reuse is safe.
     """
     devs = compat.resolve_devices(devices)
     if devs is None:

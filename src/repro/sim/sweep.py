@@ -14,15 +14,14 @@ Four execution paths, selected by ``mode``:
     DCS is a static partition (its cost/peak curve is closed-form
     arithmetic over the grid) and the EC2 allocation curve is a pure
     function of (submit, runtime, L) evaluated for ALL sweep points at
-    once as batched ``jnp`` array ops (``jax.vmap``): the trace's WS
-    demand change points are extracted and integrated once
-    (``core.profiles``), job release ticks for every lease value are a
-    broadcasted rounding to lease boundaries, node-hours is the WS
-    integral plus each job's size·(release − submit) span, and peak
-    consumption is a cumulative-max over the merged, time-sorted event
-    deltas. The arithmetic runs in float64
-    (``jax.experimental.enable_x64``) so results agree with the event
-    engine to round-off — node-hours match to < 1e-9 relative and every
+    once as batched host numpy arrays: the trace's WS demand change
+    points are extracted and integrated once (``core.profiles``), job
+    release ticks for every lease value are a broadcasted rounding to
+    lease boundaries, node-hours is the WS integral plus each job's
+    size·(release − submit) span, and peak consumption is a
+    cumulative-max over the merged, time-sorted event deltas. The
+    arithmetic runs in float64 so results agree with the event engine
+    to round-off — node-hours match to < 1e-9 relative and every
     integer metric (peak nodes, completed jobs, adjust events) matches
     exactly (tests/test_sweep.py).
 
@@ -75,7 +74,6 @@ from typing import Dict, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental import enable_x64
 
 from repro import compat
 from repro.core.jobs import Job
@@ -162,9 +160,10 @@ class ScanOptions:
     default) dispatches the traced body op by op, ``"pallas"`` fuses the
     whole outer step — compaction, admission and the unrolled rounds —
     into one Pallas kernel per lane (``repro.kernels.round_step``;
-    interpret mode auto-selected off-TPU), bit-identical rows either
-    way. The scan path ignores it. ``devices`` selects the execution
-    backend (``repro.compat.resolve_devices``): ``None`` runs the whole
+    interpret mode only — refused on a TPU, where Mosaic does not lower
+    it), bit-identical rows either way. The scan path ignores it.
+    ``devices`` selects the execution backend
+    (``repro.compat.resolve_devices``): ``None`` runs the whole
     grid on one device, a count or device sequence shards the
     (point × trace) lanes across host devices via ``shard_map``."""
 
@@ -257,7 +256,8 @@ def _sweep_dcs(points: List[SweepPoint], duration: float) -> List[Dict]:
 def _sweep_ec2(points: List[SweepPoint], jobs: Sequence[Job],
                ws_trace: Sequence[Tuple[float, int]],
                duration: float) -> List[Dict]:
-    """All EC2+RightScale points (one per lease value) as batched jnp ops.
+    """All EC2+RightScale points (one per lease value) as batched float64
+    numpy arrays on the host.
 
     Per job j and lease L: the job allocates ``size_j`` on
     ``[submit_j, rel_j)`` where ``rel_j`` is the first lease tick
@@ -265,59 +265,57 @@ def _sweep_ec2(points: List[SweepPoint], jobs: Sequence[Job],
     engine's tick-before-finish tie order), clipped to the trace
     duration when the tick never fires. The WS curve replays the demand
     trace verbatim and is lease-independent.
+
+    The host is the one placement: the closed form is a few thousand
+    elements per lease, and XLA:TPU takes minutes to compile its
+    float64 sort for each trace shape (168 s for a v5e).
     """
     ws_t64, ws_v64 = step_points(ws_trace, duration)
     ws_node_seconds = step_integral(ws_t64, ws_v64, duration)
     ws_deltas64 = np.concatenate([ws_v64[:1], np.diff(ws_v64)])
     ws_adjusts = int(np.count_nonzero(ws_deltas64))
 
-    with enable_x64():
-        submit = jnp.asarray([j.submit for j in jobs], jnp.float64)
-        size = jnp.asarray([j.size for j in jobs], jnp.float64)
-        runtime = jnp.asarray([j.runtime for j in jobs], jnp.float64)
-        end = submit + runtime
-        in_trace = submit <= duration + 1e-9     # engine drops later submits
-        finishes = in_trace & (end <= duration + 1e-9)
+    submit = np.asarray([j.submit for j in jobs], np.float64)
+    size = np.asarray([j.size for j in jobs], np.float64)
+    runtime = np.asarray([j.runtime for j in jobs], np.float64)
+    end = submit + runtime
+    in_trace = submit <= duration + 1e-9         # engine drops later submits
+    finishes = in_trace & (end <= duration + 1e-9)
 
-        L = jnp.asarray([p.lease_seconds for p in points],
-                        jnp.float64)[:, None]                  # (P, 1)
-        # First tick strictly after the finish event (see module doc).
-        # A tick exists only while k·L <= duration — the engine's strict
-        # scheduling comparison, mirrored here without tolerance.
-        rel = (jnp.floor(end / L) + 1.0) * L                   # (P, J)
-        fired = in_trace & (rel <= duration)
-        rel_eff = jnp.where(fired, rel, duration)
-        pbj_ns = jnp.sum(jnp.where(in_trace, size * (rel_eff - submit), 0.0),
-                         axis=1)
-        node_hours = (pbj_ns + ws_node_seconds) / 3600.0
+    L = np.asarray([p.lease_seconds for p in points],
+                   np.float64)[:, None]                        # (P, 1)
+    # First tick strictly after the finish event (see module doc).
+    # A tick exists only while k·L <= duration — the engine's strict
+    # scheduling comparison, mirrored here without tolerance.
+    rel = (np.floor(end / L) + 1.0) * L                        # (P, J)
+    fired = in_trace & (rel <= duration)
+    rel_eff = np.where(fired, rel, duration)
+    pbj_ns = np.sum(np.where(in_trace, size * (rel_eff - submit), 0.0),
+                    axis=1)
+    node_hours = (pbj_ns + ws_node_seconds) / 3600.0
 
-        # Peak: merge WS steps, submits (+size) and releases (−size) and
-        # take the cumulative max of the running total. Tie order at one
-        # timestamp follows the engine's event kinds (releases happen
-        # inside tick events).
-        ws_t, ws_d = jnp.asarray(ws_t64), jnp.asarray(ws_deltas64)
-        n_ws, n_j = ws_t.shape[0], submit.shape[0]
-        ev_t = jnp.concatenate([ws_t, submit, jnp.zeros(n_j)])  # rel filled per point
-        ev_kind = jnp.concatenate([jnp.full(n_ws, float(_WS)),
-                                   jnp.full(n_j, float(_SUBMIT)),
-                                   jnp.full(n_j, float(_TICK))])
-        base_delta = jnp.concatenate(
-            [ws_d, jnp.where(in_trace, size, 0.0), jnp.zeros(n_j)])
+    # Peak: merge WS steps, submits (+size) and releases (−size) and
+    # take the cumulative max of the running total. Tie order at one
+    # timestamp follows the engine's event kinds (releases happen
+    # inside tick events).
+    P, n_ws, n_j = len(points), len(ws_t64), len(submit)
+    ev_t = np.concatenate([np.broadcast_to(ws_t64, (P, n_ws)),
+                           np.broadcast_to(submit, (P, n_j)), rel], axis=1)
+    ev_kind = np.broadcast_to(np.concatenate(
+        [np.full(n_ws, float(_WS)), np.full(n_j, float(_SUBMIT)),
+         np.full(n_j, float(_TICK))]), ev_t.shape)
+    delta = np.concatenate(
+        [np.broadcast_to(ws_deltas64, (P, n_ws)),
+         np.broadcast_to(np.where(in_trace, size, 0.0), (P, n_j)),
+         np.where(fired, -size, 0.0)], axis=1)
+    order = np.lexsort((ev_kind, ev_t), axis=-1)
+    running = np.cumsum(np.take_along_axis(delta, order, axis=1), axis=1)
+    peak = np.maximum(np.max(running, axis=1), 0.0)
 
-        def peak_one(rel_row, fired_row):
-            t = ev_t.at[n_ws + n_j:].set(rel_row)
-            delta = base_delta.at[n_ws + n_j:].set(
-                jnp.where(fired_row, -size, 0.0))
-            order = jnp.lexsort((ev_kind, t))
-            running = jnp.cumsum(delta[order])
-            return jnp.maximum(jnp.max(running), 0.0)
-
-        peak = jax.vmap(peak_one)(rel, fired)
-
-        completed = jnp.sum(finishes)
-        sum_rt = jnp.sum(jnp.where(finishes, runtime, 0.0))
-        n_released = jnp.sum(fired, axis=1)
-        n_submitted = jnp.sum(in_trace)
+    completed = np.sum(finishes)
+    sum_rt = np.sum(np.where(finishes, runtime, 0.0))
+    n_released = np.sum(fired, axis=1)
+    n_submitted = np.sum(in_trace)
 
     n_completed = int(completed)
     avg_rt = float(sum_rt) / n_completed if n_completed else 0.0

@@ -28,11 +28,6 @@ import jax
 import jax.numpy as jnp
 
 
-def _axis_size(axis: str) -> int:
-    from repro.compat import axis_size
-    return axis_size(axis)
-
-
 def quantize_int8(y: jax.Array, axis: str) -> Tuple[jax.Array, jax.Array]:
     """Symmetric int8 quantization with a shared (psum-max) scale."""
     amax = jnp.max(jnp.abs(y))
@@ -48,7 +43,7 @@ def ring_reduce_scatter_int8(q: jax.Array, axis: str) -> jax.Array:
     q: (K*C,) flat int8 on each of K shards → returns this shard's (C,)
     int32 reduced chunk. Wire traffic: (K-1)·C int8 bytes per shard.
     """
-    k = _axis_size(axis)
+    k = jax.lax.axis_size(axis)
     idx = jax.lax.axis_index(axis)
     if k == 1:
         return q.astype(jnp.int32)
@@ -76,7 +71,7 @@ def ring_all_gather(x: jax.Array, axis: str, shift: int = 0) -> jax.Array:
     chunk→shard mapping produced by ``ring_reduce_scatter_int8`` (shard s
     finishes holding chunk (s+1) mod K).
     """
-    k = _axis_size(axis)
+    k = jax.lax.axis_size(axis)
     if k == 1:
         return x[None]
     perm = [(i, (i + 1) % k) for i in range(k)]
@@ -98,7 +93,7 @@ def ef_allreduce_mean(g: jax.Array, err: jax.Array, axis: str
     Returns (mean_g, new_err). Shapes are preserved; the tensor is padded
     to a multiple of the axis size internally.
     """
-    k = _axis_size(axis)
+    k = jax.lax.axis_size(axis)
     shape = g.shape
     y = g.astype(jnp.float32) + err
     q, scale = quantize_int8(y, axis)

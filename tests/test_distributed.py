@@ -19,6 +19,7 @@ def _run8(code: str) -> str:
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     env["PYTHONPATH"] = os.path.join(ROOT, "src")
+    env["JAX_PLATFORMS"] = "cpu"       # children never touch a chip
     out = subprocess.run([sys.executable, "-c", textwrap.dedent(code)],
                          capture_output=True, text=True, env=env,
                          timeout=420)
@@ -30,12 +31,11 @@ def test_int8_ef_allreduce_matches_psum():
     out = _run8("""
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import Mesh, PartitionSpec as P
-        from repro.compat import shard_map
         from repro.train import compression as C
         mesh = Mesh(np.array(jax.devices()).reshape(8), ("dp",))
         g = jax.random.normal(jax.random.PRNGKey(0), (8, 4096)) * 2.0
         e = jnp.zeros_like(g)
-        fn = jax.jit(shard_map(
+        fn = jax.jit(jax.shard_map(
             lambda g, e: C.ef_allreduce_mean(g, e, "dp"),
             mesh=mesh, in_specs=(P("dp"), P("dp")),
             out_specs=(P("dp"), P("dp")), check_vma=False))

@@ -184,12 +184,12 @@ def test_differential_completion_times_bit_match_in_float64():
     trigger §5.1 kills, whose size-class tie-breaking is the one
     documented divergence from the engine's latest-start order (the
     same precondition as tests/test_rounds.py's exactness property)."""
-    from jax.experimental import enable_x64
+    import jax
 
     jobs, _ = scenario(97)
     ws = [(0.0, 3)]
     ev = run_sweep(POINTS, jobs, ws, DAY, mode="event")
-    with enable_x64():
+    with jax.enable_x64(True):
         for coalesce in (1, 8):
             rows = run_sweep(
                 POINTS, jobs, ws, DAY, mode="rounds",

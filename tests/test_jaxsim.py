@@ -29,14 +29,13 @@ def test_fidelity_vs_event_sim(setup):
 def test_pack_trace_dtype_follows_x64_setting(setup):
     """pack_trace defaults to the active x64 mode (the setting the sweep
     engine's exact paths run under), and takes an explicit dtype."""
+    import jax
     import jax.numpy as jnp
     import numpy as np
-    from jax.experimental import enable_x64
-
     jobs, ws = setup
     packed = jaxsim.pack_trace(jobs[:8], ws[:8], 7200.0, 3600.0)
     assert packed[0].dtype == jnp.float32
-    with enable_x64():
+    with jax.enable_x64(True):
         packed64 = jaxsim.pack_trace(jobs[:8], ws[:8], 7200.0, 3600.0)
         assert packed64[0].dtype == jnp.float64
         assert packed64[3].dtype == jnp.float64

@@ -213,9 +213,7 @@ def test_fused_step_equals_reference_vmapped(policy):
 def test_fused_step_bit_equals_reference_float64():
     """f64 lanes (the bit-match-vs-event precision) through the fused
     kernel — the pack dtype follows the lane dtype."""
-    from jax.experimental import enable_x64
-
-    with enable_x64():
+    with jax.enable_x64(True):
         pk, _, inputs, rng = _lane("fb")
         assert pk.submit.dtype == jnp.float64
         spec = _spec()

@@ -56,8 +56,6 @@ def test_rounds_completion_times_match_event_exactly(seed, system):
     vectorized first-fit provably converges to the engine's sequential
     scan on every round.)"""
     import jax
-    from jax.experimental import enable_x64
-
     jobs, ws = random_workload(seed)
     if system == "fb":
         point = SweepPoint("fb", capacity=12)
@@ -66,7 +64,7 @@ def test_rounds_completion_times_match_event_exactly(seed, system):
         point = SweepPoint("flb_nub", lb_pbj=6, lb_ws=4)
         ref_sys = build_flb_nub(6, 4)
     ref = run_sim(ref_sys, clone_jobs(jobs), ws, DAY)
-    with enable_x64():
+    with jax.enable_x64(True):
         row = rounds_row(point, jobs, ws, DAY, ff_passes=8,
                          dtype=np.float64)
     assert row["window_overflow"] == 0 and row["truncated"] == 0
@@ -286,24 +284,35 @@ def test_round_budget_scales_with_inputs():
     assert round_budget(100, 50, DAY, 900.0) > base   # more ticks
 
 
-def test_compat_jit_donation_gate():
-    """The donation shim: donate_argnums reaches jax.jit only on
-    backends with buffer donation; on others it is dropped so no
-    aliasing warning can fire (asserted for real in the bench run)."""
-    import jax.numpy as jnp
+def test_compile_cache_honours_env_dir(monkeypatch):
+    """With JAX_COMPILATION_CACHE_DIR set, the entry-point helper returns
+    it and sets no cache directory in code."""
+    import jax
     from repro import compat
 
-    assert compat.supports_donation("tpu")
-    assert compat.supports_donation("gpu")
-    assert not compat.supports_donation("cpu")
+    before = jax.config.jax_compilation_cache_dir
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/env/cache")
+    assert compat.enable_compile_cache() == "/env/cache"
+    assert jax.config.jax_compilation_cache_dir == before
 
-    calls = []
-    f = compat.jit(lambda x: x + 1, donate_argnums=(0,), platform="cpu")
-    out = f(jnp.zeros(3))
-    assert out.shape == (3,)
-    # On a donating platform the kwarg passes through - jax validates
-    # it, so a bad argnum raises.
-    with pytest.raises(Exception):
-        g = compat.jit(lambda x: x + 1, donate_argnums=(5,),
-                       platform="tpu")
-        g(jnp.zeros(3))
+
+def test_compile_cache_defaults_to_fixed_checkout_path(monkeypatch):
+    """Without the variable the cache sits at one fixed, gitignored path
+    inside the checkout — never a temp name, pid or time."""
+    import os
+
+    import jax
+    from repro import compat
+
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert compat.COMPILE_CACHE_DIR == os.path.join(root, ".jax_cache")
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        assert compat.enable_compile_cache() == compat.COMPILE_CACHE_DIR
+        assert (jax.config.jax_compilation_cache_dir
+                == compat.COMPILE_CACHE_DIR)
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+    with open(os.path.join(root, ".gitignore")) as f:
+        assert ".jax_cache/" in f.read().split()

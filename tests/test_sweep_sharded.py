@@ -43,6 +43,7 @@ def _run2(code: str) -> str:
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"
     env["PYTHONPATH"] = os.path.join(ROOT, "src")
+    env["JAX_PLATFORMS"] = "cpu"       # children never touch a chip
     out = subprocess.run(
         [sys.executable, "-c",
          textwrap.dedent(_DEVICE_PREAMBLE) + textwrap.dedent(code)],
@@ -61,6 +62,7 @@ def test_forced_device_count_is_asserted_inside_the_subprocess():
     env = dict(os.environ)
     env.pop("XLA_FLAGS", None)          # no forced devices
     env["PYTHONPATH"] = os.path.join(ROOT, "src")
+    env["JAX_PLATFORMS"] = "cpu"       # children never touch a chip
     out = subprocess.run(
         [sys.executable, "-c", textwrap.dedent(_DEVICE_PREAMBLE)],
         capture_output=True, text=True, env=env, timeout=420)
