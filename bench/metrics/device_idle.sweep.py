@@ -1,0 +1,7 @@
+"""Per cent of the traced sweep queries with no operation on the device."""
+
+from bench import readings
+
+
+def read(record):
+    return readings.idle_pct(record)
