@@ -1,0 +1,7 @@
+"""Seconds per capacity answer the host waits on device results (span sweep.wait)."""
+
+from bench import program_spans
+
+
+def read(record):
+    return program_spans.child_s(record, "capacity", "sweep.wait")

@@ -54,6 +54,7 @@ from typing import Dict, List, Optional, Sequence, Tuple, Union
 
 import numpy as np
 
+from repro import spans
 from repro.core.baselines import billable_requests
 from repro.core.jobs import Job
 from repro.sim.sweep import (ScanOptions, SweepPoint, run_sweep_workloads)
@@ -259,124 +260,125 @@ def min_capacity(templates: Union[SweepPoint, Sequence[SweepPoint]],
     satisfy: ``row`` feasible, and capacity−1 infeasible (unless at the
     grid edge).
     """
-    if isinstance(templates, SweepPoint):
-        templates = [templates]
-    templates = list(templates)
-    if not templates:
-        raise ValueError("min_capacity needs at least one template")
-    lo, hi = int(lo), int(hi)
-    if lo < 1:
-        raise ValueError(f"lo must be >= 1, got {lo}")
-    if hi < lo:
-        raise ValueError(f"empty capacity interval: hi={hi} < lo={lo}")
-    _validate_templates(templates, mode)
-    wls = _normalize_workloads(workloads)
-    n_jobs = [len(jobs) for jobs, _ in wls]
-    W, T = len(wls), len(templates)
+    with spans.span("capacity"):
+        if isinstance(templates, SweepPoint):
+            templates = [templates]
+        templates = list(templates)
+        if not templates:
+            raise ValueError("min_capacity needs at least one template")
+        lo, hi = int(lo), int(hi)
+        if lo < 1:
+            raise ValueError(f"lo must be >= 1, got {lo}")
+        if hi < lo:
+            raise ValueError(f"empty capacity interval: hi={hi} < lo={lo}")
+        _validate_templates(templates, mode)
+        wls = _normalize_workloads(workloads)
+        n_jobs = [len(jobs) for jobs, _ in wls]
+        W, T = len(wls), len(templates)
 
-    cache: Dict[Tuple[int, int], Dict] = {}   # (ti, c) -> rows per wl
-    ledger = {"batches": 0, "rows": 0}
+        cache: Dict[Tuple[int, int], Dict] = {}   # (ti, c) -> rows per wl
+        ledger = {"batches": 0, "rows": 0}
 
-    def evaluate(caps_by_t: Dict[int, set]):
-        """ONE sweep batch for all (template, capacity) pairs not yet
-        cached; rows land in ``cache`` keyed (ti, c) -> [row per
-        workload]."""
-        pts, index = [], []
-        for ti in sorted(caps_by_t):
-            for c in sorted(caps_by_t[ti]):
-                if (ti, c) not in cache:
-                    pts.append(_with_capacity(templates[ti], c))
-                    index.append((ti, c))
-        if not pts:
-            return
-        # 2 frames here (this closure + min_capacity itself), plus any
-        # wrappers above us — diagnostics name the user's call site.
-        rows = run_sweep_workloads(pts, wls, duration, mode=mode,
-                                   scan_options=scan_options,
-                                   devices=devices,
-                                   _stack_offset=2 + _stack_offset)
-        ledger["batches"] += 1
-        ledger["rows"] += len(pts) * W
-        for k, key in enumerate(index):
-            cache[key] = [rows[w][k] for w in range(W)]
+        def evaluate(caps_by_t: Dict[int, set]):
+            """ONE sweep batch for all (template, capacity) pairs not yet
+            cached; rows land in ``cache`` keyed (ti, c) -> [row per
+            workload]."""
+            pts, index = [], []
+            for ti in sorted(caps_by_t):
+                for c in sorted(caps_by_t[ti]):
+                    if (ti, c) not in cache:
+                        pts.append(_with_capacity(templates[ti], c))
+                        index.append((ti, c))
+            if not pts:
+                return
+            # 2 frames here (this closure + min_capacity itself), plus any
+            # wrappers above us — diagnostics name the user's call site.
+            rows = run_sweep_workloads(pts, wls, duration, mode=mode,
+                                       scan_options=scan_options,
+                                       devices=devices,
+                                       _stack_offset=2 + _stack_offset)
+            ledger["batches"] += 1
+            ledger["rows"] += len(pts) * W
+            for k, key in enumerate(index):
+                cache[key] = [rows[w][k] for w in range(W)]
 
-    def feasible(ti: int, wi: int, c: int) -> bool:
-        return slo.satisfied(cache[(ti, c)][wi], n_jobs[wi])
+        def feasible(ti: int, wi: int, c: int) -> bool:
+            return slo.satisfied(cache[(ti, c)][wi], n_jobs[wi])
 
-    # Bracket batch: lo and hi for every template, all lanes at once.
-    evaluate({ti: {lo, hi} for ti in range(T)})
+        # Bracket batch: lo and hi for every template, all lanes at once.
+        evaluate({ti: {lo, hi} for ti in range(T)})
 
-    infeasible_lanes = []
-    # Per-lane bisection state: None once converged, else
-    # (known_bad, known_good) with known_bad infeasible, known_good
-    # feasible, answer in (known_bad, known_good].
-    state: Dict[Tuple[int, int], Optional[Tuple[int, int]]] = {}
-    answer: Dict[Tuple[int, int], int] = {}
-    for ti in range(T):
-        for wi in range(W):
-            if not feasible(ti, wi, hi):
-                row = cache[(ti, hi)][wi]
-                got = row.get("completed_jobs")
-                peak = _ws_peak(wls[wi][1])
-                hint = (f"; note hi={hi} is below the WS trace peak "
-                        f"{peak} — the web lane saturates and no "
-                        f"capacity in the interval can meet the SLO"
-                        if hi < peak else "")
-                infeasible_lanes.append(
-                    f"{_with_capacity(templates[ti], hi).name()} × "
-                    f"workload {wi}: "
-                    f"completed {got} at capacity {hi}, SLO needs "
-                    f"{slo.describe(n_jobs[wi])}{hint}")
-            elif feasible(ti, wi, lo):
-                answer[(ti, wi)] = lo
-                state[(ti, wi)] = None
-            else:
-                state[(ti, wi)] = (lo, hi)
-    if infeasible_lanes:
-        raise ValueError(
-            "SLO infeasible at the top of the capacity interval "
-            "(empty bisection interval) on "
-            f"{len(infeasible_lanes)} lane(s):\n  "
-            + "\n  ".join(infeasible_lanes)
-            + "\nRaise hi or relax the SLO.")
+        infeasible_lanes = []
+        # Per-lane bisection state: None once converged, else
+        # (known_bad, known_good) with known_bad infeasible, known_good
+        # feasible, answer in (known_bad, known_good].
+        state: Dict[Tuple[int, int], Optional[Tuple[int, int]]] = {}
+        answer: Dict[Tuple[int, int], int] = {}
+        for ti in range(T):
+            for wi in range(W):
+                if not feasible(ti, wi, hi):
+                    row = cache[(ti, hi)][wi]
+                    got = row.get("completed_jobs")
+                    peak = _ws_peak(wls[wi][1])
+                    hint = (f"; note hi={hi} is below the WS trace peak "
+                            f"{peak} — the web lane saturates and no "
+                            f"capacity in the interval can meet the SLO"
+                            if hi < peak else "")
+                    infeasible_lanes.append(
+                        f"{_with_capacity(templates[ti], hi).name()} × "
+                        f"workload {wi}: "
+                        f"completed {got} at capacity {hi}, SLO needs "
+                        f"{slo.describe(n_jobs[wi])}{hint}")
+                elif feasible(ti, wi, lo):
+                    answer[(ti, wi)] = lo
+                    state[(ti, wi)] = None
+                else:
+                    state[(ti, wi)] = (lo, hi)
+        if infeasible_lanes:
+            raise ValueError(
+                "SLO infeasible at the top of the capacity interval "
+                "(empty bisection interval) on "
+                f"{len(infeasible_lanes)} lane(s):\n  "
+                + "\n  ".join(infeasible_lanes)
+                + "\nRaise hi or relax the SLO.")
 
-    # Bisection: one batched sweep per iteration over the union of
-    # active lanes' midpoints (converged lanes contribute nothing).
-    while True:
-        mids: Dict[int, set] = {}
-        lane_mid = {}
-        for lane, st in state.items():
-            if st is None:
-                continue
-            bad, good = st
-            if good - bad <= 1:
-                answer[lane] = good
-                state[lane] = None
-                continue
-            mid = (bad + good) // 2
-            lane_mid[lane] = mid
-            mids.setdefault(lane[0], set()).add(mid)
-        if not lane_mid:
-            break
-        evaluate(mids)
-        for lane, mid in lane_mid.items():
-            bad, good = state[lane]
-            if feasible(lane[0], lane[1], mid):
-                state[lane] = (bad, mid)
-            else:
-                state[lane] = (mid, good)
+        # Bisection: one batched sweep per iteration over the union of
+        # active lanes' midpoints (converged lanes contribute nothing).
+        while True:
+            mids: Dict[int, set] = {}
+            lane_mid = {}
+            for lane, st in state.items():
+                if st is None:
+                    continue
+                bad, good = st
+                if good - bad <= 1:
+                    answer[lane] = good
+                    state[lane] = None
+                    continue
+                mid = (bad + good) // 2
+                lane_mid[lane] = mid
+                mids.setdefault(lane[0], set()).add(mid)
+            if not lane_mid:
+                break
+            evaluate(mids)
+            for lane, mid in lane_mid.items():
+                bad, good = state[lane]
+                if feasible(lane[0], lane[1], mid):
+                    state[lane] = (bad, mid)
+                else:
+                    state[lane] = (mid, good)
 
-    results = [CapacityResult(
-        template=templates[ti], template_index=ti, workload=wi,
-        capacity=answer[(ti, wi)],
-        point=_with_capacity(templates[ti], answer[(ti, wi)]),
-        row=cache[(ti, answer[(ti, wi)])][wi],
-        at_grid_edge=answer[(ti, wi)] == lo)
-        for wi in range(W) for ti in range(T)]
-    return CapacityReport(
-        slo=slo, lo=lo, hi=hi, results=results,
-        iterations=ledger["batches"], rows_evaluated=ledger["rows"],
-        brute_force_rows=(hi - lo + 1) * T * W)
+        results = [CapacityResult(
+            template=templates[ti], template_index=ti, workload=wi,
+            capacity=answer[(ti, wi)],
+            point=_with_capacity(templates[ti], answer[(ti, wi)]),
+            row=cache[(ti, answer[(ti, wi)])][wi],
+            at_grid_edge=answer[(ti, wi)] == lo)
+            for wi in range(W) for ti in range(T)]
+        return CapacityReport(
+            slo=slo, lo=lo, hi=hi, results=results,
+            iterations=ledger["batches"], rows_evaluated=ledger["rows"],
+            brute_force_rows=(hi - lo + 1) * T * W)
 
 
 # ------------------------------------------------------- Pareto front

@@ -129,7 +129,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro import compat
+from repro import compat, spans
 from repro.core.jobs import Job
 from repro.core.profiles import step_points
 from repro.sim.scan import (FBGrid, FLBGrid, _prm_tree, _size_classes,
@@ -552,11 +552,16 @@ def pack_event_workloads(workloads: Sequence[Tuple[Sequence[Job],
         else:
             fault_tabs.append((np.zeros(0), np.zeros(0), np.zeros(0)))
             fold_t, fold_v = times, values
-        integral, winmax, at_tick = _fold_tables_cached(
-            np.ascontiguousarray(fold_t, np.float64).tobytes(),
-            np.ascontiguousarray(fold_v, np.float64).tobytes(),
-            float(duration), policy, leases.tobytes(), levels.tobytes(),
-            failed_b)
+        before = _fold_tables_cached.cache_info()
+        with spans.span("rounds.fold_tables"):
+            integral, winmax, at_tick = _fold_tables_cached(
+                np.ascontiguousarray(fold_t, np.float64).tobytes(),
+                np.ascontiguousarray(fold_v, np.float64).tobytes(),
+                float(duration), policy, leases.tobytes(),
+                levels.tobytes(), failed_b)
+        after = _fold_tables_cached.cache_info()
+        spans.count("fold_tables.hits", after.hits - before.hits)
+        spans.count("fold_tables.misses", after.misses - before.misses)
         integrals.append(integral)
         winmaxes.append(winmax)
         at_ticks.append(at_tick)

@@ -67,7 +67,6 @@ lease later (the tick event sorts before the finish event).
 from __future__ import annotations
 
 import dataclasses
-import time
 import warnings
 from typing import Dict, List, Optional, Sequence, Tuple
 
@@ -75,7 +74,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro import compat
+from repro import compat, spans
 from repro.core.jobs import Job
 from repro.core.pbj_manager import PBJPolicyParams
 from repro.core.profiles import step_integral, step_points
@@ -509,31 +508,32 @@ def _pack_rounds(points: List[SweepPoint],
                  duration: float, options: ScanOptions):
     """Host-side setup stage of the rounds path: event packing + fold
     tables + grid construction (see :func:`_pack_scan`)."""
-    fb_idx = [i for i, p in enumerate(points) if p.system == "fb"]
-    flb_idx = [i for i, p in enumerate(points) if p.system == "flb_nub"]
-    max_jobs = max(len(jobs) for jobs, _ in workloads)
-    n_ws = max(len(ws) for _, ws in workloads)
+    with spans.span("sweep.pack"):
+        fb_idx = [i for i, p in enumerate(points) if p.system == "fb"]
+        flb_idx = [i for i, p in enumerate(points) if p.system == "flb_nub"]
+        max_jobs = max(len(jobs) for jobs, _ in workloads)
+        n_ws = max(len(ws) for _, ws in workloads)
 
-    fb = flb = fb_packs = flb_packs = fb_spec = flb_spec = None
-    if fb_idx:
-        leases = [points[i].lease_seconds for i in fb_idx]
-        fb_spec = options.resolve_rounds("fb", leases, duration,
-                                         max_jobs, n_ws)
-        fb_packs = roundslib.pack_event_workloads(
-            workloads, duration, fb_spec.window, "fb", leases,
-            [float(points[i].capacity) for i in fb_idx],
-            dtype=options.dtype, split=True)
-        fb = _fb_grid(points, fb_idx, fb_packs[0].submit.dtype)
-    if flb_idx:
-        leases = [points[i].lease_seconds for i in flb_idx]
-        flb_spec = options.resolve_rounds("flb_nub", leases, duration,
-                                          max_jobs, n_ws)
-        flb_packs = roundslib.pack_event_workloads(
-            workloads, duration, flb_spec.window, "flb_nub", leases,
-            [float(points[i].lb_ws) for i in flb_idx],
-            dtype=options.dtype, split=True)
-        flb = _flb_grid(points, flb_idx, flb_packs[0].submit.dtype)
-    return fb_idx, flb_idx, fb, flb, fb_packs, flb_packs, fb_spec, flb_spec
+        fb = flb = fb_packs = flb_packs = fb_spec = flb_spec = None
+        if fb_idx:
+            leases = [points[i].lease_seconds for i in fb_idx]
+            fb_spec = options.resolve_rounds("fb", leases, duration,
+                                             max_jobs, n_ws)
+            fb_packs = roundslib.pack_event_workloads(
+                workloads, duration, fb_spec.window, "fb", leases,
+                [float(points[i].capacity) for i in fb_idx],
+                dtype=options.dtype, split=True)
+            fb = _fb_grid(points, fb_idx, fb_packs[0].submit.dtype)
+        if flb_idx:
+            leases = [points[i].lease_seconds for i in flb_idx]
+            flb_spec = options.resolve_rounds("flb_nub", leases, duration,
+                                              max_jobs, n_ws)
+            flb_packs = roundslib.pack_event_workloads(
+                workloads, duration, flb_spec.window, "flb_nub", leases,
+                [float(points[i].lb_ws) for i in flb_idx],
+                dtype=options.dtype, split=True)
+            flb = _flb_grid(points, flb_idx, flb_packs[0].submit.dtype)
+        return fb_idx, flb_idx, fb, flb, fb_packs, flb_packs, fb_spec, flb_spec
 
 
 def _sweep_rounds(points: List[SweepPoint],
@@ -560,13 +560,23 @@ def _sweep_rounds(points: List[SweepPoint],
     (fb_idx, flb_idx, fb, flb, fb_packs, flb_packs,
      fb_spec, flb_spec) = _pack_rounds(points, workloads, duration, options)
 
-    outs = [roundslib.rounds_grids(
-        fb, flb,
-        fb_packs[w] if fb_packs is not None else None,
-        flb_packs[w] if flb_packs is not None else None,
-        fb_spec=fb_spec, flb_spec=flb_spec, devices=options.devices)
-        for w in range(len(workloads))]
-    outs = jax.tree_util.tree_map(np.asarray, outs)
+    outs = []
+    for w in range(len(workloads)):
+        with spans.span("sweep.dispatch"):
+            outs.append(roundslib.rounds_grids(
+                fb, flb,
+                fb_packs[w] if fb_packs is not None else None,
+                flb_packs[w] if flb_packs is not None else None,
+                fb_spec=fb_spec, flb_spec=flb_spec, devices=options.devices))
+    with spans.span("sweep.wait"):
+        outs = jax.tree_util.tree_map(np.asarray, outs)
+    # Lockstep efficiency: each policy's lanes in one device call run
+    # until its slowest lane is done.
+    for o in outs:
+        for metrics in o.values():
+            r = np.rint(metrics["rounds"]).astype(np.int64)
+            spans.count("rounds.lane_rounds", int(r.sum()))
+            spans.count("rounds.lane_slots", int(r.size * r.max()))
     out = {kind: {k: np.concatenate([o[kind][k] for o in outs])
                   for k in outs[0][kind]}
            for kind in outs[0]}
@@ -732,86 +742,90 @@ def run_sweep_workloads(points: Sequence[SweepPoint],
     here (``run_sweep``, ``warmup_sweep``, the capacity query layer)
     each add their own frame count.
     """
-    mode = _resolve_mode(mode, vectorize)
-    # warnings.warn stack depth from inside _warn_diagnostics:
-    # 1 = _warn_diagnostics, 2 = _sweep_*, 3 = this function,
-    # 4 = our caller — plus any wrapper frames above us.
-    warn_stacklevel = 4 + _stack_offset
-    if devices is not None:
-        scan_options = dataclasses.replace(scan_options, devices=devices)
-    from repro.sim import scenarios as scenarioslib
-    if isinstance(workloads, scenarioslib.ScenarioGrid):
-        # Generated scenario batches (keys + param grids, not
-        # List[Job]) flow the event-round engine only: the lanes share
-        # one dense WS grid and job-table height, so the whole (W × P)
-        # batch is one program. The grid carries its own horizon.
-        if mode not in ("auto", "rounds"):
-            raise ValueError(
-                f"generated scenario batches run the rounds engine only "
-                f"(mode 'auto'/'rounds', got {mode!r})")
-        if duration is not None and duration != workloads.duration:
-            raise ValueError(
-                "duration is fixed by ScenarioGrid.duration — pass None")
-        bad = sorted({p.system for p in points
-                      if p.system not in _SCANNABLE})
-        if bad:
-            raise ValueError(
-                f"generated scenario batches support FB / FLB-NUB points "
-                f"only, got {bad}; evaluate DCS/EC2 baselines on "
-                f"sampled lanes (repro.sim.scenarios.sample_workloads)")
-        return _sweep_rounds_generated(list(points), workloads,
-                                       scan_options,
-                                       warn_stacklevel=warn_stacklevel)
-    if duration is None:
-        duration = max(default_duration(jobs, ws) for jobs, ws in workloads)
-    rows: List[List[Optional[Dict]]] = [
-        [None] * len(points) for _ in workloads]
+    with spans.span("sweep"):
+        mode = _resolve_mode(mode, vectorize)
+        # warnings.warn stack depth from inside _warn_diagnostics:
+        # 1 = _warn_diagnostics, 2 = _sweep_*, 3 = this function,
+        # 4 = our caller — plus any wrapper frames above us.
+        warn_stacklevel = 4 + _stack_offset
+        if devices is not None:
+            scan_options = dataclasses.replace(scan_options, devices=devices)
+        from repro.sim import scenarios as scenarioslib
+        if isinstance(workloads, scenarioslib.ScenarioGrid):
+            # Generated scenario batches (keys + param grids, not
+            # List[Job]) flow the event-round engine only: the lanes share
+            # one dense WS grid and job-table height, so the whole (W × P)
+            # batch is one program. The grid carries its own horizon.
+            if mode not in ("auto", "rounds"):
+                raise ValueError(
+                    f"generated scenario batches run the rounds engine only "
+                    f"(mode 'auto'/'rounds', got {mode!r})")
+            if duration is not None and duration != workloads.duration:
+                raise ValueError(
+                    "duration is fixed by ScenarioGrid.duration — pass None")
+            bad = sorted({p.system for p in points
+                          if p.system not in _SCANNABLE})
+            if bad:
+                raise ValueError(
+                    f"generated scenario batches support FB / FLB-NUB points "
+                    f"only, got {bad}; evaluate DCS/EC2 baselines on "
+                    f"sampled lanes (repro.sim.scenarios.sample_workloads)")
+            return _sweep_rounds_generated(list(points), workloads,
+                                           scan_options,
+                                           warn_stacklevel=warn_stacklevel)
+        if duration is None:
+            duration = max(default_duration(jobs, ws)
+                           for jobs, ws in workloads)
+        rows: List[List[Optional[Dict]]] = [
+            [None] * len(points) for _ in workloads]
 
-    if mode != "event":
         dcs_idx = [i for i, p in enumerate(points) if p.system == "dcs"]
         ec2_idx = [i for i, p in enumerate(points) if p.system == "ec2"]
+        if mode != "event" and (dcs_idx or ec2_idx):
+            with spans.span("sweep.closed_forms"):
+                dcs = [points[i] for i in dcs_idx]
+                ec2 = [points[i] for i in ec2_idx]
+                for w, (jobs, ws_trace) in enumerate(workloads):
+                    if dcs:
+                        for i, row in zip(dcs_idx,
+                                          _sweep_dcs(dcs, duration)):
+                            rows[w][i] = row
+                    if ec2:
+                        for i, row in zip(ec2_idx,
+                                          _sweep_ec2(ec2, jobs, ws_trace,
+                                                     duration)):
+                            rows[w][i] = row
+
+        if mode in ("auto", "scan", "rounds"):
+            batch_idx = [i for i, p in enumerate(points)
+                         if p.system in _SCANNABLE]
+            if mode == "auto":
+                # The event-round engine is the default scan-family mode;
+                # points it rejects (FB checkpoint_preempt) quietly take
+                # the per-point event path below instead of failing.
+                batch_idx = [i for i in batch_idx
+                             if not (points[i].system == "fb"
+                                     and points[i].params.checkpoint_preempt)]
+            fast = _sweep_scan if mode == "scan" else _sweep_rounds
+            if batch_idx:
+                fast_rows = fast([points[i] for i in batch_idx],
+                                 workloads, duration, scan_options,
+                                 warn_stacklevel=warn_stacklevel)
+                for w in range(len(workloads)):
+                    for j, i in enumerate(batch_idx):
+                        rows[w][i] = fast_rows[w][j]
+
         for w, (jobs, ws_trace) in enumerate(workloads):
-            if dcs_idx:
-                for i, row in zip(dcs_idx,
-                                  _sweep_dcs([points[i] for i in dcs_idx],
-                                             duration)):
-                    rows[w][i] = row
-            if ec2_idx:
-                for i, row in zip(ec2_idx,
-                                  _sweep_ec2([points[i] for i in ec2_idx],
-                                             jobs, ws_trace, duration)):
-                    rows[w][i] = row
-
-    if mode in ("auto", "scan", "rounds"):
-        batch_idx = [i for i, p in enumerate(points)
-                     if p.system in _SCANNABLE]
-        if mode == "auto":
-            # The event-round engine is the default scan-family mode;
-            # points it rejects (FB checkpoint_preempt) quietly take
-            # the per-point event path below instead of failing.
-            batch_idx = [i for i in batch_idx
-                         if not (points[i].system == "fb"
-                                 and points[i].params.checkpoint_preempt)]
-        fast = _sweep_scan if mode == "scan" else _sweep_rounds
-        if batch_idx:
-            fast_rows = fast([points[i] for i in batch_idx],
-                             workloads, duration, scan_options,
-                             warn_stacklevel=warn_stacklevel)
-            for w in range(len(workloads)):
-                for j, i in enumerate(batch_idx):
-                    rows[w][i] = fast_rows[w][j]
-
-    for w, (jobs, ws_trace) in enumerate(workloads):
-        for i, p in enumerate(points):
-            if rows[w][i] is not None:
-                continue
-            r = run_sim(_build(p), clone_jobs(jobs), ws_trace, duration,
-                        name=p.name())
-            row = r.row()
-            row.update(system_kind=p.system, engine="event",
-                       lease_seconds=p.lease_seconds)
-            rows[w][i] = row
-    return rows                                   # type: ignore[return-value]
+            for i, p in enumerate(points):
+                if rows[w][i] is not None:
+                    continue
+                r = run_sim(_build(p), clone_jobs(jobs), ws_trace, duration,
+                            name=p.name())
+                row = r.row()
+                row.update(system_kind=p.system, engine="event",
+                           lease_seconds=p.lease_seconds)
+                rows[w][i] = row
+        return rows                               # type: ignore[return-value]
 
 
 def warmup_sweep(points: Sequence[SweepPoint],
@@ -821,8 +835,10 @@ def warmup_sweep(points: Sequence[SweepPoint],
                  scan_options: ScanOptions = ScanOptions(),
                  devices: compat.Devices = None) -> float:
     """Prime every jit cache one (grid, workloads, mode, options)
-    configuration touches and return the priming call's wall seconds —
-    the compile cost the steady-state path then never pays again.
+    configuration touches and return the priming call's seconds, as its
+    ``sweep`` root span recorded them (so call it outside any open
+    :mod:`repro.spans` span) — the compile cost the steady-state path
+    then never pays again.
 
     The fast paths' programs are cached on ``(policy, spec)`` keys that
     include the rounds ``kernel`` backend and, for the sharded backend,
@@ -835,11 +851,10 @@ def warmup_sweep(points: Sequence[SweepPoint],
     call ``jax.clear_caches()`` first and take this helper's return
     value; live paths call it once at startup and pay ~0 afterwards.
     """
-    t0 = time.time()
     run_sweep_workloads(points, workloads, duration, mode=mode,
                         scan_options=scan_options, devices=devices,
                         _stack_offset=1)
-    return time.time() - t0
+    return spans.roots("sweep")[-1]["s"]
 
 
 # ------------------------------------------------------------- paper grids
