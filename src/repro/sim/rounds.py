@@ -462,8 +462,7 @@ def pack_event_workloads(workloads: Sequence[Tuple[Sequence[Job],
                                                                   int]]]],
                          duration: float, window: int, policy: str,
                          leases: Sequence[float], levels: Sequence[float],
-                         dtype: Optional[np.dtype] = None,
-                         split: bool = False, faults=None):
+                         dtype: Optional[np.dtype] = None, faults=None):
     """Pack ``(jobs, ws_trace)`` workloads into event-round arrays for
     one policy's sweep points.
 
@@ -472,11 +471,7 @@ def pack_event_workloads(workloads: Sequence[Tuple[Sequence[Job],
     for the values given). WS change points collapse to actual value
     changes within the horizon (the event engine ledgers nothing for a
     no-op demand event); a trailing ``+inf`` sentinel keeps gathers in
-    range after the last real change. With ``split=True`` the return
-    value is a LIST of single-workload packs (one per trace, identical
-    shapes since they are padded together) cut on the host — the
-    per-trace invocations of ``repro.sim.sweep`` consume these without
-    slicing a device-resident pack per workload.
+    range after the last real change.
 
     ``faults``, when given, is a per-workload sequence of
     :class:`repro.sim.faults.FaultSchedule` (or ``None`` entries) —
@@ -589,10 +584,6 @@ def pack_event_workloads(workloads: Sequence[Tuple[Sequence[Job],
             fault_wsv[w, :len(f_w)] = f_w
         arrays.update(fault_times=fault_times, fault_failed=fault_failed,
                       fault_wsv=fault_wsv)
-    if split:
-        return [PackedEventWorkloads(
-            **{k: jnp.asarray(v[w:w + 1]) for k, v in arrays.items()})
-            for w in range(W)]
     return PackedEventWorkloads(
         **{k: jnp.asarray(v) for k, v in arrays.items()})
 
@@ -1187,14 +1178,23 @@ def _rounds_grids_single(fb: Optional[FBGrid], flb: Optional[FLBGrid],
                          fb_spec: Optional[RoundsSpec] = None,
                          flb_spec: Optional[RoundsSpec] = None
                          ) -> Dict[str, Dict[str, jnp.ndarray]]:
-    """Single-device execution: the (trace, point) grid as nested vmaps.
-    Nothing is donated: the outputs are per-lane metrics, so no packed
-    buffer could be reused for them."""
+    """Single-device execution: the (trace, point) grid as ONE vmap over
+    the flattened W·P lanes, each lane gathering its point's parameters
+    and its trace's pack row (as the sharded path does per device).
+    Nested vmaps would lay the lanes out as W blocks of P rows, each
+    padded to the device's tile on its own: on a TPU v5e the paper
+    grid's rounds loop then took 1.35 s against 0.77 s flattened, with
+    bit-identical rows. Nothing is donated: the outputs are per-lane
+    metrics, so no packed buffer could be reused for them."""
     def run(policy, prm_tree, packed, spec):
-        lane = _rounds_lane(policy, spec)
-        over_points = jax.vmap(lane, in_axes=(0, None))
-        over_traces = jax.vmap(over_points, in_axes=(None, 0))
-        return over_traces(prm_tree, packed)
+        W = packed.submit.shape[0]
+        P = prm_tree["p_idx"].shape[0]
+        w_idx = jnp.repeat(jnp.arange(W), P)
+        p_idx = jnp.tile(jnp.arange(P), W)
+        prm_l = jax.tree_util.tree_map(lambda a: a[p_idx], prm_tree)
+        pk_l = jax.tree_util.tree_map(lambda a: a[w_idx], packed)
+        out = jax.vmap(_rounds_lane(policy, spec))(prm_l, pk_l)
+        return jax.tree_util.tree_map(lambda a: a.reshape(W, P), out)
 
     out: Dict[str, Dict[str, jnp.ndarray]] = {}
     if fb_spec is not None:
@@ -1219,8 +1219,8 @@ def rounds_grids(fb: Optional[FBGrid], flb: Optional[FLBGrid],
     scan_grids`; a policy is skipped when its spec is ``None``.
 
     ``devices`` selects the backend exactly as for the scan engine:
-    ``None`` / one device runs the nested-vmap program, two or more
-    shard the flattened (trace × point) lanes via the shared
+    ``None`` / one device runs the flattened (trace × point) lanes as
+    one vmapped program, two or more shard those lanes via the shared
     ``sharded_grid_map`` — bit-identical rows either way, since every
     lane runs the identical per-lane program.
     """

@@ -221,8 +221,8 @@ def test_rounds_rejects_checkpoint_preempt_and_auto_falls_back():
 
 def test_rounds_batches_trace_axis():
     """run_sweep_workloads in rounds mode: per-workload rows reflect
-    their own trace (the workload axis runs as separate invocations of
-    one compiled program)."""
+    their own trace (the workload axis is a vmap axis of one device
+    call)."""
     from repro.sim.sweep import run_sweep_workloads
 
     jobs1, ws1 = random_workload(11)
@@ -246,6 +246,43 @@ def test_rounds_batches_trace_axis():
     # any job trace.)
     assert rows[0][0]["completed_jobs"] != rows[1][0]["completed_jobs"]
     assert rows[0][0]["avg_turnaround"] != rows[1][0]["avg_turnaround"]
+
+
+def test_stacked_workloads_match_single_workload_calls():
+    """Three workloads of different lengths run as ONE device call whose
+    rows equal three single-workload calls, rounds included: lanes that
+    finish early idle in lockstep and change nothing. (The workloads
+    share their job and demand-point counts, so the three single calls
+    share one compiled program.)"""
+    from repro import spans
+    from repro.sim.sweep import run_sweep_workloads
+
+    def workload(seed, hours, peak):
+        rng = random.Random(seed)
+        jobs = [Job(i, rng.uniform(0.0, hours * 3600.0),
+                    size=2 ** rng.randrange(0, 4),
+                    runtime=rng.uniform(600.0, 3 * 3600.0))
+                for i in range(24)]
+        ws = [(0.0, 1), (0.3 * hours * 3600.0, peak),
+              (0.6 * hours * 3600.0, 2)]
+        return jobs, ws
+
+    workloads = [workload(21, 16, 6), workload(22, 4, 3),
+                 workload(23, 9, 5)]
+    pts = [SweepPoint("fb", capacity=10), SweepPoint("fb", capacity=16),
+           SweepPoint("flb_nub", lb_pbj=6, lb_ws=4),
+           SweepPoint("flb_nub", lb_pbj=3, lb_ws=2)]
+    stacked = run_sweep_workloads(pts, workloads, DAY, mode="rounds")
+    root = spans.roots("sweep")[-1]
+    dispatches = [r for r in spans.RECORDER.records
+                  if r.root_id == root["id"] and r.name == "sweep.dispatch"]
+    assert len(dispatches) == 1
+    single = [run_sweep(pts, jobs, ws, DAY, mode="rounds")
+              for jobs, ws in workloads]
+    assert stacked == single
+    # The workloads end at different depths, so some lanes idled.
+    depths = {max(r["rounds"] for r in rows) for rows in stacked}
+    assert len(depths) == 3
 
 
 # ------------------------------------------------------ pick_dt edges

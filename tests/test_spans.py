@@ -109,12 +109,14 @@ def test_fold_counters_are_the_cache_statistics_over_the_call(call):
 
 def test_lane_counters_are_the_rows_rounds(call):
     rows, root, _, _ = call
+    # One device call per query: each policy's lanes over all workloads
+    # wait for its slowest lane over all workloads.
     total = slots = 0
-    for rs in rows:
-        for kind in ("fb", "flb_nub"):
-            r = [row["rounds"] for row in rs if row["system_kind"] == kind]
-            total += sum(r)
-            slots += len(r) * max(r)
+    for kind in ("fb", "flb_nub"):
+        r = [row["rounds"] for rs in rows for row in rs
+             if row["system_kind"] == kind]
+        total += sum(r)
+        slots += len(r) * max(r)
     assert root["counters"]["rounds.lane_rounds"] == total
     assert root["counters"]["rounds.lane_slots"] == slots
     assert set(root["children_s"]) == {

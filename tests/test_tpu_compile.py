@@ -89,20 +89,21 @@ def test_rounds_program_compiles_for_v5e(policy, paper_pack, one_chip,
                                          no_persistent_cache):
     """The whole per-policy rounds program — the while loop over
     compaction, admission and the unrolled event rounds, vmapped over
-    the sweep points — compiles for one v5e chip at paper width."""
-    (_, _, fb, flb, fb_packs, flb_packs, fb_spec, flb_spec) = paper_pack
+    the sweep points and the three workloads — compiles for one v5e
+    chip at paper width."""
+    (_, _, fb, flb, fb_packed, flb_packed, fb_spec, flb_spec) = paper_pack
     if policy == "fb":
-        args = _shapes((fb, None, fb_packs[0], None), one_chip)
+        args = _shapes((fb, None, fb_packed, None), one_chip)
         spec = dict(fb_spec=fb_spec, flb_spec=None)
         n_points = fb.lease.shape[0]
     else:
-        args = _shapes((None, flb, None, flb_packs[0]), one_chip)
+        args = _shapes((None, flb, None, flb_packed), one_chip)
         spec = dict(fb_spec=None, flb_spec=flb_spec)
         n_points = flb.lease.shape[0]
     assert n_points == (5 if policy == "fb" else 10)
     compiled = roundslib._rounds_grids_single.lower(*args, **spec).compile()
     out = compiled.out_info[policy]
-    assert out["completed_jobs"].shape == (1, n_points)
+    assert out["completed_jobs"].shape == (3, n_points)
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes > 0
     assert mem.temp_size_in_bytes < 16 * 2 ** 30      # fits one chip
