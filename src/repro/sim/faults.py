@@ -147,40 +147,67 @@ def exponential_schedule(seed: int, n_nodes: int, mtbf: float,
     return _finish(events)
 
 
+REPAIRS = ("exponential", "lognormal")
+
+
+def _repair_draw(rng: np.random.Generator, mttr: float, repair: str,
+                 sigma: float) -> Callable[[], float]:
+    """A draw of one repair time with mean ``mttr``: exponential, or
+    lognormal with log-standard-deviation ``sigma`` (the fit of field
+    repair times, Schroeder & Gibson, DSN 2006), whose log-mean
+    ``ln(mttr) - sigma**2 / 2`` keeps the mean at ``mttr``."""
+    if mttr <= 0:
+        raise ValueError("mttr must be > 0")
+    if repair == "exponential":
+        return lambda: rng.exponential(mttr)
+    if repair == "lognormal":
+        if sigma <= 0:
+            raise ValueError("repair_sigma must be > 0")
+        mu = math.log(mttr) - 0.5 * sigma * sigma
+        return lambda: rng.lognormal(mu, sigma)
+    raise ValueError(f"unknown repair distribution {repair!r}; expected "
+                     f"one of {REPAIRS}")
+
+
 def weibull_schedule(seed: int, n_nodes: int, mtbf: float, mttr: float,
-                     duration: float, shape: float = 1.5) -> FaultSchedule:
+                     duration: float, shape: float = 1.5,
+                     repair: str = "exponential",
+                     repair_sigma: float = 1.0) -> FaultSchedule:
     """Per-node Weibull time-between-failures (scale chosen so the mean
     equals ``mtbf``; shape > 1 models aging hardware with increasing
-    hazard), exponential repair."""
-    if mtbf <= 0 or mttr <= 0 or shape <= 0:
-        raise ValueError("mtbf, mttr and shape must be > 0")
+    hazard, shape < 1 the decreasing hazard of field data), repair
+    times drawn by ``repair`` (see :func:`_repair_draw`)."""
+    if mtbf <= 0 or shape <= 0:
+        raise ValueError("mtbf and shape must be > 0")
     scale = mtbf / math.gamma(1.0 + 1.0 / shape)
     rng = np.random.Generator(np.random.PCG64(seed))
+    draw_down = _repair_draw(rng, mttr, repair, repair_sigma)
     events: List[Tuple[float, int]] = []
     for _ in range(n_nodes):
         events += _renewal(rng, duration,
-                           lambda: scale * rng.weibull(shape),
-                           lambda: rng.exponential(mttr))
+                           lambda: scale * rng.weibull(shape), draw_down)
     return _finish(events)
 
 
 def burst_schedule(seed: int, k: int, mtbf: float, mttr: float,
-                   duration: float) -> FaultSchedule:
+                   duration: float, repair: str = "exponential",
+                   repair_sigma: float = 1.0) -> FaultSchedule:
     """Correlated bursts: ``k`` nodes fail at once (a shared failure
     domain — rack power, top-of-rack switch) at exponential inter-burst
-    times, all repaired together after an exponential outage. Bursts
-    never overlap: the next inter-burst time starts at the previous
-    repair."""
+    times, all repaired together after an outage drawn by ``repair``
+    (see :func:`_repair_draw`). Bursts never overlap: the next
+    inter-burst time starts at the previous repair."""
     if k <= 0:
         raise ValueError("burst size k must be > 0")
-    if mtbf <= 0 or mttr <= 0:
-        raise ValueError("mtbf and mttr must be > 0")
+    if mtbf <= 0:
+        raise ValueError("mtbf must be > 0")
     rng = np.random.Generator(np.random.PCG64(seed))
+    draw_down = _repair_draw(rng, mttr, repair, repair_sigma)
     events: List[Tuple[float, int]] = []
     t = rng.exponential(mtbf)
     while t < duration:
         events.append((t, +k))
-        r = t + max(rng.exponential(mttr), 1e-6)
+        r = t + max(draw_down(), 1e-6)
         events.append((r, -k))
         t = r + max(rng.exponential(mtbf), 1e-6)
     return _finish(events)

@@ -457,12 +457,47 @@ def fold_table_cache_clear() -> None:
     _fold_tables_cached.cache_clear()
 
 
+def _fault_table(fs, levels: np.ndarray, times: np.ndarray,
+                 values: np.ndarray, duration: float):
+    """One workload's fault stops and its fold axis. Returns the
+    distinct fault instants inside the horizon ``u_t``, the failed
+    count in effect after all of an instant's events ``u_n``, the raw
+    demand at each (the loop's reclaim level) ``u_w``, and the fold
+    axis: the union of demand and fault change points with the demand
+    and failed count resampled onto it; ``None`` when the clamp leaves
+    no event."""
+    # Mirror the site ledger's clamp (at most C nodes down at once;
+    # repairs revive only actually-failed nodes). The clamp recurrence
+    # depends on C, so a multi-level grid can only share one fault
+    # table when the clamp never binds.
+    if np.unique(levels).size == 1:
+        fs = fs.clamp(int(levels[0]))
+    elif fs.max_concurrent() > int(np.min(levels)):
+        raise ValueError(
+            "fault schedule's concurrent failures exceed the smallest "
+            "capacity level; the ledger clamp is per-capacity — pack one "
+            "level at a time")
+    if not len(fs):
+        return None
+    f_t, f_n = fs.failed_series()
+    u_t = np.unique(f_t[f_t < duration])
+    u_n = np.concatenate([[0], f_n])[
+        np.searchsorted(f_t, u_t, "right")].astype(np.float64)
+    u_w = values[np.searchsorted(times, u_t, "right") - 1]
+    m_t = np.union1d(times, u_t)
+    m_v = values[np.searchsorted(times, m_t, "right") - 1]
+    m_f = np.concatenate([[0.0], u_n])[np.searchsorted(u_t, m_t, "right")]
+    return (u_t, u_n, u_w, m_t, m_v,
+            np.ascontiguousarray(m_f, np.float64))
+
+
 def pack_event_workloads(workloads: Sequence[Tuple[Sequence[Job],
                                                    Sequence[Tuple[float,
                                                                   int]]]],
                          duration: float, window: int, policy: str,
                          leases: Sequence[float], levels: Sequence[float],
-                         dtype: Optional[np.dtype] = None, faults=None):
+                         dtype: Optional[np.dtype] = None, faults=None,
+                         fault_stops: Optional[int] = None):
     """Pack ``(jobs, ws_trace)`` workloads into event-round arrays for
     one policy's sweep points.
 
@@ -480,7 +515,9 @@ def pack_event_workloads(workloads: Sequence[Tuple[Sequence[Job],
     on the union of demand and fault change points with the FB share
     line ``min(ws, max(C - failed(t), 0))``, so the WS integral and the
     window maxima stay exact under failures. Demand-rise stops keep
-    coming from the original demand points.
+    coming from the original demand points. The fault tables are padded
+    to the most instants over the workloads, or to ``fault_stops`` when
+    given (a schedule with more instants raises).
     """
     dtype = resolve_pack_dtype(dtype)
     if faults is not None and any(f is not None and len(f) for f in faults):
@@ -513,40 +550,18 @@ def pack_event_workloads(workloads: Sequence[Tuple[Sequence[Job],
         up = values[1:] > values[:-1]
         rises.append((times[1:][up], values[1:][up]))
         fs = faults[w] if faults is not None else None
-        failed_b = b""
+        tab = None
         if fs is not None and len(fs):
-            # Mirror the site ledger's clamp (at most C nodes down at
-            # once; repairs revive only actually-failed nodes). The
-            # clamp recurrence depends on C, so a multi-level grid can
-            # only share one fault table when the clamp never binds.
-            if np.unique(levels).size == 1:
-                fs = fs.clamp(int(levels[0]))
-            elif fs.max_concurrent() > int(np.min(levels)):
-                raise ValueError(
-                    "fault schedule's concurrent failures exceed the "
-                    "smallest capacity level; the ledger clamp is "
-                    "per-capacity — pack one level at a time")
-        if fs is not None and len(fs):
-            f_t, f_n = fs.failed_series()
-            # Distinct fault instants inside the horizon, with the
-            # failed count in effect after all same-time events and the
-            # raw demand at that instant (the loop's reclaim level).
-            u_t = np.unique(f_t[f_t < duration])
-            u_n = np.concatenate([[0], f_n])[
-                np.searchsorted(f_t, u_t, "right")].astype(np.float64)
-            u_w = values[np.searchsorted(times, u_t, "right") - 1]
+            with spans.span("rounds.fault_tables"):
+                tab = _fault_table(fs, levels, times, values, duration)
+        if tab is not None:
+            u_t, u_n, u_w, fold_t, fold_v, failed = tab
+            spans.count("faults.events", len(u_t))
             fault_tabs.append((u_t, u_n, u_w))
-            # Fold axis: the union of demand and fault change points,
-            # demand and failed resampled onto it.
-            m_t = np.union1d(times, u_t)
-            m_v = values[np.searchsorted(times, m_t, "right") - 1]
-            m_f = np.concatenate([[0.0], u_n])[
-                np.searchsorted(u_t, m_t, "right")]
-            fold_t, fold_v = m_t, m_v
-            failed_b = np.ascontiguousarray(m_f, np.float64).tobytes()
+            failed_b = failed.tobytes()
         else:
             fault_tabs.append((np.zeros(0), np.zeros(0), np.zeros(0)))
-            fold_t, fold_v = times, values
+            fold_t, fold_v, failed_b = times, values, b""
         before = _fold_tables_cached.cache_info()
         with spans.span("rounds.fold_tables"):
             integral, winmax, at_tick = _fold_tables_cached(
@@ -574,14 +589,23 @@ def pack_event_workloads(workloads: Sequence[Tuple[Sequence[Job],
         ws_winmax=np.stack(winmaxes).astype(dtype),
         ws_at_tick=np.stack(at_ticks).astype(dtype), n_jobs=n_jobs)
     if faults is not None:
-        nf = max(len(ft) for ft, _, _ in fault_tabs) + 1  # +inf sentinel
-        fault_times = np.full((W, nf), np.inf, dtype)
-        fault_failed = np.zeros((W, nf), dtype)
-        fault_wsv = np.zeros((W, nf), dtype)
-        for w, (f_t, f_n, f_w) in enumerate(fault_tabs):
-            fault_times[w, :len(f_t)] = f_t
-            fault_failed[w, :len(f_n)] = f_n
-            fault_wsv[w, :len(f_w)] = f_w
+        # (W, NF) tables padded to the most instants over the stacked
+        # workloads; a workload without a schedule reads only the
+        # sentinel, so its lanes never stop at a fault.
+        with spans.span("rounds.fault_tables"):
+            most = max(len(ft) for ft, _, _ in fault_tabs)
+            if fault_stops is not None and most > fault_stops:
+                raise ValueError(
+                    f"a fault schedule holds {most} instants inside the "
+                    f"horizon, more than fault_stops={fault_stops}")
+            nf = (most if fault_stops is None else fault_stops) + 1  # +inf
+            fault_times = np.full((W, nf), np.inf, dtype)
+            fault_failed = np.zeros((W, nf), dtype)
+            fault_wsv = np.zeros((W, nf), dtype)
+            for w, (f_t, f_n, f_w) in enumerate(fault_tabs):
+                fault_times[w, :len(f_t)] = f_t
+                fault_failed[w, :len(f_n)] = f_n
+                fault_wsv[w, :len(f_w)] = f_w
         arrays.update(fault_times=fault_times, fault_failed=fault_failed,
                       fault_wsv=fault_wsv)
     return PackedEventWorkloads(
@@ -610,6 +634,13 @@ def round_budget(max_jobs: int, n_ws: int, duration: float,
 ACC_KEYS = ("completed", "turn_sum", "exec_sum", "kills", "node_seconds",
             "peak", "pbj_adjusts", "adjusts", "window_overflow", "rounds",
             "coalesced")
+
+
+def _fault_acc_keys(ctx: Dict) -> Tuple[str, ...]:
+    """The accumulators a faulted lane adds beside ``ACC_KEYS``: the
+    rounds that stopped at a fault instant and the jobs killed in them.
+    A pack without fault tables adds none, so its program is unchanged."""
+    return ("fault_rounds", "fault_kills") if "fault_times" in ctx else ()
 
 
 def _lane_ctx(policy: str, prm: Dict, pk: PackedEventWorkloads) -> Dict:
@@ -946,12 +977,20 @@ def _round_body(policy: str, ctx: Dict, spec: RoundsSpec, carry, szcls):
         fib = jnp.searchsorted(ft, b, side="right")
         fprev = jnp.maximum(fib - 1, 0)
         failed_b = jnp.where(fib > 0, ffl[fprev], zero)
-        wsv = jnp.where((fib > 0) & (ft[fprev] == b), fwv[fprev], wsv)
+        at_fault = active & (fib > 0) & (ft[fprev] == b)
+        wsv = jnp.where(at_fault, fwv[fprev], wsv)
         ctx = dict(ctx, C=jnp.maximum(ctx["C"] - failed_b, zero))
+        kills_before = acc["kills"]
     wsv = jnp.where(is_tick, ws_at_tick[win], wsv)
     owned, pool_pbj, run, starts, integrand, acc = _actions(
         policy, ctx, spec.ff_passes, owned, pool_pbj, run, used, queued,
         wsv, is_tick, win, w_sz, szcls, acc)
+    if faulted:
+        # Rounds that stopped at a fault instant, and the jobs killed
+        # in them (a web rise or tick at the same instant included).
+        acc["fault_rounds"] += at_fault.astype(f)
+        acc["fault_kills"] += jnp.where(at_fault, acc["kills"] - kills_before,
+                                        zero)
     start_t = jnp.where(starts, b, start_t)
     end_t = jnp.where(starts, b + w_rt, end_t)
     # Recompute the queue and usage from the POST-action lane state:
@@ -1074,7 +1113,7 @@ def _simulate_rounds(policy: str, prm: Dict, pk: PackedEventWorkloads,
     # followed by the t = 0 submit events (no tick fires at 0), plus
     # the first lease window's peak probe (the tick-gated probe in
     # _actions starts at window 1).
-    acc = {k: zero for k in ACC_KEYS}
+    acc = {k: zero for k in ACC_KEYS + _fault_acc_keys(ctx)}
     w_sub = tr_submit[:K]
     w_sz = tr_size[:K]
     w_rt = tr_runtime[:K]
@@ -1151,6 +1190,7 @@ def _simulate_rounds(policy: str, prm: Dict, pk: PackedEventWorkloads,
         "rounds": acc["rounds"],
         "coalesced": acc["coalesced"],
         "truncated": (t_end < duration).astype(f),
+        **{k: acc[k] for k in _fault_acc_keys(ctx)},
     }
 
 
@@ -1252,31 +1292,17 @@ def fb_rounds_row(jobs: Sequence[Job], ws_trace: Sequence[Tuple[float, int]],
                   capacity: int, lease_seconds: float, duration: float,
                   faults=None, kernel: str = "xla",
                   batch: int = DEFAULT_BATCH,
-                  dtype: Optional[np.dtype] = None) -> Dict[str, float]:
-    """One FB (capacity, lease) point through the rounds engine as a
-    plain scalar row — the single-point convenience the chaos
-    differential harness and ``benchmarks.run faults`` share. With
-    ``faults`` set, the schedule's stops fold into the horizon and the
-    effective capacity becomes ``max(C - failed(t), 0)`` (see
-    :func:`pack_event_workloads`)."""
-    n_faults = len(faults) if faults is not None else 0
-    spec = RoundsSpec(
-        duration=float(duration),
-        max_rounds=round_budget(len(jobs), len(list(ws_trace)),
-                                float(duration), float(lease_seconds))
-        + 8 * n_faults,   # each fault stop may kill + restart jobs
-        window=FB_ROUNDS_WINDOW, kernel=kernel, batch=batch)
-    pk = pack_event_workloads(
-        [(jobs, ws_trace)], float(duration), spec.window, "fb",
-        [float(lease_seconds)], [float(capacity)], dtype=dtype,
-        faults=[faults] if faults is not None else None)
-    f = pk.submit.dtype
-    fb = FBGrid(capacity=jnp.asarray([float(capacity)], f),
-                lease=jnp.asarray([float(lease_seconds)], f))
-    out = rounds_grids(fb, None, pk, None, fb_spec=spec)["fb"]
-    row = {k: float(np.asarray(v)[0, 0]) for k, v in out.items()}
-    for k in ("completed_jobs", "peak_nodes"):
-        row[k] = int(round(row[k]))
-    row["engine"] = "rounds"
-    row["system"] = "fb"
-    return row
+                  dtype: Optional[np.dtype] = None) -> Dict:
+    """One FB (capacity, lease) point through the rounds engine as one
+    row of :func:`repro.sim.sweep.run_sweep_workloads` — the single-point
+    convenience the chaos differential harness and ``benchmarks.run
+    faults`` share. With ``faults`` set, the schedule's stops fold into
+    the horizon and the effective capacity becomes ``max(C - failed(t),
+    0)`` (see :func:`pack_event_workloads`)."""
+    from repro.sim import sweep
+    point = sweep.SweepPoint("fb", capacity=int(capacity),
+                             lease_seconds=float(lease_seconds))
+    options = sweep.ScanOptions(kernel=kernel, coalesce=batch, dtype=dtype)
+    return sweep.run_sweep_workloads(
+        [point], [(jobs, ws_trace)], float(duration), mode="rounds",
+        scan_options=options, faults=[faults], _stack_offset=1)[0][0]

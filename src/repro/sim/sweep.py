@@ -164,7 +164,13 @@ class ScanOptions:
     ``devices`` selects the execution backend
     (``repro.compat.resolve_devices``): ``None`` runs the whole
     grid on one device, a count or device sequence shards the
-    (point × trace) lanes across host devices via ``shard_map``."""
+    (point × trace) lanes across host devices via ``shard_map``.
+    ``fault_stops`` fixes how many fault instants one workload's
+    schedule may hold inside the horizon (FB under ``faults=``; more
+    raise). Left ``None``, the fault tables and the round budget size to
+    the call's schedules, so each new draw of schedules compiles the
+    rounds program again; a caller asking many questions of fresh draws
+    sets it once and compiles once."""
 
     dt: Optional[float] = None
     window: Optional[int] = None
@@ -174,6 +180,7 @@ class ScanOptions:
     dtype: Optional[np.dtype] = None
     devices: compat.Devices = None
     kernel: str = "xla"
+    fault_stops: Optional[int] = None
 
     def resolve(self, policy: str, leases: Sequence[float],
                 duration: float,
@@ -378,7 +385,8 @@ def _flb_grid(points: List[SweepPoint], idxs: List[int],
         lease=jnp.asarray([points[i].lease_seconds for i in idxs], f))
 
 
-_DIAG_KEYS = ("window_overflow", "truncated", "rounds", "coalesced")
+_DIAG_KEYS = ("window_overflow", "truncated", "rounds", "coalesced",
+              "fault_rounds", "fault_kills")
 
 
 def _assemble_rows(points: List[SweepPoint], fb_idx: List[int],
@@ -505,10 +513,12 @@ def _sweep_scan(points: List[SweepPoint],
 def _pack_rounds(points: List[SweepPoint],
                  workloads: Sequence[Tuple[Sequence[Job],
                                            Sequence[Tuple[float, int]]]],
-                 duration: float, options: ScanOptions):
+                 duration: float, options: ScanOptions, faults=None):
     """Host-side setup stage of the rounds path: event packing + fold
     tables + grid construction (see :func:`_pack_scan`). Each policy's
-    pack holds every workload, stacked on a leading trace axis."""
+    pack holds every workload, stacked on a leading trace axis. FB's
+    pack carries ``faults`` (one schedule or ``None`` per workload), and
+    its round budget the slack of the call's largest schedule."""
     with spans.span("sweep.pack"):
         fb_idx = [i for i, p in enumerate(points) if p.system == "fb"]
         flb_idx = [i for i, p in enumerate(points) if p.system == "flb_nub"]
@@ -520,10 +530,19 @@ def _pack_rounds(points: List[SweepPoint],
             leases = [points[i].lease_seconds for i in fb_idx]
             fb_spec = options.resolve_rounds("fb", leases, duration,
                                              max_jobs, n_ws)
+            n_faults = max((len(f) for f in faults or () if f is not None),
+                           default=0)
+            if n_faults and options.fault_stops is not None:
+                n_faults = options.fault_stops
+            if n_faults:
+                # Each fault stop may kill and restart jobs.
+                fb_spec = dataclasses.replace(
+                    fb_spec, max_rounds=fb_spec.max_rounds + 8 * n_faults)
             fb_packed = roundslib.pack_event_workloads(
                 workloads, duration, fb_spec.window, "fb", leases,
                 [float(points[i].capacity) for i in fb_idx],
-                dtype=options.dtype)
+                dtype=options.dtype, faults=faults,
+                fault_stops=options.fault_stops)
             fb = _fb_grid(points, fb_idx, fb_packed.submit.dtype)
         if flb_idx:
             leases = [points[i].lease_seconds for i in flb_idx]
@@ -543,7 +562,7 @@ def _sweep_rounds(points: List[SweepPoint],
                                             Sequence[Tuple[float, int]]]],
                   duration: float,
                   options: ScanOptions,
-                  warn_stacklevel: int = 3) -> List[List[Dict]]:
+                  warn_stacklevel: int = 3, faults=None) -> List[List[Dict]]:
     """FB and FLB-NUB points through the event-round fast path
     (``repro.sim.rounds``): adaptive jump-to-next-event steps with
     exact completions, batched over sweep points like the scan.
@@ -558,12 +577,16 @@ def _sweep_rounds(points: List[SweepPoint],
     call against 1.32 s in three. On a 2-core CPU, where a round's cost
     grows in proportion to the lanes, the one call is ~12 % slower on
     the paper grid. With ``devices`` set, the call shards the lanes
-    across the devices.
+    across the devices. ``faults`` (FB only, one schedule or ``None`` per
+    workload) rides in the same call; the root span then counts the
+    rounds that stopped at a fault instant (``rounds.fault_rounds``)
+    and the jobs killed in them (``faults.kills``).
     """
     assert all(p.system in _SCANNABLE for p in points)
     _reject_preempt(points, "rounds")
     (fb_idx, flb_idx, fb, flb, fb_packed, flb_packed,
-     fb_spec, flb_spec) = _pack_rounds(points, workloads, duration, options)
+     fb_spec, flb_spec) = _pack_rounds(points, workloads, duration, options,
+                                       faults)
 
     with spans.span("sweep.dispatch"):
         out = roundslib.rounds_grids(
@@ -577,6 +600,11 @@ def _sweep_rounds(points: List[SweepPoint],
         r = np.rint(metrics["rounds"]).astype(np.int64)
         spans.count("rounds.lane_rounds", int(r.sum()))
         spans.count("rounds.lane_slots", int(r.size * r.max()))
+    if "fault_rounds" in out.get("fb", {}):
+        spans.count("rounds.fault_rounds",
+                    int(np.rint(out["fb"]["fault_rounds"]).sum()))
+        spans.count("faults.kills",
+                    int(np.rint(out["fb"]["fault_kills"]).sum()))
     rows = _assemble_rows(points, fb_idx, flb_idx, out, len(workloads),
                           "rounds")
     _warn_diagnostics(rows, "rounds", stacklevel=warn_stacklevel)
@@ -649,6 +677,34 @@ def _sweep_rounds_generated(points: List[SweepPoint], grid,
 
 # --------------------------------------------------------------- the sweep
 
+def _check_faults(points: Sequence[SweepPoint], workloads, faults,
+                  mode: str) -> Optional[List]:
+    """``faults`` as a list aligned with ``workloads``, or ``None`` when
+    no schedule holds an event; raises where a schedule cannot run."""
+    if faults is None:
+        return None
+    faults = list(faults)
+    if not any(f is not None and len(f) for f in faults):
+        return None
+    from repro.sim import scenarios as scenarioslib
+    if isinstance(workloads, scenarioslib.ScenarioGrid):
+        raise ValueError("fault schedules run over listed workloads, not a "
+                         "generated ScenarioGrid")
+    if len(faults) != len(workloads):
+        raise ValueError(f"faults ({len(faults)}) must align with workloads "
+                         f"({len(workloads)})")
+    other = sorted({p.system for p in points if p.system != "fb"})
+    if other:
+        raise ValueError(
+            f"fault schedules run FB points only (the rounds engine folds "
+            f"failures for FB alone), got {other}; run those points "
+            f"without faults or one at a time through run_sim(faults=)")
+    if mode == "scan":
+        raise ValueError("fault schedules run mode 'rounds', 'auto' or "
+                         "'event', not 'scan'")
+    return faults
+
+
 def _resolve_mode(mode: Optional[str], vectorize: bool) -> str:
     if mode is None:
         return "auto" if vectorize else "event"
@@ -709,6 +765,7 @@ def run_sweep_workloads(points: Sequence[SweepPoint],
                         mode: Optional[str] = None,
                         scan_options: ScanOptions = ScanOptions(),
                         devices: compat.Devices = None,
+                        faults: Optional[Sequence] = None,
                         _stack_offset: int = 0
                         ) -> List[List[Dict]]:
     """Evaluate a sweep grid over SEVERAL workload traces at once.
@@ -731,6 +788,17 @@ def run_sweep_workloads(points: Sequence[SweepPoint],
     ``devices`` when set); only FB / FLB-NUB points are supported and
     the grid fixes the horizon (``duration`` must stay ``None``).
 
+    ``faults``, when given, holds one
+    :class:`repro.sim.faults.FaultSchedule` or ``None`` per workload:
+    node failures and repairs on that workload's site (the chaos tier).
+    They run FB points only, through the rounds engine (one device call
+    with the other workloads, fault instants as loop stops and the
+    capacity ``max(C - failed(t), 0)``) or, in ``mode="event"`` and for
+    the points ``"auto"`` sends there, through ``run_sim(faults=)``. A
+    schedule beside a DCS, EC2 or FLB-NUB point, or in ``mode="scan"``,
+    raises ``ValueError``. ``None``, or no schedule with an event,
+    leaves every path as it is without faults.
+
     ``_stack_offset`` (private) is the number of wrapper frames between
     the user's call site and this function; diagnostic
     ``RuntimeWarning``\\ s use it to attribute the warning to the
@@ -747,6 +815,7 @@ def run_sweep_workloads(points: Sequence[SweepPoint],
         if devices is not None:
             scan_options = dataclasses.replace(scan_options, devices=devices)
         from repro.sim import scenarios as scenarioslib
+        faults = _check_faults(points, workloads, faults, mode)
         if isinstance(workloads, scenarioslib.ScenarioGrid):
             # Generated scenario batches (keys + param grids, not
             # List[Job]) flow the event-round engine only: the lanes share
@@ -802,11 +871,17 @@ def run_sweep_workloads(points: Sequence[SweepPoint],
                 batch_idx = [i for i in batch_idx
                              if not (points[i].system == "fb"
                                      and points[i].params.checkpoint_preempt)]
-            fast = _sweep_scan if mode == "scan" else _sweep_rounds
             if batch_idx:
-                fast_rows = fast([points[i] for i in batch_idx],
-                                 workloads, duration, scan_options,
-                                 warn_stacklevel=warn_stacklevel)
+                batch = [points[i] for i in batch_idx]
+                if mode == "scan":
+                    fast_rows = _sweep_scan(batch, workloads, duration,
+                                            scan_options,
+                                            warn_stacklevel=warn_stacklevel)
+                else:
+                    fast_rows = _sweep_rounds(batch, workloads, duration,
+                                              scan_options,
+                                              warn_stacklevel=warn_stacklevel,
+                                              faults=faults)
                 for w in range(len(workloads)):
                     for j, i in enumerate(batch_idx):
                         rows[w][i] = fast_rows[w][j]
@@ -816,7 +891,8 @@ def run_sweep_workloads(points: Sequence[SweepPoint],
                 if rows[w][i] is not None:
                     continue
                 r = run_sim(_build(p), clone_jobs(jobs), ws_trace, duration,
-                            name=p.name())
+                            name=p.name(),
+                            faults=faults[w] if faults is not None else None)
                 row = r.row()
                 row.update(system_kind=p.system, engine="event",
                            lease_seconds=p.lease_seconds)

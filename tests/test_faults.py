@@ -19,6 +19,7 @@ What is pinned here:
   admission throttle, plus torn-checkpoint skip-and-restore.
 """
 
+import math
 import os
 
 import numpy as np
@@ -106,6 +107,50 @@ def test_generators_deterministic_and_replayable():
     m = merge_schedules(a, bu, None)
     assert len(m) == len(a) + len(bu)
     assert np.all(np.diff(m.times) >= 0)
+
+
+def test_lognormal_repairs_keep_the_mean_asked_for():
+    """The lognormal repair draw (log-sigma given) has the mean repair
+    time asked for, in both generators that take it."""
+    mttr = 6 * 3600.0
+    bu = burst_schedule(seed=5, k=3, mtbf=3600.0, mttr=mttr,
+                        duration=20000 * 3600.0, repair="lognormal",
+                        repair_sigma=1.0)
+    down = bu.times[1::2] - bu.times[0::2]
+    assert len(down) > 2000
+    assert np.mean(down) == pytest.approx(mttr, rel=0.05)
+    # Lognormal, not exponential: the log-times' spread is sigma.
+    assert np.std(np.log(down)) == pytest.approx(1.0, rel=0.05)
+    w = weibull_schedule(seed=6, n_nodes=1, mtbf=3600.0, mttr=mttr,
+                         duration=20000 * 3600.0, shape=0.7,
+                         repair="lognormal", repair_sigma=0.5)
+    down = w.times[1::2] - w.times[0::2]
+    assert len(down) > 2000
+    assert np.mean(down) == pytest.approx(mttr, rel=0.05)
+    with pytest.raises(ValueError, match="repair distribution"):
+        burst_schedule(seed=5, k=3, mtbf=3600.0, mttr=mttr, duration=DAY,
+                       repair="gamma")
+
+
+def test_default_repairs_replay_the_exponential_draws():
+    """The default repair stays exponential: schedules replay the same
+    draws, in the same order, as the renewal written out here."""
+    mtbf, mttr, shape = 6 * 3600.0, 1800.0, 1.5
+    rng = np.random.Generator(np.random.PCG64(3))
+    scale = mtbf / math.gamma(1.0 + 1.0 / shape)
+    events = []
+    for _ in range(8):
+        t = scale * rng.weibull(shape)
+        while t < DAY:
+            events.append((t, 1))
+            r = t + max(rng.exponential(mttr), 1e-6)
+            events.append((r, -1))
+            t = r + max(scale * rng.weibull(shape), 1e-6)
+    events.sort(key=lambda e: e[0])
+    w = weibull_schedule(seed=3, n_nodes=8, mtbf=mtbf, mttr=mttr,
+                         duration=DAY, shape=shape)
+    assert np.array_equal(w.times, [t for t, _ in events])
+    assert np.array_equal(w.deltas, [d for _, d in events])
 
 
 def test_schedule_clamp_matches_ledger():

@@ -109,6 +109,34 @@ def test_rounds_program_compiles_for_v5e(policy, paper_pack, one_chip,
     assert mem.temp_size_in_bytes < 16 * 2 ** 30      # fits one chip
 
 
+def test_faulted_fb_program_compiles_for_v5e(one_chip, no_persistent_cache):
+    """FB under failures, as ``run_sweep_workloads(faults=)`` stacks it:
+    the paper's three workloads, each under a node-and-rack schedule,
+    with the fault tables at a fixed ``fault_stops`` — the program the
+    ``paper.faults`` cell runs, at two-week depth."""
+    from repro.sim.faults import (burst_schedule, merge_schedules,
+                                  weibull_schedule)
+    smoke = _chip_smoke()
+    horizon = traces.TWO_WEEKS
+    points = [SweepPoint("fb", capacity=192, lease_seconds=L)
+              for L in (900.0, 3600.0, 14400.0)]
+    faults = [merge_schedules(
+        weibull_schedule(s, 192, 6 * DAY, 6 * 3600.0, horizon, shape=0.7,
+                         repair="lognormal"),
+        burst_schedule(s + 1, 32, 6 * DAY, 4 * 3600.0, horizon,
+                       repair="lognormal")) for s in range(3)]
+    (_, _, fb, _, fb_packed, _, fb_spec, _) = _pack_rounds(
+        points, smoke.paper_workloads(horizon), horizon,
+        ScanOptions(fault_stops=2048, window=384), faults)
+    assert fb_packed.fault_times.shape == (3, 2049)
+    compiled = roundslib._rounds_grids_single.lower(
+        *_shapes((fb, None, fb_packed, None), one_chip),
+        fb_spec=fb_spec, flb_spec=None).compile()
+    out = compiled.out_info["fb"]
+    assert out["fault_rounds"].shape == out["fault_kills"].shape == (3, 3)
+    assert compiled.memory_analysis().temp_size_in_bytes < 16 * 2 ** 30
+
+
 def test_mosaic_refuses_the_fused_round_step(one_chip, no_persistent_cache):
     """Mosaic has no lowering for the round body's prefix sums: the
     compiled kernel (bypassing ``chunk_step``'s guard) is refused."""
