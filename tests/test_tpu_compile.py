@@ -107,6 +107,11 @@ def test_rounds_program_compiles_for_v5e(policy, paper_pack, one_chip,
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes > 0
     assert mem.temp_size_in_bytes < 16 * 2 ** 30      # fits one chip
+    # Compaction ranks kept lanes by compare-and-count on the TPU: no
+    # binary-search loop (one gather per level) is left in the program.
+    hlo = compiled.as_text()
+    assert "op_name=" in hlo
+    assert "jit(searchsorted)/vmap()/while" not in hlo
 
 
 def test_faulted_fb_program_compiles_for_v5e(one_chip, no_persistent_cache):
