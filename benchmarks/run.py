@@ -7,29 +7,21 @@ metric) and dump all rows to results/tables.json. The roofline table
 
 ``python -m benchmarks.run sweep`` instead benchmarks the sweep engine's
 execution paths against each other — per-point event engine vs the
-batched ``mode="scan"`` fast path vs the event-round ``mode="rounds"``
-engine vs their device-sharded variants — on the paper's FB / FLB-NUB
-grids (Figs. 13/14/18) across workload traces, writes
-``results/BENCH_sweep.json`` (wall-clock, points/sec, per-point
-fidelity drift for both fast engines) and, with ``--check-fidelity X``,
-exits non-zero when any scan point's completed-jobs or node-hours drift
-exceeds the fraction ``X`` or any rounds point misses its tighter
-contract (completed jobs exact, node-hours/peak within 5 %, sharded
-rows bit-identical) — the CI smoke gate. ``--perf-gate R`` additionally
-fails when the rounds engine's steady-state points/sec falls below
-``R ×`` the scan engine's (the regression gate; both engines share the
-per-step machinery, so a rounds-only slowdown is a real regression).
-``--tiny`` shrinks the study to a two-day trace slice for fast CI runs.
-``--devices N`` also times the shard_map backends over N devices; on a
-CPU-only host it sets ``XLA_FLAGS=--xla_force_host_platform_device_count=N``
-for you (all imports of jax are deferred until after the flag is in
-place, so one plain invocation measures real multi-core scaling). The
-run also asserts that no buffer-donation ("aliasing") warnings escaped
-the jitted fast paths, which donate nothing. ``--kernel pallas`` adds a
-``rounds_pallas`` column: the fused round-step backend
-(``repro.kernels.round_step``, interpret mode off-TPU) timed with
-separated ``compile_s``/``run_s`` walls and held to the same rounds
-contract plus bit-identity to the unfused rows.
+event-round ``mode="rounds"`` engine vs its device-sharded variant — on
+the paper's FB / FLB-NUB grids (Figs. 13/14/18) across workload traces,
+writes ``results/BENCH_sweep.json`` (wall-clock, points/sec, per-point
+fidelity drift) and, with ``--check-fidelity X``, exits non-zero when
+any rounds point's completed-jobs or node-hours drift exceeds the
+fraction ``X`` or misses the rounds contract (completed jobs exact,
+node-hours/peak within 5 %, sharded rows bit-identical) — the CI smoke
+gate. ``--tiny`` shrinks the study to a two-day trace slice for fast CI
+runs. ``--devices N`` also times the shard_map backend over N devices;
+on a CPU-only host it sets
+``XLA_FLAGS=--xla_force_host_platform_device_count=N`` for you (all
+imports of jax are deferred until after the flag is in place, so one
+plain invocation measures real multi-core scaling). The run also
+asserts that no buffer-donation ("aliasing") warnings escaped the
+jitted fast path, which donates nothing.
 
 ``python -m benchmarks.run scenarios`` benchmarks the generated-scenario
 path: on-device trace synthesis (``repro.sim.scenarios``) + the batched
@@ -47,11 +39,6 @@ engine, the rounds engine (time-varying capacity) and a ``LiveCloud``
 trace replay. ``--check-contract`` gates on ``CONTRACTS['faults']``,
 the no-lost-jobs invariant, and event-vs-live ledger identity; writes
 ``results/BENCH_faults.json``.
-
-``python -m benchmarks.run roundstep`` is the kernel microbenchmark:
-one fused vs one unfused outer step across vmapped lane widths
-(``--lanes``), bit-equality asserted at every width, written to
-``results/BENCH_roundstep.json``.
 """
 
 import argparse
@@ -135,15 +122,10 @@ def _timed(fn, reps: int = 3):
     return max(best, 1e-6), out
 
 
-def sweep_benchmark(tiny: bool = False, devices: int = 0,
-                    kernel: str = "xla") -> dict:
-    """Event engine vs batched scan vs event-round engine (plain and
-    coalesced, vs their sharded variants when ``devices >= 2``) on the
-    paper's coordinated-policy grids. ``kernel="pallas"`` ADDS a
-    ``rounds_pallas`` column — the fused round-step backend timed and
-    fidelity-gated alongside the regular engines (its rows must be
-    bit-identical to the unfused rounds rows). Returns the
-    BENCH_sweep.json payload."""
+def sweep_benchmark(tiny: bool = False, devices: int = 0) -> dict:
+    """Event engine vs the event-round engine (and its sharded variant
+    when ``devices >= 2``) on the paper's coordinated-policy grids.
+    Returns the BENCH_sweep.json payload."""
     import warnings
 
     import jax
@@ -202,19 +184,13 @@ def sweep_benchmark(tiny: bool = False, devices: int = 0,
                           label=f"FLB-NUB(L={m}min)")
                for m in (15, 30, 60, 120, 240)])             # Fig. 18
 
-    # Setup stage, timed honestly per engine family (the setup_s column
-    # the compile_s/run_s walls silently excluded): numpy trace
-    # synthesis, plus each family's host-side pack — job tables + WS
-    # profiles for the scan, job tables + WS fold tables for the rounds
-    # engines (cold fold-table cache per rep; the coalesced/pallas
-    # variants share the rounds pack — identical windows, identical
-    # arrays).
+    # Setup stage, timed on its own (the setup_s column the
+    # compile_s/run_s walls exclude): numpy trace synthesis, plus the
+    # rounds pack — job tables + WS fold tables (cold fold-table cache
+    # per rep).
     from repro.sim.rounds import fold_table_cache_clear
-    from repro.sim.sweep import _pack_rounds, _pack_scan
+    from repro.sim.sweep import _pack_rounds
     tracegen_s, workloads = _timed(build_workloads, reps=2)
-    scan_pack_s, _ = _timed(
-        lambda: _pack_scan(points, workloads, horizon, ScanOptions()),
-        reps=2)
 
     def _rounds_setup():
         fold_table_cache_clear()
@@ -233,51 +209,21 @@ def sweep_benchmark(tiny: bool = False, devices: int = 0,
     event_wall, event_rows = _timed(lambda: run_sweep_workloads(
         points, workloads, horizon, mode="event"), reps=2)
 
-    # The coalesced-rounds variant: contended stretches fold up to
-    # COALESCE_BATCH completions (plus riding arrivals) per event round
-    # via the bulk top-k/prefix-feasibility section of repro.sim.rounds.
-    from repro.sim.rounds import COALESCE_BATCH
-    coalesce_opts = ScanOptions(coalesce=COALESCE_BATCH)
-
-    # Any donation ("aliasing") warning from the jitted fast paths is a
-    # regression (they donate nothing) — record them and gate below.
-    pallas_opts = (ScanOptions(kernel="pallas") if kernel == "pallas"
-                   else None)
-
+    # Any donation ("aliasing") warning from the jitted fast path is a
+    # regression (it donates nothing) — record them and gate below.
     with warnings.catch_warnings(record=True) as caught:
         warnings.simplefilter("always")
-
-        scan_compile = warmup_sweep(points, workloads, horizon,
-                                    mode="scan")
-        scan_wall, scan_rows = _timed(lambda: run_sweep_workloads(
-            points, workloads, horizon, mode="scan"))
-
         rounds_compile = warmup_sweep(points, workloads, horizon,
                                       mode="rounds")
         rounds_wall, rounds_rows = _timed(lambda: run_sweep_workloads(
             points, workloads, horizon, mode="rounds"))
-
-        coal_compile = warmup_sweep(points, workloads, horizon,
-                                    mode="rounds",
-                                    scan_options=coalesce_opts)
-        coal_wall, coal_rows = _timed(lambda: run_sweep_workloads(
-            points, workloads, horizon, mode="rounds",
-            scan_options=coalesce_opts))
-
-        if pallas_opts is not None:
-            pallas_compile = warmup_sweep(points, workloads, horizon,
-                                          mode="rounds",
-                                          scan_options=pallas_opts)
-            pallas_wall, pallas_rows = _timed(lambda: run_sweep_workloads(
-                points, workloads, horizon, mode="rounds",
-                scan_options=pallas_opts))
     donation_warnings = [str(w.message) for w in caught
                          if "donat" in str(w.message).lower()
                          or "alias" in str(w.message).lower()]
 
     def _walls(compile_plus_run, wall):
         # compile_s is the warm-up wall minus one steady run — the jit
-        # trace + XLA (and Pallas) compile cost in isolation; the old
+        # trace + XLA compile cost in isolation; the old
         # compile_plus_run_s column stays for ledger continuity.
         return {"compile_plus_run_s": round(compile_plus_run, 4),
                 "compile_s": round(max(compile_plus_run - wall, 0.0), 4),
@@ -285,73 +231,14 @@ def sweep_benchmark(tiny: bool = False, devices: int = 0,
 
     out["event"] = {"wall_s": round(event_wall, 4),
                     "points_per_sec": round(n_evals / event_wall, 2)}
-    out["scan"] = {**_walls(scan_compile, scan_wall),
-                   "wall_s": round(scan_wall, 4),
-                   "points_per_sec": round(n_evals / scan_wall, 2)}
     out["rounds"] = {**_walls(rounds_compile, rounds_wall),
                      "wall_s": round(rounds_wall, 4),
                      "points_per_sec": round(n_evals / rounds_wall, 2),
-                     "speedup_vs_event": round(event_wall / rounds_wall, 2),
-                     "speedup_vs_scan": round(scan_wall / rounds_wall, 2)}
-    out["rounds_coalesced"] = {
-        "coalesce_batch": COALESCE_BATCH,
-        **_walls(coal_compile, coal_wall),
-        "wall_s": round(coal_wall, 4),
-        "points_per_sec": round(n_evals / coal_wall, 2),
-        "speedup_vs_event": round(event_wall / coal_wall, 2),
-        "speedup_vs_scan": round(scan_wall / coal_wall, 2),
-        "speedup_vs_rounds": round(rounds_wall / coal_wall, 2),
-        "max_rounds": max(r.get("rounds", 0)
-                          for rows_w in coal_rows for r in rows_w),
-        "max_rounds_uncoalesced": max(r.get("rounds", 0)
-                                      for rows_w in rounds_rows
-                                      for r in rows_w),
-        "coalesced_events": sum(r.get("coalesced", 0)
-                                for rows_w in coal_rows
-                                for r in rows_w),
-    }
-    if pallas_opts is not None:
-        from repro.kernels.ops import _default_interpret
-        out["rounds_pallas"] = {
-            **_walls(pallas_compile, pallas_wall),
-            "wall_s": round(pallas_wall, 4),
-            "points_per_sec": round(n_evals / pallas_wall, 2),
-            "speedup_vs_event": round(event_wall / pallas_wall, 2),
-            "speedup_vs_rounds": round(rounds_wall / pallas_wall, 2),
-            # Interpret mode (CPU) validates semantics, not speed — the
-            # compiled-kernel regime is GPU/TPU. Recorded so the ledger
-            # never passes an interpret wall off as a kernel wall.
-            "interpret": _default_interpret(),
-            # Both backends run the same _chunk_core math on the same
-            # inputs — any row difference is a packing bug.
-            "rows_match_rounds": pallas_rows == rounds_rows,
-        }
-    out["speedup"] = round(event_wall / scan_wall, 2)
+                     "speedup_vs_event": round(event_wall / rounds_wall, 2)}
     out["donation_warnings"] = donation_warnings
 
-    sharded_rows = rounds_sharded_rows = None
-    pallas_sharded_match = None
+    rounds_sharded_rows = None
     if devices and devices >= 2:
-        t0 = time.time()
-        run_sweep_workloads(points, workloads, horizon, mode="scan",
-                            devices=devices)
-        sharded_compile = time.time() - t0
-        sharded_wall, sharded_rows = _timed(lambda: run_sweep_workloads(
-            points, workloads, horizon, mode="scan", devices=devices),
-            reps=2)
-        out["scan_sharded"] = {
-            "devices": devices,
-            "compile_plus_run_s": round(sharded_compile, 4),
-            "compile_s": round(max(sharded_compile - sharded_wall, 0.0), 4),
-            "run_s": round(sharded_wall, 4),
-            "wall_s": round(sharded_wall, 4),
-            "points_per_sec": round(n_evals / sharded_wall, 2),
-            "speedup_vs_event": round(event_wall / sharded_wall, 2),
-            "speedup_vs_scan": round(scan_wall / sharded_wall, 2),
-            # The sharded backend runs the identical per-lane program —
-            # any row mismatch vs the single-device scan is a bug.
-            "rows_match_scan": sharded_rows == scan_rows,
-        }
         t0 = time.time()
         run_sweep_workloads(points, workloads, horizon, mode="rounds",
                             devices=devices)
@@ -369,63 +256,33 @@ def sweep_benchmark(tiny: bool = False, devices: int = 0,
             "points_per_sec": round(n_evals / rsh_wall, 2),
             "speedup_vs_event": round(event_wall / rsh_wall, 2),
             "speedup_vs_rounds": round(rounds_wall / rsh_wall, 2),
+            # The sharded backend runs the identical per-lane program —
+            # any row mismatch vs the single-device run is a bug.
             "rows_match_rounds": rounds_sharded_rows == rounds_rows,
         }
-        if pallas_opts is not None:
-            # The fused kernel's sharded leg: lanes split across host
-            # devices via the same sharded_grid_map (the vmapped
-            # pallas_call is just the per-lane program) — rows must stay
-            # bit-identical to the single-device fused run.
-            psh_compile = warmup_sweep(points, workloads, horizon,
-                                       mode="rounds",
-                                       scan_options=pallas_opts,
-                                       devices=devices)
-            psh_wall, psh_rows = _timed(
-                lambda: run_sweep_workloads(points, workloads, horizon,
-                                            mode="rounds",
-                                            scan_options=pallas_opts,
-                                            devices=devices), reps=2)
-            pallas_sharded_match = psh_rows == pallas_rows
-            out["rounds_pallas_sharded"] = {
-                "devices": devices,
-                "compile_plus_run_s": round(psh_compile, 4),
-                "compile_s": round(max(psh_compile - psh_wall, 0.0), 4),
-                "run_s": round(psh_wall, 4),
-                "wall_s": round(psh_wall, 4),
-                "points_per_sec": round(n_evals / psh_wall, 2),
-                "rows_match_pallas": pallas_sharded_match,
-            }
 
     # Every engine row reports its setup cost: trace synthesis for the
-    # event engine, plus the family's pack stage for the fast paths
-    # (sharded variants share their family's pack — the pack is
-    # device-count independent).
+    # event engine, plus the rounds pack for the fast path (the sharded
+    # variant shares it — the pack is device-count independent).
     for key, engine in list(out.items()):
         if isinstance(engine, dict) and "points_per_sec" in engine:
-            if key.startswith("scan"):
-                engine["setup_s"] = round(tracegen_s + scan_pack_s, 4)
-            elif key.startswith("rounds"):
+            if key.startswith("rounds"):
                 engine["setup_s"] = round(tracegen_s + rounds_pack_s, 4)
             else:                                  # the event engine
                 engine["setup_s"] = round(tracegen_s, 4)
 
     out["backend"] = {"devices": [str(d) for d in jax.devices()],
                       "cpu_count": os.cpu_count()}
-    out["note"] = ("all fast paths are jitted XLA programs batched over "
-                   "the (policy, point) grid — compute-bound per lane, so "
-                   "their speedup over the per-point Python event engine "
-                   "scales with the host's cores/SIMD/accelerator. scan "
-                   "advances every lane on a fixed dt; rounds jumps "
-                   "lane-by-lane to the next event (exact completions and "
-                   "allocation integrals — see its tighter drift columns). "
-                   "On the paper traces the event density matches the "
-                   "scan's substep density, so the engines run at similar "
-                   "wall-clock; the rounds engine pulls ahead on demand "
-                   "traces finer than the scan's FLB_MIN_DT floor, and "
-                   "its fidelity contract (completed exact, <=5% "
-                   "node-hours/peak) holds everywhere. *_sharded split "
-                   "the lanes across host devices (shard_map) and must "
-                   "report bit-identical rows")
+    out["note"] = ("rounds is a jitted XLA program batched over the "
+                   "(policy, point, workload) grid — compute-bound per "
+                   "lane, so its speedup over the per-point Python event "
+                   "engine scales with the host's cores/SIMD/accelerator. "
+                   "It jumps lane-by-lane to the next event (exact "
+                   "completions and allocation integrals), and its "
+                   "fidelity contract (completed exact, <=5% "
+                   "node-hours/peak) holds everywhere. rounds_sharded "
+                   "splits the lanes across host devices (shard_map) and "
+                   "must report bit-identical rows")
 
     def _drift(rows):
         worst, comparisons = [], []
@@ -454,54 +311,30 @@ def sweep_benchmark(tiny: bool = False, devices: int = 0,
                     "drift_peak": round(dp, 4)})
         return worst, comparisons
 
-    def _fidelity(rows, cmp_rows):
-        return {
-            "completed_jobs_exact": all(c["jobs_exact"] for c in cmp_rows),
-            "max_drift_node_hours": round(max(c["drift_node_hours"]
-                                              for c in cmp_rows), 4),
-            "max_drift_peak": round(max(c["drift_peak"]
-                                        for c in cmp_rows), 4),
-            "truncated_lanes": sum(r.get("truncated", 0)
-                                   for rows_w in rows for r in rows_w),
-        }
-
-    scan_drift, scan_cmp = _drift(scan_rows)
     rounds_drift, rounds_cmp = _drift(rounds_rows)
-    _, coal_cmp = _drift(coal_rows)
-    out["max_drift"] = round(max(scan_drift), 4)
-    out["rounds_fidelity"] = _fidelity(rounds_rows, rounds_cmp)
-    out["rounds_coalesced_fidelity"] = _fidelity(coal_rows, coal_cmp)
-    if sharded_rows is not None and not out["scan_sharded"]["rows_match_scan"]:
+    out["max_drift"] = round(max(rounds_drift), 4)
+    out["rounds_fidelity"] = {
+        "completed_jobs_exact": all(c["jobs_exact"] for c in rounds_cmp),
+        "max_drift_node_hours": round(max(c["drift_node_hours"]
+                                          for c in rounds_cmp), 4),
+        "max_drift_peak": round(max(c["drift_peak"]
+                                    for c in rounds_cmp), 4),
+        "truncated_lanes": sum(r.get("truncated", 0)
+                               for rows_w in rounds_rows for r in rows_w),
+    }
+    sharded_match = (rounds_sharded_rows is None
+                     or out["rounds_sharded"]["rows_match_rounds"])
+    if not sharded_match:
         # Surface a sharding bug through the same CI gate as fidelity.
         out["max_drift"] = max(out["max_drift"], 1.0)
-    out["comparisons"] = scan_cmp
     out["rounds_comparisons"] = rounds_cmp
     # The rounds contract (thresholds imported from
     # repro.sim.contracts — the table the tests assert), folded into
-    # one gate flag per engine variant: completed jobs exact,
-    # node-hours and peak within the contract band, sharded rows
-    # bit-identical, no lane truncation, no donation warnings. The
-    # coalesced variant must satisfy the SAME contract — the coalescer
-    # may never buy speed with fidelity.
+    # one gate flag: completed jobs exact, node-hours and peak within
+    # the contract band, sharded rows bit-identical, no lane
+    # truncation, no donation warnings.
     out["rounds_contract_ok"] = rounds_contract_ok(
-        out["rounds_fidelity"], donation_warnings,
-        rounds_sharded_rows is None
-        or out["rounds_sharded"]["rows_match_rounds"])
-    # The coalesced sharded-identity leg is pinned by
-    # tests/test_sweep_sharded.py (subprocess, 2 forced devices), not
-    # re-timed here — True stands for "covered elsewhere".
-    out["rounds_coalesced_contract_ok"] = rounds_contract_ok(
-        out["rounds_coalesced_fidelity"], donation_warnings, True)
-    if pallas_opts is not None:
-        # The fused kernel answers to the SAME contract as the engine it
-        # fuses, plus bit-identity to the unfused rows (and to its own
-        # sharded run when a sharded leg was timed).
-        _, pallas_cmp = _drift(pallas_rows)
-        out["rounds_pallas_fidelity"] = _fidelity(pallas_rows, pallas_cmp)
-        out["rounds_pallas_contract_ok"] = bool(rounds_contract_ok(
-            out["rounds_pallas_fidelity"], donation_warnings,
-            pallas_sharded_match is None or pallas_sharded_match)
-            and out["rounds_pallas"]["rows_match_rounds"])
+        out["rounds_fidelity"], donation_warnings, sharded_match)
     return out
 
 
@@ -510,66 +343,36 @@ def run_sweep_bench(argv) -> int:
     ap.add_argument("--tiny", action="store_true",
                     help="two-day trace slice, 4-point grid (CI smoke)")
     ap.add_argument("--devices", type=int, default=0, metavar="N",
-                    help="also time the sharded fast paths over N host "
+                    help="also time the sharded rounds path over N host "
                     "devices (forces N XLA CPU devices when jax is not "
                     "yet loaded)")
     ap.add_argument("--check-fidelity", type=float, default=None,
-                    metavar="FRAC", help="exit 1 if any scan point's "
+                    metavar="FRAC", help="exit 1 if any rounds point's "
                     "completed-jobs or node-hours drift exceeds FRAC, or "
                     "the rounds contract (jobs exact, node-hours/peak "
-                    "within 5%%, sharded rows identical) fails — with "
-                    "--kernel pallas the fused column answers to the "
-                    "same contract plus bit-identity to unfused rows")
-    ap.add_argument("--perf-gate", type=float, default=None, metavar="R",
-                    help="exit 1 if the (unfused) rounds engine's "
-                    "steady-state points/sec drops below R x the scan "
-                    "engine's")
-    ap.add_argument("--kernel", choices=("xla", "pallas"), default="xla",
-                    help="'pallas' additionally times the fused "
-                    "round-step kernel as a rounds_pallas column "
-                    "(interpret mode off-TPU)")
+                    "within 5%%, sharded rows identical) fails")
     ap.add_argument("--out", default="results/BENCH_sweep.json")
     args = ap.parse_args(argv)
     if args.devices >= 2:
         from repro.hostdev import force_host_device_count
         force_host_device_count(args.devices)
     _enable_compile_cache()
-    out = sweep_benchmark(tiny=args.tiny, devices=args.devices,
-                          kernel=args.kernel)
+    out = sweep_benchmark(tiny=args.tiny, devices=args.devices)
     os.makedirs(os.path.dirname(args.out) or ".", exist_ok=True)
     with open(args.out, "w") as f:
         json.dump(out, f, indent=1)
     rd = out["rounds"]
-    rco = out["rounds_coalesced"]
     line = (f"evals={out['evals']} event={out['event']['wall_s']}s "
             f"({out['event']['points_per_sec']} pts/s) "
-            f"scan={out['scan']['wall_s']}s "
-            f"({out['scan']['points_per_sec']} pts/s) "
             f"rounds={rd['wall_s']}s ({rd['points_per_sec']} pts/s, "
             f"{rd['speedup_vs_event']}x event) "
-            f"rounds_coalesced[{rco['coalesce_batch']}]={rco['wall_s']}s "
-            f"({rco['points_per_sec']} pts/s, max_rounds "
-            f"{rco['max_rounds_uncoalesced']}->{rco['max_rounds']}) "
-            f"max_drift(scan)={out['max_drift']} "
-            f"rounds_contract_ok={out['rounds_contract_ok']} "
-            f"coalesced_contract_ok={out['rounds_coalesced_contract_ok']}")
-    if "rounds_pallas" in out:
-        rp = out["rounds_pallas"]
-        line += (f" rounds_pallas={rp['run_s']}s "
-                 f"(compile {rp['compile_s']}s, interpret "
-                 f"{rp['interpret']}, rows_match="
-                 f"{rp['rows_match_rounds']}, contract_ok="
-                 f"{out['rounds_pallas_contract_ok']})")
-    for key, base in (("scan_sharded", "scan"),
-                      ("rounds_sharded", "rounds"),
-                      ("rounds_pallas_sharded", "rounds_pallas")):
-        if key in out:
-            sh = out[key]
-            match = sh.get("rows_match_scan",
-                           sh.get("rows_match_rounds",
-                                  sh.get("rows_match_pallas")))
-            line += (f" {key}[{sh['devices']}]={sh['wall_s']}s "
-                     f"({sh['points_per_sec']} pts/s, rows_match={match})")
+            f"max_drift={out['max_drift']} "
+            f"rounds_contract_ok={out['rounds_contract_ok']}")
+    if "rounds_sharded" in out:
+        sh = out["rounds_sharded"]
+        line += (f" rounds_sharded[{sh['devices']}]={sh['wall_s']}s "
+                 f"({sh['points_per_sec']} pts/s, "
+                 f"rows_match={sh['rows_match_rounds']})")
     print(line)
     print(f"# -> {args.out}")
     rc = 0
@@ -582,23 +385,6 @@ def run_sweep_bench(argv) -> int:
             print(f"ROUNDS CONTRACT FAILED: {out['rounds_fidelity']} "
                   f"donation_warnings={out['donation_warnings']}",
                   file=sys.stderr)
-            rc = 1
-        if not out["rounds_coalesced_contract_ok"]:
-            print(f"COALESCED ROUNDS CONTRACT FAILED: "
-                  f"{out['rounds_coalesced_fidelity']}", file=sys.stderr)
-            rc = 1
-        if "rounds_pallas" in out and not out["rounds_pallas_contract_ok"]:
-            print(f"PALLAS ROUNDS CONTRACT FAILED: "
-                  f"{out['rounds_pallas_fidelity']} rows_match="
-                  f"{out['rounds_pallas']['rows_match_rounds']}",
-                  file=sys.stderr)
-            rc = 1
-    if args.perf_gate is not None:
-        ratio = rd["points_per_sec"] / max(out["scan"]["points_per_sec"],
-                                           1e-9)
-        if ratio < args.perf_gate:
-            print(f"PERF GATE: rounds at {ratio:.2f}x scan points/sec, "
-                  f"below the {args.perf_gate}x gate", file=sys.stderr)
             rc = 1
     return rc
 
@@ -817,104 +603,6 @@ def run_scenarios_bench(argv) -> int:
     return rc
 
 
-def roundstep_benchmark(lane_widths=(1, 4, 16, 64), reps: int = 3) -> dict:
-    """Microbenchmark of the fused Pallas round-step kernel vs the
-    unfused traced body: ONE outer step (compaction + admission + the
-    ``compact_every`` unrolled rounds) on a real packed trace lane,
-    vmapped across ``lane_widths`` lane counts — the per-op dispatch
-    floor the fusion attacks, isolated from the while_loop. Also
-    asserts the two backends' packed outputs are bit-identical at every
-    width. Returns the BENCH_roundstep.json payload."""
-    import numpy as np
-
-    import jax
-    import jax.numpy as jnp
-    from repro.kernels import round_step as rsk
-    from repro.kernels.ops import _default_interpret
-    from repro.sim import rounds as roundslib
-    from repro.sim import traces
-
-    horizon = 2 * 24 * 3600.0
-    jobs = [j for j in traces.nasa_ipsc(seed=0) if j.submit < horizon]
-    ws = [(t, d) for t, d in traces.worldcup98(seed=0, peak_vms=64)
-          if t < horizon]
-    K = roundslib.FB_ROUNDS_WINDOW
-    spec = roundslib.RoundsSpec(
-        duration=horizon,
-        max_rounds=roundslib.round_budget(len(jobs), len(ws), horizon,
-                                          3600.0),
-        window=K, kernel="pallas")
-    pk = jax.tree_util.tree_map(
-        lambda a: a[0], roundslib.pack_event_workloads(
-            [(jobs, ws)], horizon, K, "fb", leases=[3600.0], levels=[96]))
-    prm = {"lease": jnp.asarray(3600.0, pk.submit.dtype),
-           "capacity": jnp.asarray(96.0, pk.submit.dtype),
-           "p_idx": jnp.asarray(0, jnp.int32)}
-    ctx = roundslib._lane_ctx("fb", prm, pk)
-    inputs = rsk.lane_inputs("fb", ctx)
-    f = pk.submit.dtype
-    zero = jnp.zeros((), f)
-    acc = {k: zero for k in roundslib.ACC_KEYS}
-    core0 = (zero, jnp.asarray(64.0, f), zero, zero,
-             jnp.asarray(False), pk.ws0, jnp.asarray(64.0, f),
-             jnp.asarray(0, jnp.int32), jnp.asarray(K, jnp.int32),
-             pk.submit[:K], pk.size[:K], pk.runtime[:K],
-             jnp.zeros(K, bool), jnp.zeros(K, bool), jnp.zeros(K, f),
-             jnp.zeros(K, f), acc)
-    sc1, win1 = rsk.pack_carry(core0)
-
-    def step(fn):
-        return jax.jit(jax.vmap(
-            lambda sc, win: fn(*inputs, sc, win, policy="fb", spec=spec),
-            in_axes=(0, 0)))
-
-    fused, ref = step(rsk.chunk_step), step(rsk.chunk_step_ref)
-    out = {"window": K, "compact_every": spec.compact_every,
-           "interpret": _default_interpret(), "policy": "fb",
-           "trace_jobs": len(jobs), "widths": []}
-    for n in lane_widths:
-        sc = jnp.broadcast_to(sc1, (n,) + sc1.shape)
-        win = jnp.broadcast_to(win1, (n,) + win1.shape)
-        row = {"lanes": int(n)}
-        results = {}
-        for name, fn in (("fused", fused), ("ref", ref)):
-            t0 = time.time()
-            r = jax.block_until_ready(fn(sc, win))
-            row[f"{name}_compile_plus_run_s"] = round(time.time() - t0, 4)
-            wall, r = _timed(lambda: jax.block_until_ready(fn(sc, win)),
-                             reps=reps)
-            row[f"{name}_run_s"] = round(wall, 5)
-            results[name] = r
-        row["bit_equal"] = all(
-            bool(jnp.array_equal(a, b)) for a, b in
-            zip(jax.tree_util.tree_leaves(results["fused"]),
-                jax.tree_util.tree_leaves(results["ref"])))
-        row["fused_vs_ref"] = round(
-            row["ref_run_s"] / max(row["fused_run_s"], 1e-9), 2)
-        out["widths"].append(row)
-    return out
-
-
-def run_roundstep_bench(argv) -> int:
-    ap = argparse.ArgumentParser(prog="benchmarks.run roundstep")
-    ap.add_argument("--lanes", type=int, nargs="+",
-                    default=[1, 4, 16, 64], metavar="N",
-                    help="vmapped lane counts to time")
-    ap.add_argument("--out", default="results/BENCH_roundstep.json")
-    args = ap.parse_args(argv)
-    _enable_compile_cache()
-    out = roundstep_benchmark(lane_widths=tuple(args.lanes))
-    os.makedirs(os.path.dirname(args.out) or ".", exist_ok=True)
-    with open(args.out, "w") as fjson:
-        json.dump(out, fjson, indent=1)
-    for row in out["widths"]:
-        print(f"lanes={row['lanes']} fused={row['fused_run_s']}s "
-              f"ref={row['ref_run_s']}s ({row['fused_vs_ref']}x, "
-              f"bit_equal={row['bit_equal']})")
-    print(f"# interpret={out['interpret']} -> {args.out}")
-    return 0 if all(r["bit_equal"] for r in out["widths"]) else 1
-
-
 def live_benchmark(tiny: bool = False, serve_dt: float = 30.0) -> dict:
     """Live-vs-sim differential: replay WS traces as request traffic
     through the serving stack (``repro.serving.replay`` — autoscaler +
@@ -1032,7 +720,8 @@ def faults_benchmark(tiny: bool = False, serve_dt: float = 30.0) -> dict:
     through the repo's three execution paths and cross-checked:
 
       * event engine (plain kill mode) vs the rounds engine's
-        time-varying-capacity fold (``fb_rounds_row``), under
+        time-varying-capacity fold (a one-point
+        ``run_sweep_workloads``), under
         ``CONTRACTS['faults']`` (node-hours/peak 2 %, completions
         ±2-jobs-or-2 %);
       * event engine (checkpoint-preempt mode) vs a ``LiveCloud`` trace
@@ -1056,7 +745,7 @@ def faults_benchmark(tiny: bool = False, serve_dt: float = 30.0) -> dict:
     from repro.sim.faults import (burst_schedule, exponential_schedule,
                                   merge_schedules)
     from repro.sim.pump import DecisionLedger
-    from repro.sim.rounds import fb_rounds_row
+    from repro.sim.sweep import SweepPoint, run_sweep_workloads
 
     day = 24 * 3600.0
     horizon = day if tiny else 2 * day
@@ -1105,8 +794,10 @@ def faults_benchmark(tiny: bool = False, serve_dt: float = 30.0) -> dict:
         lost = no_lost_jobs(ev_jobs, ev_sys)
         # Rounds engine: fault instants folded into the horizon min,
         # capacity time-varying.
-        wall_rr, rr = _timed(lambda: fb_rounds_row(
-            jobs, ws, capacity, lease, horizon, faults=sched), reps=1)
+        point = SweepPoint("fb", capacity=capacity, lease_seconds=lease)
+        wall_rr, rr = _timed(lambda: run_sweep_workloads(
+            [point], [(jobs, ws)], horizon, mode="rounds",
+            faults=[sched])[0][0], reps=1)
         violations = contract.check_row(rr, ev.row())
         # Checkpoint-restart recovery: event(ckpt) vs LiveCloud trace
         # replay of the same schedule — one pump, exact ledgers.
@@ -1547,8 +1238,6 @@ def main() -> int:
 if __name__ == "__main__":
     if len(sys.argv) > 1 and sys.argv[1] == "sweep":
         sys.exit(run_sweep_bench(sys.argv[2:]))
-    if len(sys.argv) > 1 and sys.argv[1] == "roundstep":
-        sys.exit(run_roundstep_bench(sys.argv[2:]))
     if len(sys.argv) > 1 and sys.argv[1] == "scenarios":
         sys.exit(run_scenarios_bench(sys.argv[2:]))
     if len(sys.argv) > 1 and sys.argv[1] == "live":

@@ -5,9 +5,8 @@
     python chip_smoke.py --chips 4    # phase b sharded over 4 chips vs one
 
 Every phase goes through the public sweep API (``run_sweep_workloads``,
-``run_sweep``, ``min_capacity``) with ``mode="rounds"`` and the default
-``kernel="xla"``, at the paper's size: a 256-node site and two-week
-traces generated from seeds.
+``run_sweep``, ``min_capacity``) with ``mode="rounds"``, at the paper's
+size: a 256-node site and two-week traces generated from seeds.
 
   a. Paper grid: the three two-week workloads of ``benchmarks.run
      sweep`` times the 15 FB / FLB-NUB points of Figs. 13/14/18 (45

@@ -13,11 +13,9 @@ stateful PhoenixCloud policies run:
          path rejects
   rounds FB / FLB-NUB batched through the jump-to-next-event engine
          (completed jobs exact, node-hours/peak within 5 %)
-  scan   FB / FLB-NUB batched through one fixed-dt jitted lax.scan
-         (approximate: jobs ±2 %, node-hours ±15 %, trends exact)
   event  everything on the event engine (the cross-validation reference)
 
-``--devices N`` shards the batched paths' point lanes across N host
+``--devices N`` shards the batched path's point lanes across N host
 devices (forcing N XLA CPU devices when needed) — the multi-core
 backend of the sweep engine.
 
@@ -42,17 +40,17 @@ ap.add_argument("--mode", default="auto",
                 help="execution path for the FB / FLB-NUB points")
 ap.add_argument("--devices", type=int, default=0,
                 help="shard the batched-path lanes across N host devices "
-                "(requires a batched mode: auto, scan or rounds)")
+                "(requires a batched mode: auto or rounds)")
 ap.add_argument("--queries", action="store_true",
                 help="also run the capacity query layer: min-C "
                 "bisection, Pareto frontier and the cost lens")
 args = ap.parse_args()
 
 if args.devices >= 2:
-    if args.mode not in ("auto", "scan", "rounds"):
-        # Only the batched paths consume the devices option — anything
+    if args.mode not in ("auto", "rounds"):
+        # Only the batched path consumes the devices option — anything
         # else would silently run unsharded.
-        ap.error("--devices requires a batched mode (auto, scan, rounds)")
+        ap.error("--devices requires a batched mode (auto, rounds)")
     from repro.hostdev import force_host_device_count
     force_host_device_count(args.devices)
 

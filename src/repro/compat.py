@@ -40,9 +40,8 @@ def enable_compile_cache() -> str:
 def resolve_pack_dtype(dtype=None):
     """Default a packing dtype to the active jax x64 setting; reject a
     float64 request that ``jnp.asarray`` would silently downcast. The
-    one canonical copy for every pack path (``repro.sim.scan``,
-    ``repro.sim.rounds``, ``repro.sim.scenarios``,
-    ``repro.core.jaxsim``)."""
+    one canonical copy for every pack path (``repro.sim.rounds``,
+    ``repro.sim.scenarios``, ``repro.core.jaxsim``)."""
     import numpy as np
     if dtype is None:
         return np.float64 if jax.config.jax_enable_x64 else np.float32

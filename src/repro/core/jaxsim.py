@@ -9,11 +9,10 @@ runs as ONE batched XLA program instead of 20 sequential event-driven
 simulations. This is the paper's contribution as a *composable JAX
 module* (DESIGN.md §3).
 
-The sweep engine generalizes this approach: ``repro.sim.scan`` extends
-the tick-simulator idea with a sliding job window, the FB kill path and
-a traced lease axis, and ``repro.sim.sweep`` exposes it as
-``run_sweep(..., mode="scan")`` over full ``SweepPoint`` grids and
-batched workload traces. This module remains the minimal, fixed-lease
+The sweep engine's event-round path (``repro.sim.rounds``, exposed as
+``run_sweep(..., mode="rounds")``) replaces the fixed tick with exact
+event times over a sliding job window, with the FB kill path and a
+traced lease axis. This module remains the minimal, fixed-lease
 B × U × V × G study (§6.6.4) in its simplest vmappable form.
 
 Approximations vs the event simulator (both documented and measured in

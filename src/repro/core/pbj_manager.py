@@ -29,10 +29,10 @@ class PBJPolicyParams:
 
     A jax pytree (U/V/G are data leaves, the preemption mode is static
     metadata) so policy parameters flow directly into the jitted sweep
-    paths — ``repro.sim.scan`` builds its vmapped U/V/G grids from these
-    fields, and a batch of params can itself be ``tree_map``-ed or
-    stacked for parameter studies. The registration lives in
-    ``repro.sim.scan`` (the jax-side consumer): this module stays
+    paths — ``repro.sim.sweep`` builds the rounds engine's vmapped U/V/G
+    grids from these fields, and a batch of params can itself be
+    ``tree_map``-ed or stacked for parameter studies. The registration
+    lives in ``repro.sim.lanes`` (the jax-side consumer): this module stays
     importable with numpy alone, like the rest of the event engine.
     """
 

@@ -4,9 +4,7 @@ Every fast path of the sweep engine is validated against the
 discrete-event reference (``repro.sim.engine.run_sim``), each with its
 own tolerance band:
 
-* the fixed-``dt`` **scan** is approximate by time discretization —
-  completed jobs within 2 %, node-hours and peak within 15 %;
-* the event-round **rounds** engine (coalesced or not) replays events
+* the event-round **rounds** engine replays events
   at exact times — completed jobs must match EXACTLY (and completion
   times bit-match in float64), node-hours and peak within 5 % (the
   residue is first-fit pass convergence and §5.1 kill tie-breaking,
@@ -41,7 +39,7 @@ from __future__ import annotations
 import dataclasses
 
 __all__ = ["EngineContract", "LiveContract", "FaultContract",
-           "HeadlineContract", "SCAN_CONTRACT", "ROUNDS_CONTRACT",
+           "HeadlineContract", "ROUNDS_CONTRACT",
            "VECTORIZED_CONTRACT", "LIVE_CONTRACT", "FAULT_CONTRACT",
            "HEADLINE_CONTRACT", "CONTRACTS",
            "check_fidelity", "demand_drift", "no_lost_jobs"]
@@ -249,8 +247,6 @@ class HeadlineContract:
         return violations
 
 
-SCAN_CONTRACT = EngineContract(completed_rel=0.02, node_hours_rel=0.15,
-                               peak_rel=0.15)
 ROUNDS_CONTRACT = EngineContract(completed_rel=0.0, node_hours_rel=0.05,
                                  peak_rel=0.05, completed_exact=True)
 VECTORIZED_CONTRACT = EngineContract(completed_rel=0.0,
@@ -280,7 +276,6 @@ HEADLINE_CONTRACT = HeadlineContract()
 # Keyed by the ``engine`` tag run_sweep puts on each row; "queries"
 # keys the capacity layer's headline gate.
 CONTRACTS = {
-    "scan": SCAN_CONTRACT,
     "rounds": ROUNDS_CONTRACT,
     "vectorized": VECTORIZED_CONTRACT,
     "live": LIVE_CONTRACT,

@@ -17,8 +17,8 @@ The four paper systems (§6.3, §6.5, §6.6) are constructed by the
   * EC2+RightScale     — §6.6.1 (``core.baselines.EC2RightScaleSystem``)
 
 Parameter *sweeps* over grids of systems live in ``repro.sim.sweep``,
-which batches the stateless systems as exact vectorized JAX programs,
-offers a batched ``lax.scan`` fast path (``repro.sim.scan``) for the
+which batches the stateless systems as exact vectorized programs,
+offers a batched event-round fast path (``repro.sim.rounds``) for the
 stateful PhoenixCloud policies, and uses this engine as the per-point
 reference path (``mode="event"``) that every fast path is
 cross-validated against.

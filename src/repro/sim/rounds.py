@@ -1,11 +1,7 @@
 """Event-round fast path: jump-to-next-event steps for the stateful
 PhoenixCloud policies.
 
-The fixed-``dt`` scan (``repro.sim.scan``) advances every lane by the
-same substep whether or not anything happens in it, and rounds
-completions to the nearest substep. This module replaces the time grid
-with *event rounds*: each step of the jitted loop computes the next
-event horizon per lane —
+Each step of the jitted loop computes the next event horizon per lane —
 
     ``b = min(next submit, earliest completion among running lanes,
               next WS change the policy can react to,
@@ -14,20 +10,19 @@ event horizon per lane —
 — advances straight to it, and fires the policy tick only when the step
 lands on a lease boundary (the lease axis L stays *traced*, so Fig. 18
 sweeps it inside the batch). Completions happen at their exact times
-(``start + runtime``, no nearest-substep rounding) and every allocation
-interval integrates exactly, so the scan's 15 % fidelity contract
-collapses to the policy-approximation residue alone (first-fit pass
-convergence and FB kill tie-breaking): completed jobs match the event
-engine *exactly* and node-hours/peak stay within 5 % on the paper
-grids.
+(``start + runtime``) and every allocation interval integrates exactly,
+so the only residue against the event engine is the policy
+approximation (first-fit pass convergence and FB kill tie-breaking):
+completed jobs match the event engine *exactly* and node-hours/peak
+stay within 5 % on the paper grids.
 
 What counts as an event (the step-count economics)
 --------------------------------------------------
 
-A naive event list (every submit, completion and WS change) is *denser*
-than the scan's substep grid on the paper traces — the World Cup demand
-profile alone changes ~2.8k times in two weeks. The engine therefore
-jumps over every event whose effect is computable without stopping:
+A naive event list (every submit, completion and WS change) is dense on
+the paper traces — the World Cup demand profile alone changes ~2.8k
+times in two weeks. The engine therefore jumps over every event whose
+effect is computable without stopping:
 
 * **WS demand changes** never stop a lane. The WS share of the
   allocation is policy-independent, so its node-hour integral and its
@@ -49,56 +44,13 @@ jumps over every event whose effect is computable without stopping:
   finish can then start queued jobs); with an empty queue they fold
   retroactively at the next round, at their exact times.
 
-What remains is one round per lease tick plus the contended stretches —
-on the paper grids ~3-6× fewer steps than the scan's substep count, and
-each round is cheaper (no per-substep WS profile, a smaller window).
-On demand traces finer than the scan's ``FLB_MIN_DT`` floor the gap
-widens by another order of magnitude.
+What remains is one round per lease tick plus the contended stretches.
 
-The contended-stretch coalescer (``ScanOptions(coalesce=k)``)
-----------------------------------------------------------------
-
-Long queued periods drain one completion per round above — the
-dominant remaining round count on capacity-bound grids. With
-coalescing enabled, one round absorbs up to ``k`` such events via a
-loop-free bulk section: the next ``k`` distinct completion instants
-among running lanes are extracted as iterated masked mins (a sorted
-masked top-k; a ``lax.top_k`` sort probe measured ~6× the whole
-section's cost on XLA:CPU), queue admissions at each instant resolve
-through a prefix-sum feasibility test (arrival order is lane order, so
-a pending job starts at the first instant whose cumulative freed mass
-covers the pending jobs ahead of it plus itself, or at its own submit
-time), and the policy-owned allocation integral needs no per-instant
-work at all (the share is constant across a stretch — FB reclaims only
-at rises, which bound the horizon; FLB adjusts only at ticks). The
-closed form is proven exact per round or abandoned mid-round: a
-possible first-fit leapfrog (an unstarted pending job that fits a
-conservatively over-estimated free capacity at a replayed instant or
-at its own arrival), a chain event (a batch-started job completing
-inside the round), or the ``k`` cap each end the round exactly AT the
-first such instant, where the ordinary tail replays it with the full
-``ff_passes`` first-fit and the §5.1 kill machinery — so coalesced
-results carry the SAME fidelity contract as uncoalesced rounds (the
-differential suite pins bit-equality of the job metrics).
-
-Honest perf ledger: the bulk work is masked, not branched — vmapped
-point-lanes run in lockstep, so every round pays it whether or not a
-stretch is underway. On the 2-core CI box that tax exceeds what the
-saved rounds return on the paper-density grids (max rounds/lane drops
-6258 → 4047 yet wall-clock roughly doubles at k = 8 — see the
-``rounds_coalesced`` column of results/BENCH_sweep.json), which is
-why ``DEFAULT_BATCH = 1`` leaves the coalescer OFF unless requested.
-The reduction in *rounds* — the lockstep depth — is the real asset:
-it pays where per-round cost is dominated by the lane width (wide
-accelerator batches) or where traces make event rounds sparse and
-stretches long.
-
-The queue/kill machinery is shared with the scan engine: the same
-fixed-size job window with status lanes, vectorized first-fit and §5.1
-size-class kill selection (``repro.sim.scan.fb_actions`` /
-``flb_actions``), with lanes carrying an absolute ``end_t`` instead of
-a decremented remaining time — what makes completions exact and FB
-kill-restarts trivially correct (a restart rewrites ``end_t``).
+The queue/kill machinery lives in ``repro.sim.lanes``: the fixed-size
+job window with status lanes, vectorized first-fit and §5.1 size-class
+kill selection (``fb_actions`` / ``flb_actions``). Lanes carry an
+absolute ``end_t``, which makes completions exact and FB kill-restarts
+trivially correct (a restart rewrites ``end_t``).
 
 Loop structure: an outer ``while_loop`` step compacts the window (one
 stacked lane gather — the only data-movement op, amortized) and admits
@@ -114,9 +66,9 @@ tick's policy actions instead of around them — a measure-zero
 coincidence on real-valued traces.
 
 With ``devices`` set, the flattened (point × trace) lane axis shards
-across host devices exactly like the scan path (the shared
-``sharded_grid_map``); each lane runs the identical per-lane program,
-so sharded rows are bit-identical to single-device rows.
+across host devices (``lanes.sharded_grid_map``); each lane runs the
+identical per-lane program, so sharded rows are bit-identical to
+single-device rows.
 """
 
 from __future__ import annotations
@@ -132,18 +84,16 @@ import numpy as np
 from repro import compat, spans
 from repro.core.jobs import Job
 from repro.core.profiles import step_points
-from repro.sim.scan import (FBGrid, FLBGrid, _prm_tree, _size_classes,
-                            fb_actions, flb_actions, pack_job_table,
-                            resolve_pack_dtype, sharded_grid_map,
-                            stable_compact)
+from repro.sim.lanes import (FBGrid, FLBGrid, fb_actions, flb_actions,
+                             pack_job_table, prm_tree, resolve_pack_dtype,
+                             sharded_grid_map, size_classes, stable_compact)
 
 __all__ = [
     "PackedEventWorkloads", "RoundsSpec", "pack_event_workloads",
     "rounds_grids", "round_budget", "ws_fold_tables_batch",
-    "fb_rounds_row",
     "fold_table_cache_info", "fold_table_cache_clear",
     "FB_ROUNDS_WINDOW", "FLB_ROUNDS_WINDOW", "ROUNDS_FF_PASSES",
-    "COMPACT_EVERY", "COALESCE_BATCH", "DEFAULT_BATCH",
+    "COMPACT_EVERY",
 ]
 
 # Windows are sized to the measured unfinished-job backlog on the §6.2
@@ -153,41 +103,17 @@ __all__ = [
 # submitting jobs must already be admitted.
 FB_ROUNDS_WINDOW = 192
 FLB_ROUNDS_WINDOW = 96
-# The scan's pass count. PR 4 spent a third pass because a pass-
-# convergence miss at an exact event time is a start-time error; the
-# paper-grid contract was RE-MEASURED at two passes (completed jobs
-# exact on all 45 evals, node-hours <= 3.8 %, peak <= 1.3 % — identical
-# to the 3-pass ledger) and the random-trace contract tests hold, so
-# the default aligns with the scan's validated setting. With the
-# coalescer enabled the contended instants are additionally exact by
-# construction (one proven-or-deferred pass per replayed instant).
+# Filtered-prefix first-fit passes per round. A pass-convergence miss
+# at an exact event time is a start-time error; the paper-grid contract
+# was measured at two passes (completed jobs exact on all 45 evals,
+# node-hours <= 3.8 %, peak <= 1.3 % — identical to three passes) and
+# the random-trace contract tests hold.
 ROUNDS_FF_PASSES = 2
 # Rounds between window compactions. Compaction is the one data-movement
 # op of the loop (a stacked lane gather); amortizing it every few rounds
 # keeps the per-round cost at reduction-dispatch level. The inner block
 # is unrolled, so this also bounds the compiled body size.
 COMPACT_EVERY = 8
-# Contended-stretch coalescing batch: with ``ScanOptions(coalesce=k)``
-# one round absorbs up to k queued-period completions (and the arrivals
-# riding the same stretch), each replayed at its exact instant by the
-# bulk section of ``round_body``. COALESCE_BATCH is the recommended
-# opt-in batch; the ENGINE default is 1 (coalescing off) because the
-# bulk's fixed vector work executes every round whether or not a
-# stretch is underway (vmapped lanes run in lockstep, so it cannot be
-# branched away), and on CPU-class hosts that tax measurably exceeds
-# the rounds it saves on the paper-density grids — the structural
-# step-count reduction pays off where per-round lockstep cost
-# dominates instead (wide accelerator batches). Re-measured under the
-# fused Pallas round-step kernel (kernel="pallas", coalesce=8, the
-# 45-eval paper grids): still a net loss on CPU — 8.7 s vs 4.0 s
-# plain-fused despite max rounds dropping 6258 -> 4047, because the
-# bulk's lockstep vector work runs inside the kernel too and interpret
-# mode executes it per-op per-lane. The verdict stands until a
-# compiled-kernel accelerator measurement says otherwise, so
-# DEFAULT_BATCH stays 1. See the honest-perf note in the module
-# docstring and README's engine table.
-COALESCE_BATCH = 8
-DEFAULT_BATCH = 1
 
 
 @dataclasses.dataclass(frozen=True)
@@ -196,44 +122,24 @@ class RoundsSpec:
     event-round program: the measurement horizon, the safety cap on
     rounds (the loop exits when every lane reaches the horizon — the
     cap only stops a runaway lane, see :func:`round_budget`), the job
-    window, the first-fit passes per round, the compaction cadence and
-    the contended-stretch coalescing batch (completions absorbed per
-    round while a queue exists; 1 disables coalescing).
-
-    ``kernel`` selects the round-step backend: ``"xla"`` (default) runs
-    the outer-loop body as plain traced jnp ops; ``"pallas"`` fuses the
-    whole body — compaction, admission, size classes and the unrolled
-    ``compact_every`` rounds — into one Pallas kernel per lane
-    (``repro.kernels.round_step``), in interpret mode; Mosaic does not
-    lower it, so on a TPU it raises ``NotImplementedError``. Both
-    backends execute the SAME ``_chunk_core`` math, so their rows are
-    bit-identical (tests/test_round_step_kernel.py).
-    The field is part of the spec hash, so the jit caches key on
-    ``(policy, spec-incl-kernel)`` and switching backends never reuses
-    a stale compiled program."""
+    window, the first-fit passes per round and the compaction cadence.
+    The jit caches key on ``(policy, spec)``."""
 
     duration: float
     max_rounds: int
     window: int
     ff_passes: int = ROUNDS_FF_PASSES
     compact_every: int = COMPACT_EVERY
-    batch: int = DEFAULT_BATCH
-    kernel: str = "xla"
-
-    def __post_init__(self):
-        if self.kernel not in ("xla", "pallas"):
-            raise ValueError(
-                f"unknown rounds kernel {self.kernel!r}; expected "
-                f"\"xla\" or \"pallas\"")
 
 
 @dataclasses.dataclass(frozen=True)
 class PackedEventWorkloads:
     """Fixed-size event arrays for W workloads and one policy's P sweep
-    points: the arrival-sorted job tables of the scan pack plus the WS
-    demand change points (value changes only, +inf sentinel padding)
-    and the host-precomputed WS fold tables (see the module docstring —
-    the loop never stops at a WS change, it reads these instead)."""
+    points: the arrival-sorted job tables (``lanes.pack_job_table``),
+    the WS demand change points (value changes only, +inf sentinel
+    padding) and the host-precomputed WS fold tables (see the module
+    docstring — the loop never stops at a WS change, it reads these
+    instead)."""
 
     submit: jnp.ndarray       # (W, J + K) — padded past the table end
     size: jnp.ndarray         # (W, J + K)
@@ -628,12 +534,9 @@ def round_budget(max_jobs: int, n_ws: int, duration: float,
 
 # ------------------------------------------------------------- the rounds core
 
-# The loop's metric accumulators, in the FIXED order the fused kernel
-# packs them into its scalar state vector (repro.kernels.round_step) —
-# both backends build the acc dict from this tuple.
+# The loop's metric accumulators.
 ACC_KEYS = ("completed", "turn_sum", "exec_sum", "kills", "node_seconds",
-            "peak", "pbj_adjusts", "adjusts", "window_overflow", "rounds",
-            "coalesced")
+            "peak", "pbj_adjusts", "adjusts", "window_overflow", "rounds")
 
 
 def _fault_acc_keys(ctx: Dict) -> Tuple[str, ...]:
@@ -646,10 +549,7 @@ def _fault_acc_keys(ctx: Dict) -> Tuple[str, ...]:
 def _lane_ctx(policy: str, prm: Dict, pk: PackedEventWorkloads) -> Dict:
     """One lane's traced round-body inputs as a flat dict — the job
     table, the FB demand-rise stops, the per-point WS fold tables and
-    the policy scalars. The XLA path builds it from the packed pytree;
-    the fused kernel rebuilds the IDENTICAL dict from its input refs
-    (``repro.kernels.round_step._ctx_from_inputs``), so both backends
-    feed the same values through the same ``_chunk_core`` math."""
+    the policy scalars, built from the packed pytree."""
     f = pk.submit.dtype
     p_idx = prm["p_idx"]
     ctx = {
@@ -678,7 +578,7 @@ def _lane_ctx(policy: str, prm: Dict, pk: PackedEventWorkloads) -> Dict:
 
 def _actions(policy: str, ctx: Dict, ff_passes: int, owned, pool_pbj,
              run, used, queued, wsv, is_tick, win, w_sz, szcls, acc):
-    """The shared §5 policy step at one instant (see scan.py). The
+    """The §5 policy step at one instant (``repro.sim.lanes``). The
     integrand it returns covers only the policy-owned share — the
     WS share integrates host-side (``ws_integral``) — and peaks
     fold per lease window: the policy share is constant inside one
@@ -714,15 +614,11 @@ def _actions(policy: str, ctx: Dict, ff_passes: int, owned, pool_pbj,
 
 
 def _round_body(policy: str, ctx: Dict, spec: RoundsSpec, carry, szcls):
-    """One event round over the window lanes — pure jnp on the carry,
-    shared verbatim by the XLA outer loop and the fused Pallas kernel
+    """One event round over the window lanes — pure jnp on the carry
     (see the module docstring for the event semantics)."""
     (t, owned, pool_pbj, used, has_queue, wsv, alloc_prev, rise_i,
      row_sub, w_sub, w_sz, w_rt, run, done, start_t, end_t, acc) = carry
     duration = spec.duration
-    K = w_sub.shape[0]
-    batch = min(spec.batch, K)      # top-k cannot exceed the window
-    coalesce = batch > 1
     f = w_sub.dtype
     inf = jnp.asarray(jnp.inf, f)
     zero = jnp.zeros((), f)
@@ -770,171 +666,22 @@ def _round_body(policy: str, ctx: Dict, spec: RoundsSpec, carry, szcls):
     # in aggregate (free only grows inside the horizon; the
     # row_sub cap keeps every such submit inside the window), each
     # starts exactly on time — retroactively, below; otherwise
-    # stop at the next submit. Non-empty queue with coalescing on
-    # (batch > 1): neither completions nor submits bound the
-    # horizon — the coalescer below replays a whole batch of them
-    # inside (t, b) at their exact instants (and re-clamps b when
-    # it has to stop early). With coalescing off the legacy
-    # horizon applies: stop at the earliest running-lane
-    # completion, and silently enqueue arrivals that cannot fit
-    # the (then constant) free capacity.
-    if not coalesce:
-        b0 = jnp.minimum(b0, jnp.where(has_queue, mins[1], inf))
+    # stop at the next submit. Non-empty queue: stop at the
+    # earliest running-lane completion, and silently enqueue
+    # arrivals that cannot fit the (then constant) free capacity.
+    b0 = jnp.minimum(b0, jnp.where(has_queue, mins[1], inf))
     fresh = (w_sub > t) & (w_sub <= b0)
     sum_new = jnp.sum(jnp.where(fresh, w_sz, zero))
     free = owned - used
     skip_ok = ~has_queue & (sum_new <= free)
-    if coalesce:
-        unbounded = skip_ok | has_queue
-    else:
-        min_new = jnp.min(jnp.where(fresh, w_sz, inf))
-        unbounded = skip_ok | (has_queue & (min_new > free))
+    min_new = jnp.min(jnp.where(fresh, w_sz, inf))
+    unbounded = skip_ok | (has_queue & (min_new > free))
     b = jnp.where(unbounded, b0, jnp.minimum(b0, next_sub))
     b = jnp.where(active, b, t)
-    # --- the contended-stretch coalescer: while a queue existed at
-    # the round start, every completion and submit strictly inside
-    # (t, b) is an event the engine reacts to (a finish or arrival
-    # triggers the §6.5.2 first-fit), and the coalescer replays a
-    # whole batch of them in ONE round of fixed vector work:
-    #
-    #   1. masked top-k — the next `batch` distinct completion
-    #      instants among running lanes, extracted as iterated
-    #      masked mins (sorted by construction; simultaneous
-    #      completions collapse into one instant), with the freed
-    #      node mass per instant;
-    #   2. a prefix-sum feasibility test for queue admissions at
-    #      each instant: under the engine's arrival-order scan a
-    #      pending job q starts once the cumulative freed mass
-    #      covers the pending jobs ahead of it plus itself
-    #      (arrival order IS lane order, so `need` is one exclusive
-    #      prefix sum), i.e. at instant τ_{i(q)} with i(q) the
-    #      first index where freedcum ≥ need(q) — or at its own
-    #      submit time if capacity already suffices;
-    #   3. defer-on-divergence: the closed form assumes FIFO
-    #      starts. Whenever the engine's first-fit could diverge —
-    #      an unstarted pending job that FITS the (conservatively
-    #      overestimated) free capacity at some replayed instant
-    #      or at its own arrival (a leapfrog), or a batch-started
-    #      job completing inside the round (a chain event the
-    #      freed-mass ledger does not contain), or more than
-    #      `batch` instants (the cap) — the round ends exactly AT
-    #      the first such instant Θ: every extracted instant,
-    #      admission and fold before Θ stays, and the tail replays
-    #      Θ itself with the full `ff_passes` first-fit (and the
-    #      §5.1 kill machinery when Θ is a demand rise), exactly
-    #      like an uncoalesced round.
-    #
-    # Allocation integrals need no per-instant work at all: the
-    # policy-owned share is constant across the whole stretch (FB
-    # reclaims only at rises, which bound b; FLB adjusts only at
-    # ticks), so each sub-interval contributes to one rectangle.
-    # A lax.top_k sort probe was measured ~6x the cost of this
-    # whole section on XLA:CPU — hence the iterated masked mins.
-    if coalesce:
-        engaged = active & has_queue
-        run0, done0, used0, free0 = run, done, used, free
-        # (1) masked top-k completion instants inside (t, b).
-        avail = engaged & run0 & (end_t < b)
-        taus, freds = [], []
-        for _ in range(batch):
-            v = jnp.min(jnp.where(avail, end_t, inf))
-            take = avail & (end_t <= v)
-            taus.append(v)
-            freds.append(jnp.sum(jnp.where(take, w_sz, zero)))
-            avail = avail & ~take
-        frontier = jnp.min(jnp.where(avail, end_t, inf))
-        tau_v = jnp.stack(taus)                        # (k,) sorted
-        freedcum = jnp.cumsum(jnp.stack(freds))        # (k,)
-        tau_pad = jnp.concatenate([t[None], tau_v])    # idx 0 → t
-        # (2) prefix-sum admission. Pending lanes (queued now or
-        # arriving inside the round) block each other in lane
-        # (= arrival) order; inherited queue heads that already
-        # fit free0 belong to the convergence residue of the LAST
-        # round's first-fit and start retroactively at t.
-        pend = engaged & ~run0 & ~done0 & (w_sub <= b)
-        psz = jnp.where(pend, w_sz, zero)
-        need = (jnp.cumsum(psz) - psz) + w_sz - free0
-        uncov = need[:, None] > freedcum[None, :]      # (K, k)
-        idx = jnp.sum(uncov.astype(jnp.int32), axis=-1)
-        # idx = first slot whose cumulative mass covers `need`;
-        # tau_pad maps slot j to τ_j (and a non-positive need to t:
-        # capacity already sufficed, the job is last round's
-        # first-fit convergence residue or starts at its arrival).
-        start_i = jnp.where(need <= 0.0, 0,
-                            jnp.minimum(idx + 1, batch))
-        covered = pend & ((need <= 0.0) | (idx < batch))
-        start_at = jnp.where(covered,
-                             jnp.maximum(w_sub, tau_pad[start_i]),
-                             inf)
-        # A zero-runtime job starting AT the round start would
-        # complete instantly — freed mass the ledger below cannot
-        # carry (Θ must stay > t), which would under-estimate
-        # free_at and mask a real leapfrog. Leave such a lane to
-        # the tail's first-fit (the one-instant-late residue the
-        # contract already carries); zero-runtime starts at later
-        # instants defer naturally through the chain probe.
-        start_at = jnp.where((w_rt <= 0.0) & (start_at <= t), inf,
-                             start_at)
-        # (3) divergence probes, all conservative (free capacity
-        # only ever OVER-estimated, so every possible first-fit
-        # leapfrog defers). started_at[j] counts admissions that
-        # happened strictly up to τ_j.
-        stsz = jnp.where(start_at < inf, w_sz, zero)
-        started_by = jnp.sum(
-            jnp.where(start_at[:, None] <= tau_v[None, :],
-                      stsz[:, None], zero), axis=0)    # (k,)
-        free_at = free0 + freedcum - started_by        # (k,)
-        fits = (pend[:, None]
-                & (w_sub[:, None] <= tau_v[None, :])
-                & (start_at[:, None] > tau_v[None, :])
-                & (w_sz[:, None] <= free_at[None, :])) # (K, k)
-        leap = jnp.min(jnp.where(jnp.any(fits, axis=0), tau_v, inf))
-        # ...and at each arrival instant: net freed mass before the
-        # arrival, ignoring arrival-triggered consumption (an
-        # overestimate). An elementwise masked sum, not a (K,k) @ (k,)
-        # matmul: a TPU matmul at default precision rounds its inputs
-        # to bf16, inexact for node counts above 256.
-        net = jnp.concatenate([freedcum[:1],
-                               jnp.diff(freedcum)]) \
-            - jnp.concatenate([started_by[:1],
-                               jnp.diff(started_by)])
-        free_arr = free0 + jnp.sum(
-            jnp.where(tau_v[None, :] < w_sub[:, None], net[None, :], zero),
-            axis=-1)
-        arr_leap = pend & (w_sub > t) & (start_at > w_sub) \
-            & (w_sz <= free_arr)
-        leap = jnp.minimum(leap, jnp.min(jnp.where(arr_leap, w_sub,
-                                                   inf)))
-        # Chain events: batch-started jobs finishing inside the
-        # round free mass the ledger above does not see.
-        chain = jnp.min(jnp.where(start_at < inf,
-                                  start_at + w_rt, inf))
-        chain = jnp.where(chain > t, chain, inf)       # 0-runtime
-        theta = jnp.minimum(jnp.minimum(leap, chain), frontier)
-        # (4) apply everything strictly before Θ; Θ itself (and
-        # anything later) belongs to the tail / next rounds.
-        cmp_c = engaged & run0 & (end_t < jnp.minimum(theta, b))
-        st_c = (start_at < jnp.minimum(theta, b))
-        cf = cmp_c.astype(f)
-        folds_c = jnp.sum(jnp.stack([cf, cf * (end_t - w_sub),
-                                     cf * (end_t - start_t),
-                                     cf * w_sz,
-                                     jnp.where(st_c, w_sz, zero)]),
-                          axis=-1)                 # one packed reduction
-        run = (run0 & ~cmp_c) | st_c
-        done = done0 | cmp_c
-        start_t = jnp.where(st_c, start_at, start_t)
-        end_t = jnp.where(st_c, start_at + w_rt, end_t)
-        used = used0 - folds_c[3] + folds_c[4]
-        acc["completed"] += folds_c[0]
-        acc["turn_sum"] += folds_c[1]
-        acc["exec_sum"] += folds_c[2]
-        acc["coalesced"] += folds_c[0]
-        b = jnp.minimum(b, theta)
     # --- exact interval integration: the policy-owned share is
     # constant on (t, b] — it only ever changes at policy actions,
-    # which happen at rounds (ticks, rises), never at coalesced
-    # completions or starts.
+    # which happen at rounds (ticks, rises), never at completions or
+    # starts.
     acc["node_seconds"] += alloc_prev * jnp.maximum(b - t, 0.0)
     # --- retroactive starts at exact submit times.
     starting = (w_sub > t) & (w_sub <= b) & ~run & ~done & skip_ok
@@ -1017,9 +764,7 @@ def _chunk_core(policy: str, ctx: Dict, spec: RoundsSpec, core):
     admission, the per-chunk size classes and ``compact_every`` unrolled
     event rounds. ``core`` is the 17-tuple loop state with ``next_row``
     (the admission cursor) in the slot the inner rounds carry
-    ``row_sub`` in. Shared verbatim by the XLA backend and the fused
-    Pallas kernel — the kernel body IS this function applied to values
-    read from its refs (repro.kernels.round_step)."""
+    ``row_sub`` in."""
     (t, owned, pool_pbj, used, has_queue, wsv, alloc_prev, rise_i,
      next_row, w_sub, w_sz, w_rt, run, done, start_t, end_t, acc) = core
     tr_submit = ctx["tr_submit"]
@@ -1060,7 +805,7 @@ def _chunk_core(policy: str, ctx: Dict, spec: RoundsSpec, core):
     # The FB kill size classes depend only on the window contents,
     # which change at compactions — computed once per chunk, not
     # once per round.
-    szcls = _size_classes(w_sz)
+    szcls = size_classes(w_sz)
     for _ in range(spec.compact_every):  # unrolled: XLA fuses the rounds
         inner = _round_body(policy, ctx, spec, inner, szcls)
     (t, owned, pool_pbj, used, has_queue, wsv, alloc_prev, rise_i,
@@ -1077,22 +822,11 @@ def _simulate_rounds(policy: str, prm: Dict, pk: PackedEventWorkloads,
 
     ``pk`` holds a single workload's rows; ``prm`` one sweep point's
     scalars plus its index ``p_idx`` into the packed WS fold tables;
-    ``policy`` is static ("fb" | "flb_nub"). With ``spec.kernel ==
-    "pallas"`` the loop body runs as the fused Pallas round-step kernel
-    (``repro.kernels.round_step``) on a float-packed state; the state
-    round-trips bit-exactly, and the kernel body calls the same
-    ``_chunk_core``, so both backends return identical rows.
+    ``policy`` is static ("fb" | "flb_nub").
     """
     duration = spec.duration
     K = spec.window
     R = spec.compact_every
-    if spec.kernel == "pallas" and pk.fault_times is not None:
-        # The fused kernel's lane_inputs/ctx round-trip carries exactly
-        # the pre-fault context; keeping fault keys out of it preserves
-        # the kernel's bit-identity guarantee for every no-fault row.
-        raise NotImplementedError(
-            "fault injection is not supported by the fused pallas "
-            "round step; use kernel=\"xla\"")
     ctx = _lane_ctx(policy, prm, pk)
     tr_submit = ctx["tr_submit"]
     tr_size, tr_runtime = ctx["tr_size"], ctx["tr_runtime"]
@@ -1121,7 +855,7 @@ def _simulate_rounds(policy: str, prm: Dict, pk: PackedEventWorkloads,
     owned, pool_pbj, run, starts0, alloc0, acc = _actions(
         policy, ctx, spec.ff_passes, owned0, pool0, jnp.zeros(K, bool),
         zero, queued0, ws0, jnp.asarray(False), jnp.asarray(0, jnp.int32),
-        w_sz, _size_classes(w_sz), acc)
+        w_sz, size_classes(w_sz), acc)
     if policy == "fb":
         acc["peak"] = jnp.maximum(acc["peak"],
                                   jnp.minimum(owned + ws_winmax[0], C))
@@ -1140,41 +874,16 @@ def _simulate_rounds(policy: str, prm: Dict, pk: PackedEventWorkloads,
              w_sub, w_sz, w_rt, run, jnp.zeros(K, bool), start_t, end_t,
              acc)
 
-    if spec.kernel == "pallas":
-        # The fused backend: pack the loop state into the kernel's
-        # scalar vector + window matrix, run each outer step as ONE
-        # pallas_call (vmapped lanes become the kernel grid), unpack
-        # once after the loop. Imported lazily — the kernels layer is
-        # optional and the import direction stays kernels -> sim.
-        from repro.kernels import round_step as rsk
-        jobs, rises, wstab, prmv = rsk.lane_inputs(policy, ctx)
-        sc0, win0 = rsk.pack_carry(core0)
+    def cond(carry):
+        i, t = carry[0], carry[1]
+        return (i < outer_max) & (t < duration)
 
-        def cond(carry):
-            return (carry[0] < outer_max) & (carry[1][rsk.SC_T] < duration)
+    def chunk(carry):
+        return (carry[0] + 1,) + _chunk_core(policy, ctx, spec, carry[1:])
 
-        def chunk(carry):
-            i, sc, win = carry
-            sc, win = rsk.chunk_step(jobs, rises, wstab, prmv, sc, win,
-                                     policy=policy, spec=spec)
-            return (i + 1, sc, win)
-
-        carry = jax.lax.while_loop(
-            cond, chunk, (jnp.asarray(0, jnp.int32), sc0, win0))
-        core = rsk.unpack_carry(carry[1], carry[2])
-        t_end, acc = core[0], core[-1]
-    else:
-        def cond(carry):
-            i, t = carry[0], carry[1]
-            return (i < outer_max) & (t < duration)
-
-        def chunk(carry):
-            return (carry[0] + 1,) + _chunk_core(policy, ctx, spec,
-                                                 carry[1:])
-
-        carry = jax.lax.while_loop(
-            cond, chunk, (jnp.asarray(0, jnp.int32),) + core0)
-        t_end, acc = carry[1], carry[-1]
+    carry = jax.lax.while_loop(
+        cond, chunk, (jnp.asarray(0, jnp.int32),) + core0)
+    t_end, acc = carry[1], carry[-1]
 
     n_done = jnp.maximum(acc["completed"], 1.0)
     return {
@@ -1188,16 +897,15 @@ def _simulate_rounds(policy: str, prm: Dict, pk: PackedEventWorkloads,
         "kills": acc["kills"],
         "window_overflow": acc["window_overflow"],
         "rounds": acc["rounds"],
-        "coalesced": acc["coalesced"],
         "truncated": (t_end < duration).astype(f),
         **{k: acc[k] for k in _fault_acc_keys(ctx)},
     }
 
 
 def _rounds_prm_tree(policy: str, grid) -> Dict[str, jnp.ndarray]:
-    """The scan parameter tree plus each point's index into the packed
+    """The grid's parameter tree plus each point's index into the packed
     WS fold tables (``ws_integral`` / ``ws_winmax``)."""
-    prm = dict(_prm_tree(policy, grid))
+    prm = dict(prm_tree(policy, grid))
     prm["p_idx"] = jnp.arange(int(grid.lease.shape[0]), dtype=jnp.int32)
     return prm
 
@@ -1255,14 +963,15 @@ def rounds_grids(fb: Optional[FBGrid], flb: Optional[FLBGrid],
                  ) -> Dict[str, Dict[str, jnp.ndarray]]:
     """Evaluate FB and FLB-NUB sweep grids through the event-round
     engine. Returns ``{"fb": metrics, "flb_nub": metrics}`` with
-    ``(W, P_policy)`` metric arrays, like :func:`repro.sim.scan.
-    scan_grids`; a policy is skipped when its spec is ``None``.
+    ``(W, P_policy)`` metric arrays; a policy is skipped when its spec
+    is ``None``.
 
-    ``devices`` selects the backend exactly as for the scan engine:
-    ``None`` / one device runs the flattened (trace × point) lanes as
-    one vmapped program, two or more shard those lanes via the shared
-    ``sharded_grid_map`` — bit-identical rows either way, since every
-    lane runs the identical per-lane program.
+    ``devices`` (``None`` | device count | device sequence, see
+    ``repro.compat.resolve_devices``) selects the backend: ``None`` /
+    one device runs the flattened (trace × point) lanes as one vmapped
+    program, two or more shard those lanes via
+    ``lanes.sharded_grid_map`` — bit-identical rows either way, since
+    every lane runs the identical per-lane program.
     """
     devs = compat.resolve_devices(devices)
     if devs is None:
@@ -1286,23 +995,3 @@ def rounds_grids(fb: Optional[FBGrid], flb: Optional[FLBGrid],
             _rounds_prm_tree("flb_nub", flb), flb_packed,
             int(flb_packed.submit.shape[0]), int(flb.lease.shape[0]), devs)
     return out
-
-
-def fb_rounds_row(jobs: Sequence[Job], ws_trace: Sequence[Tuple[float, int]],
-                  capacity: int, lease_seconds: float, duration: float,
-                  faults=None, kernel: str = "xla",
-                  batch: int = DEFAULT_BATCH,
-                  dtype: Optional[np.dtype] = None) -> Dict:
-    """One FB (capacity, lease) point through the rounds engine as one
-    row of :func:`repro.sim.sweep.run_sweep_workloads` — the single-point
-    convenience the chaos differential harness and ``benchmarks.run
-    faults`` share. With ``faults`` set, the schedule's stops fold into
-    the horizon and the effective capacity becomes ``max(C - failed(t),
-    0)`` (see :func:`pack_event_workloads`)."""
-    from repro.sim import sweep
-    point = sweep.SweepPoint("fb", capacity=int(capacity),
-                             lease_seconds=float(lease_seconds))
-    options = sweep.ScanOptions(kernel=kernel, coalesce=batch, dtype=dtype)
-    return sweep.run_sweep_workloads(
-        [point], [(jobs, ws_trace)], float(duration), mode="rounds",
-        scan_options=options, faults=[faults], _stack_offset=1)[0][0]

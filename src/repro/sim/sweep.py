@@ -7,7 +7,7 @@ time unit L against EC2+RightScale. ``run_sweep`` evaluates a whole grid
 of :class:`SweepPoint`s — mixing all four systems — in one call, and
 ``run_sweep_workloads`` adds a second batch axis over workload traces.
 
-Four execution paths, selected by ``mode``:
+Three execution paths, selected by ``mode``:
 
   * **Vectorized fast path** (DCS and EC2+RightScale; every mode except
     ``"event"``). Both baselines are *stateless* given the trace —
@@ -26,31 +26,20 @@ Four execution paths, selected by ``mode``:
     exactly (tests/test_sweep.py).
 
   * **Event-round fast path** (PhoenixCloud FB and FLB-NUB; modes
-    ``"rounds"`` and ``"auto"`` — the default scan-family mode). The
-    coordinated policies are stateful — kills, queue contents and U/V/G
-    adjustments feed back into the allocation — so they cannot be
-    closed-form; ``repro.sim.rounds`` batches them as a jitted
-    ``lax.while_loop`` whose every step jumps straight to the next
-    event (submit / completion / WS change / lease boundary) per lane.
-    Completions and the allocation integral are *exact*: completed jobs
-    match the event engine exactly and node-hours/peak stay within 5 %
-    (the residue is first-fit pass convergence and kill tie-breaking,
-    not time discretization). ``mode="auto"`` routes FB / FLB-NUB
-    points through this engine, except beyond-paper
-    ``checkpoint_preempt`` FB points which quietly fall back to the
-    event engine (the status-lane kill encoding always restarts from
-    scratch).
-
-  * **Batched scan fast path** (PhoenixCloud FB and FLB-NUB; mode
-    ``"scan"``). The fixed-``dt`` predecessor of the rounds engine:
-    ``repro.sim.scan`` re-expresses both policies as a single jitted
-    ``lax.scan`` over a fixed-size job window with status lanes,
-    ``vmap``-ed over sweep points AND packed workload traces.
-    Approximate by discretization: completed jobs within 2 %,
-    node-hours and peak within 15 % of the event engine, parameter-sweep
-    orderings (J1/J2 trends) identical (tests/test_sweep.py,
-    tests/test_scan_policies.py). Kept as the cross-check of the rounds
-    engine and for substep-resolution studies.
+    ``"rounds"`` and ``"auto"``). The coordinated policies are
+    stateful — kills, queue contents and U/V/G adjustments feed back
+    into the allocation — so they cannot be closed-form;
+    ``repro.sim.rounds`` batches them as a jitted ``lax.while_loop``
+    whose every step jumps straight to the next event (submit /
+    completion / WS change / lease boundary) per lane, ``vmap``-ed over
+    sweep points AND packed workload traces. Completions and the
+    allocation integral are *exact*: completed jobs match the event
+    engine exactly and node-hours/peak stay within 5 % (the residue is
+    first-fit pass convergence and kill tie-breaking, not time
+    discretization). ``mode="auto"`` routes FB / FLB-NUB points through
+    this engine, except beyond-paper ``checkpoint_preempt`` FB points
+    which quietly fall back to the event engine (the status-lane kill
+    encoding always restarts from scratch).
 
   * **Event-engine path** (mode ``"event"``, and the fallback for
     points no fast path accepts). Each point runs through
@@ -78,8 +67,8 @@ from repro import compat, spans
 from repro.core.jobs import Job
 from repro.core.pbj_manager import PBJPolicyParams
 from repro.core.profiles import step_integral, step_points
+from repro.sim import lanes
 from repro.sim import rounds as roundslib
-from repro.sim import scan as scanlib
 from repro.sim.engine import (_SUBMIT, _TICK, _WS, SYSTEMS, build_dcs,
                               build_ec2_rightscale, build_fb, build_flb_nub,
                               clone_jobs, default_duration, run_sim)
@@ -87,12 +76,12 @@ from repro.sim.engine import (_SUBMIT, _TICK, _WS, SYSTEMS, build_dcs,
 __all__ = ["SweepPoint", "ScanOptions", "run_sweep", "run_sweep_workloads",
            "paper_grid"]
 
-MODES = ("auto", "event", "scan", "rounds")
+MODES = ("auto", "event", "rounds")
 
 # Systems with a stateless closed-form fast path vs the stateful
-# coordinated policies that take the batched scan/rounds paths.
+# coordinated policies that take the batched rounds path.
 _VECTORIZED = ("dcs", "ec2")
-_SCANNABLE = ("fb", "flb_nub")
+_BATCHED = ("fb", "flb_nub")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -137,30 +126,13 @@ class SweepPoint:
 
 @dataclasses.dataclass(frozen=True)
 class ScanOptions:
-    """Tuning knobs of the batched fast paths (``mode="scan"`` and
-    ``mode="rounds"``, see ``repro.sim.scan`` / ``repro.sim.rounds``).
-    The defaults are the settings the fidelity contracts are validated
-    at; ``dt=None`` picks each policy's validated substep
-    (``scanlib.pick_dt`` — FB coarse, FLB-NUB fine), capped by the
-    grid's shortest lease and, for FLB-NUB, by the workloads' WS
-    change-point spacing. The rounds engine has no substep — ``dt`` and
-    ``chunk_len`` only affect ``mode="scan"``. ``ff_passes=None`` takes
-    the engines' shared default (2 filtered-prefix passes; the rounds
-    coalescer's drain instants are exact-or-deferred regardless).
-    ``coalesce`` is the rounds engine's contended-stretch batch — up to
-    that many queued-period completions (plus the arrivals riding the
-    same stretch) fold into one event round, each replayed at its
-    exact instant; ``repro.sim.rounds.COALESCE_BATCH`` (8) is the
-    recommended opt-in value, 1 (the default) leaves one round per
-    contended completion — on CPU hosts the coalescer's fixed per-
-    round vector work measurably outweighs the rounds it saves, see
-    the rounds module docstring. The scan path ignores it. ``kernel``
-    selects the rounds engine's round-step backend: ``"xla"`` (the safe
-    default) dispatches the traced body op by op, ``"pallas"`` fuses the
-    whole outer step — compaction, admission and the unrolled rounds —
-    into one Pallas kernel per lane (``repro.kernels.round_step``;
-    interpret mode only — refused on a TPU, where Mosaic does not lower
-    it), bit-identical rows either way. The scan path ignores it.
+    """Tuning knobs of the batched rounds path (``mode="rounds"`` and
+    ``"auto"``, see ``repro.sim.rounds``). The defaults are the
+    settings the fidelity contracts are validated at. ``window`` is the
+    job window per lane (``None``: ``FB_ROUNDS_WINDOW`` /
+    ``FLB_ROUNDS_WINDOW``), ``ff_passes`` the first-fit passes per
+    round (``None``: ``ROUNDS_FF_PASSES``) and ``dtype`` the pack dtype
+    (``None``: the active jax x64 setting).
     ``devices`` selects the execution backend
     (``repro.compat.resolve_devices``): ``None`` runs the whole
     grid on one device, a count or device sequence shards the
@@ -172,35 +144,11 @@ class ScanOptions:
     rounds program again; a caller asking many questions of fresh draws
     sets it once and compiles once."""
 
-    dt: Optional[float] = None
     window: Optional[int] = None
-    chunk_len: Optional[int] = None
     ff_passes: Optional[int] = None
-    coalesce: Optional[int] = None
     dtype: Optional[np.dtype] = None
     devices: compat.Devices = None
-    kernel: str = "xla"
     fault_stops: Optional[int] = None
-
-    def resolve(self, policy: str, leases: Sequence[float],
-                duration: float,
-                ws_traces: Optional[Sequence[Sequence[Tuple[float, int]]]]
-                = None) -> scanlib.ScanSpec:
-        dt = self.dt if self.dt is not None else scanlib.pick_dt(
-            policy, leases, ws_traces, duration)
-        window = (self.window if self.window is not None else
-                  (scanlib.FB_WINDOW if policy == "fb"
-                   else scanlib.FLB_WINDOW))
-        # Re-gather cadence: FB's window turns over slowly (its backlog
-        # is bounded by C), FLB-NUB's buffers arrival bursts.
-        chunk_seconds = 3600.0 if policy == "fb" else 1800.0
-        chunk = (self.chunk_len if self.chunk_len is not None
-                 else max(2, int(round(chunk_seconds / dt))))
-        ff = (self.ff_passes if self.ff_passes is not None
-              else scanlib.DEFAULT_FF_PASSES)
-        return scanlib.ScanSpec(
-            n_steps=int(np.ceil(duration / dt)), dt=dt, window=window,
-            chunk_len=chunk, ff_passes=ff)
 
     def resolve_rounds(self, policy: str, leases: Sequence[float],
                        duration: float, max_jobs: int,
@@ -210,16 +158,11 @@ class ScanOptions:
                    else roundslib.FLB_ROUNDS_WINDOW))
         ff = (self.ff_passes if self.ff_passes is not None
               else roundslib.ROUNDS_FF_PASSES)
-        batch = (self.coalesce if self.coalesce is not None
-                 else roundslib.DEFAULT_BATCH)
-        if batch < 1:
-            raise ValueError(f"coalesce batch must be >= 1, got {batch}")
         return roundslib.RoundsSpec(
             duration=duration,
             max_rounds=roundslib.round_budget(max_jobs, n_ws, duration,
                                               min(leases)),
-            window=window, ff_passes=ff, batch=batch,
-            kernel=self.kernel)
+            window=window, ff_passes=ff)
 
 
 def _build(p: SweepPoint):
@@ -343,19 +286,18 @@ def _sweep_ec2(points: List[SweepPoint], jobs: Sequence[Job],
     return rows
 
 
-# ------------------------------------------------ batched scan/rounds paths
+# ------------------------------------------------------ batched rounds path
 
 def _reject_preempt(points: List[SweepPoint], mode: str) -> None:
     for p in points:
         # The status-lane kill encoding resets a killed lane to its full
-        # runtime (repro.sim.scan / repro.sim.rounds); the beyond-paper
-        # checkpoint-preempt mode only exists on the event engine — fail
-        # loudly rather than silently report full-restart metrics for a
-        # preemption study. The guard is FB-only on purpose: FLB-NUB
+        # runtime (repro.sim.lanes); the beyond-paper checkpoint-preempt
+        # mode only exists on the event engine — fail loudly rather than
+        # silently report full-restart metrics for a preemption study. The guard is FB-only on purpose: FLB-NUB
         # never force-releases (§5.2 satisfies WS elastically and only
         # ever releases *free* nodes), so it has no kills for the
         # preemption mode to change —
-        # tests/test_scan_policies.py::test_flb_nub_never_kills pins
+        # tests/test_lane_policies.py::test_flb_nub_never_kills pins
         # that invariant, making the exemption safe.
         if p.system == "fb" and p.params.checkpoint_preempt:
             raise ValueError(
@@ -365,15 +307,15 @@ def _reject_preempt(points: List[SweepPoint], mode: str) -> None:
 
 
 def _fb_grid(points: List[SweepPoint], idxs: List[int],
-             f) -> scanlib.FBGrid:
-    return scanlib.FBGrid(
+             f) -> lanes.FBGrid:
+    return lanes.FBGrid(
         capacity=jnp.asarray([float(points[i].capacity) for i in idxs], f),
         lease=jnp.asarray([points[i].lease_seconds for i in idxs], f))
 
 
 def _flb_grid(points: List[SweepPoint], idxs: List[int],
-              f) -> scanlib.FLBGrid:
-    return scanlib.FLBGrid(
+              f) -> lanes.FLBGrid:
+    return lanes.FLBGrid(
         B=jnp.asarray([float(points[i].lb_pbj + points[i].lb_ws)
                        for i in idxs], f),
         lb_ws=jnp.asarray([float(points[i].lb_ws) for i in idxs], f),
@@ -385,8 +327,8 @@ def _flb_grid(points: List[SweepPoint], idxs: List[int],
         lease=jnp.asarray([points[i].lease_seconds for i in idxs], f))
 
 
-_DIAG_KEYS = ("window_overflow", "truncated", "rounds", "coalesced",
-              "fault_rounds", "fault_kills")
+_DIAG_KEYS = ("window_overflow", "truncated", "rounds", "fault_rounds",
+              "fault_kills")
 
 
 def _assemble_rows(points: List[SweepPoint], fb_idx: List[int],
@@ -452,70 +394,13 @@ def _warn_diagnostics(per_workload: List[List[Dict]], engine: str,
             stacklevel=stacklevel)
 
 
-def _pack_scan(points: List[SweepPoint],
-               workloads: Sequence[Tuple[Sequence[Job],
-                                         Sequence[Tuple[float, int]]]],
-               duration: float, options: ScanOptions):
-    """Host-side setup stage of the scan path: trace packing + grid
-    construction. Factored out of :func:`_sweep_scan` so
-    ``benchmarks/run.py`` can time setup separately from compile/run
-    (the ``setup_s`` ledger column)."""
-    fb_idx = [i for i, p in enumerate(points) if p.system == "fb"]
-    flb_idx = [i for i, p in enumerate(points) if p.system == "flb_nub"]
-    ws_traces = [ws for _, ws in workloads]
-
-    fb = flb = fb_packed = flb_packed = fb_spec = flb_spec = None
-    if fb_idx:
-        fb_spec = options.resolve(
-            "fb", [points[i].lease_seconds for i in fb_idx], duration)
-        fb_packed, _ = scanlib.pack_workloads(
-            workloads, duration, fb_spec.dt, window=fb_spec.window,
-            chunk_len=fb_spec.chunk_len, dtype=options.dtype)
-        fb = _fb_grid(points, fb_idx, fb_packed.ws.dtype)
-    if flb_idx:
-        flb_spec = options.resolve(
-            "flb_nub", [points[i].lease_seconds for i in flb_idx], duration,
-            ws_traces=ws_traces)
-        flb_packed, _ = scanlib.pack_workloads(
-            workloads, duration, flb_spec.dt, window=flb_spec.window,
-            chunk_len=flb_spec.chunk_len, dtype=options.dtype)
-        flb = _flb_grid(points, flb_idx, flb_packed.ws.dtype)
-    return fb_idx, flb_idx, fb, flb, fb_packed, flb_packed, fb_spec, flb_spec
-
-
-def _sweep_scan(points: List[SweepPoint],
-                workloads: Sequence[Tuple[Sequence[Job],
-                                          Sequence[Tuple[float, int]]]],
-                duration: float,
-                options: ScanOptions,
-                warn_stacklevel: int = 3) -> List[List[Dict]]:
-    """FB and FLB-NUB points through the batched ``lax.scan`` fast path.
-
-    Returns one row list per workload, each aligned with ``points``
-    (which must all be scan-eligible systems). The whole
-    (policy, point, workload) grid is one jitted XLA program.
-    """
-    assert all(p.system in _SCANNABLE for p in points)
-    _reject_preempt(points, "scan")
-    (fb_idx, flb_idx, fb, flb, fb_packed, flb_packed,
-     fb_spec, flb_spec) = _pack_scan(points, workloads, duration, options)
-
-    out = scanlib.scan_grids(fb, flb, fb_packed, flb_packed,
-                             fb_spec=fb_spec, flb_spec=flb_spec,
-                             devices=options.devices)
-    out = jax.tree_util.tree_map(np.asarray, out)
-    rows = _assemble_rows(points, fb_idx, flb_idx, out, len(workloads),
-                          "scan")
-    _warn_diagnostics(rows, "scan", stacklevel=warn_stacklevel)
-    return rows
-
-
 def _pack_rounds(points: List[SweepPoint],
                  workloads: Sequence[Tuple[Sequence[Job],
                                            Sequence[Tuple[float, int]]]],
                  duration: float, options: ScanOptions, faults=None):
     """Host-side setup stage of the rounds path: event packing + fold
-    tables + grid construction (see :func:`_pack_scan`). Each policy's
+    tables + grid construction, apart from the device call so callers
+    can time setup on its own. Each policy's
     pack holds every workload, stacked on a leading trace axis. FB's
     pack carries ``faults`` (one schedule or ``None`` per workload), and
     its round budget the slack of the call's largest schedule."""
@@ -565,7 +450,7 @@ def _sweep_rounds(points: List[SweepPoint],
                   warn_stacklevel: int = 3, faults=None) -> List[List[Dict]]:
     """FB and FLB-NUB points through the event-round fast path
     (``repro.sim.rounds``): adaptive jump-to-next-event steps with
-    exact completions, batched over sweep points like the scan.
+    exact completions, batched over sweep points and workloads.
 
     All workloads run in ONE device call: the packs stack the traces
     and the program runs every (trace × point) lane in lockstep, so the
@@ -582,7 +467,7 @@ def _sweep_rounds(points: List[SweepPoint],
     rounds that stopped at a fault instant (``rounds.fault_rounds``)
     and the jobs killed in them (``faults.kills``).
     """
-    assert all(p.system in _SCANNABLE for p in points)
+    assert all(p.system in _BATCHED for p in points)
     _reject_preempt(points, "rounds")
     (fb_idx, flb_idx, fb, flb, fb_packed, flb_packed,
      fb_spec, flb_spec) = _pack_rounds(points, workloads, duration, options,
@@ -659,7 +544,7 @@ def _sweep_rounds_generated(points: List[SweepPoint], grid,
     share one dense WS grid and one job-table height.
     """
     from repro.sim import scenarios as scenarioslib
-    assert all(p.system in _SCANNABLE for p in points)
+    assert all(p.system in _BATCHED for p in points)
     _reject_preempt(points, "rounds")
     if synth is None:
         synth = scenarioslib.synthesize(grid)
@@ -699,9 +584,6 @@ def _check_faults(points: Sequence[SweepPoint], workloads, faults,
             f"fault schedules run FB points only (the rounds engine folds "
             f"failures for FB alone), got {other}; run those points "
             f"without faults or one at a time through run_sim(faults=)")
-    if mode == "scan":
-        raise ValueError("fault schedules run mode 'rounds', 'auto' or "
-                         "'event', not 'scan'")
     return faults
 
 
@@ -724,8 +606,7 @@ def run_sweep(points: Sequence[SweepPoint], jobs: Sequence[Job],
 
     Returns one row dict per point, in input order, each tagged with
     ``engine`` = ``"vectorized"`` (exact batched jnp fast path),
-    ``"rounds"`` (event-round fast path for FB / FLB-NUB),
-    ``"scan"`` (fixed-dt lax.scan fast path, mode ``"scan"`` only) or
+    ``"rounds"`` (event-round fast path for FB / FLB-NUB) or
     ``"event"`` (per-point discrete-event run).
 
     ``mode`` selects the execution paths (see module docstring):
@@ -734,21 +615,20 @@ def run_sweep(points: Sequence[SweepPoint], jobs: Sequence[Job],
     jobs exact, node-hours/peak within 5 %), falling back to the event
     engine for points the fast path rejects (FB with
     ``checkpoint_preempt``); ``"rounds"`` is the same but *fails* on
-    such points; ``"scan"`` batches FB / FLB-NUB through the fixed-dt
-    ``repro.sim.scan`` instead; ``"event"`` runs everything on the
+    such points; ``"event"`` runs everything on the
     event engine — the cross-validation reference used by
     tests/test_sweep.py. The legacy ``vectorize=False`` flag is
     equivalent to ``mode="event"``.
 
     ``devices`` (shorthand for ``scan_options.devices``) shards the
     fast path's (point × trace) lanes across that many host devices —
-    see :class:`ScanOptions`. It affects modes ``"auto"``, ``"scan"``
-    and ``"rounds"``.
+    see :class:`ScanOptions`. It affects modes ``"auto"`` and
+    ``"rounds"``.
 
     Vectorized DCS rows carry cost/peak metrics only (use ``.get`` or
     ``mode="event"`` when job metrics are needed for a DCS point);
-    scan/rounds rows carry the full metric set plus lane diagnostics
-    (``window_overflow``, and ``truncated`` for rounds) — a nonzero
+    rounds rows carry the full metric set plus lane diagnostics
+    (``window_overflow``, ``truncated`` and ``rounds``) — a nonzero
     diagnostic also raises a ``RuntimeWarning``.
     """
     return run_sweep_workloads(points, [(jobs, ws_trace)], duration,
@@ -795,9 +675,9 @@ def run_sweep_workloads(points: Sequence[SweepPoint],
     with the other workloads, fault instants as loop stops and the
     capacity ``max(C - failed(t), 0)``) or, in ``mode="event"`` and for
     the points ``"auto"`` sends there, through ``run_sim(faults=)``. A
-    schedule beside a DCS, EC2 or FLB-NUB point, or in ``mode="scan"``,
-    raises ``ValueError``. ``None``, or no schedule with an event,
-    leaves every path as it is without faults.
+    schedule beside a DCS, EC2 or FLB-NUB point raises ``ValueError``.
+    ``None``, or no schedule with an event, leaves every path as it is
+    without faults.
 
     ``_stack_offset`` (private) is the number of wrapper frames between
     the user's call site and this function; diagnostic
@@ -829,7 +709,7 @@ def run_sweep_workloads(points: Sequence[SweepPoint],
                 raise ValueError(
                     "duration is fixed by ScenarioGrid.duration — pass None")
             bad = sorted({p.system for p in points
-                          if p.system not in _SCANNABLE})
+                          if p.system not in _BATCHED})
             if bad:
                 raise ValueError(
                     f"generated scenario batches support FB / FLB-NUB points "
@@ -861,27 +741,22 @@ def run_sweep_workloads(points: Sequence[SweepPoint],
                                                      duration)):
                             rows[w][i] = row
 
-        if mode in ("auto", "scan", "rounds"):
+        if mode in ("auto", "rounds"):
             batch_idx = [i for i, p in enumerate(points)
-                         if p.system in _SCANNABLE]
+                         if p.system in _BATCHED]
             if mode == "auto":
-                # The event-round engine is the default scan-family mode;
-                # points it rejects (FB checkpoint_preempt) quietly take
-                # the per-point event path below instead of failing.
+                # Points the event-round engine rejects (FB
+                # checkpoint_preempt) quietly take the per-point event
+                # path below instead of failing.
                 batch_idx = [i for i in batch_idx
                              if not (points[i].system == "fb"
                                      and points[i].params.checkpoint_preempt)]
             if batch_idx:
                 batch = [points[i] for i in batch_idx]
-                if mode == "scan":
-                    fast_rows = _sweep_scan(batch, workloads, duration,
-                                            scan_options,
-                                            warn_stacklevel=warn_stacklevel)
-                else:
-                    fast_rows = _sweep_rounds(batch, workloads, duration,
-                                              scan_options,
-                                              warn_stacklevel=warn_stacklevel,
-                                              faults=faults)
+                fast_rows = _sweep_rounds(batch, workloads, duration,
+                                          scan_options,
+                                          warn_stacklevel=warn_stacklevel,
+                                          faults=faults)
                 for w in range(len(workloads)):
                     for j, i in enumerate(batch_idx):
                         rows[w][i] = fast_rows[w][j]
@@ -912,9 +787,9 @@ def warmup_sweep(points: Sequence[SweepPoint],
     :mod:`repro.spans` span) — the compile cost the steady-state path
     then never pays again.
 
-    The fast paths' programs are cached on ``(policy, spec)`` keys that
-    include the rounds ``kernel`` backend and, for the sharded backend,
-    the device mesh (``rounds._rounds_lane`` / ``scan._sharded_lanes``),
+    The rounds programs are cached on ``(policy, spec)`` keys and, for
+    the sharded backend, the device mesh (``rounds._rounds_lane`` /
+    ``lanes._sharded_lanes``),
     so warming one configuration never evicts or aliases another. The
     helper is ``jax.clear_caches()``-safe: nothing is memoized on wall
     time or call order, so after a cache clear the next call simply

@@ -12,8 +12,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.sim import scan
-from repro.sim.scan import rank_by_compare, rank_by_search, stable_compact
+from repro.sim import lanes
+from repro.sim.lanes import rank_by_compare, rank_by_search, stable_compact
 
 pytestmark = pytest.mark.tier1
 
@@ -80,7 +80,7 @@ def test_stable_compact_is_identical_through_either_rank(K, kind,
         return jax.jit(jax.vmap(compact))(keep, flags, times, sizes)
 
     (a0, b0, c0), n0 = run()
-    monkeypatch.setattr(scan, "rank_by_search", rank_by_compare)
+    monkeypatch.setattr(lanes, "rank_by_search", rank_by_compare)
     (a1, b1, c1), n1 = run()
     for x, y in [(a0, a1), (b0, b1), (c0, c1), (n0, n1)]:
         assert x.dtype == y.dtype

@@ -3,13 +3,10 @@ EC2+RightScale comparison methodology and the earlier PhoenixCloud
 consolidation study, arXiv:0906.1346).
 
 One shared scenario generator drives random PBJ/WS traces and sweep
-points through ALL sweep engines — the per-point discrete-event
-reference, the fixed-dt scan, the event-round engine, its
-contended-stretch-coalesced variant and its fused Pallas round-step
-backend (``kernel="pallas"``, bit-identical by contract) — and asserts
-each engine's
-fidelity contract from ``repro.sim.contracts`` (the same table the CI
-bench gate imports, so the gate and these tests cannot drift apart).
+points through the per-point discrete-event reference and the batched
+event-round engine, and asserts the rounds fidelity contract from
+``repro.sim.contracts`` (the same table the CI bench gate imports, so
+the gate and these tests cannot drift apart).
 
 Layout:
 
@@ -17,19 +14,16 @@ Layout:
   mandatory hypothesis dependency);
 * a hypothesis-driven differential runs when hypothesis is installed,
   reusing the identical checker;
-* the coalescer regression pins the crafted all-contended trace: a
-  whole batch of completions -> head-of-queue starts per round, event
-  times bit-exact, round count within the ceil(completions / batch)
-  bound;
+* the all-contended drain pins a crafted trace where the queue drains
+  one generation of simultaneous completions at a time: completion
+  times bit-exact, one round per generation;
 * a unit test pins that the bench gate (`benchmarks.run
   .rounds_contract_ok`) actually reads the contract table.
 
 Scenario shapes are FIXED per-axis (job count, WS change count,
-horizon, windows) so every seed reuses one compiled program per engine
-— the differential sweep stays minutes-cheap despite four engines.
+horizon, windows) so every seed reuses one compiled program.
 """
 
-import math
 import random
 
 import numpy as np
@@ -38,14 +32,14 @@ import pytest
 from repro.core.jobs import Job
 from repro.sim.contracts import (CONTRACTS, FAULT_CONTRACT,
                                  LIVE_CONTRACT, ROUNDS_CONTRACT,
-                                 SCAN_CONTRACT, check_fidelity)
+                                 check_fidelity)
 from repro.sim.sweep import ScanOptions, SweepPoint, run_sweep
 
 pytestmark = pytest.mark.tier1
 
 DAY = 24 * 3600.0
 N_JOBS = 36          # fixed -> shared RoundsSpec.max_rounds -> one compile
-N_WS_STEPS = 24      # fixed -> shared pick_dt / budget across seeds
+N_WS_STEPS = 24      # fixed -> shared round budget across seeds
 WINDOW = 48          # >= N_JOBS: no backlog can outgrow the lanes
 
 POINTS = [SweepPoint("fb", capacity=16),
@@ -67,144 +61,84 @@ def scenario(seed: int):
     return jobs, ws
 
 
-def run_engines(jobs, ws, coalesce=None):
-    """The shared fixture core: one scenario through all the engines —
-    the event reference, the scan, the event-round engine, its
-    coalesced variant and its fused-Pallas-kernel backend. Returns
-    ``{engine_name: rows}`` aligned with POINTS."""
-    opts = ScanOptions(window=WINDOW)
-    out = {
+def run_engines(jobs, ws):
+    """The shared fixture core: one scenario through the event reference
+    and the event-round engine. Returns ``{engine_name: rows}`` aligned
+    with POINTS."""
+    return {
         "event": run_sweep(POINTS, jobs, ws, DAY, mode="event"),
-        "scan": run_sweep(POINTS, jobs, ws, DAY, mode="scan",
-                          scan_options=opts),
         "rounds": run_sweep(POINTS, jobs, ws, DAY, mode="rounds",
-                            scan_options=opts),
-        "rounds_coalesced": run_sweep(
-            POINTS, jobs, ws, DAY, mode="rounds",
-            scan_options=ScanOptions(window=WINDOW,
-                                     coalesce=coalesce or 8)),
-        "rounds_pallas": run_sweep(
-            POINTS, jobs, ws, DAY, mode="rounds",
-            scan_options=ScanOptions(window=WINDOW, kernel="pallas")),
+                            scan_options=ScanOptions(window=WINDOW)),
     }
-    return out
 
 
 def assert_contracts(engines: dict, label) -> None:
-    """Per-engine fidelity contracts against the event reference —
-    the assertions AND the bench gate read repro.sim.contracts.
+    """The rounds fidelity contract against the event reference — the
+    assertions AND the bench gate read repro.sim.contracts.
 
     One carve-out, inherited from tests/test_rounds.py: the FLB-NUB
     bands are paper-grid contracts (gated for real by the sweep
     benchmark's --check-fidelity on the Fig. 13/14/18 grids). On
     adversarial random microtraces — WS demand repeatedly crossing a
-    tiny lb_ws — the U/V/G feedback's shared policy approximation can
-    overshoot them in every fast engine identically, so the random
-    differential holds FLB-NUB to DOUBLE each band (still a tight
-    differential against real divergence) while FB stays at the full
-    contract (its peak is exact by construction) and completed-job
-    exactness stays absolute everywhere."""
+    tiny lb_ws — the U/V/G feedback's policy approximation can
+    overshoot them, so the random differential holds FLB-NUB to DOUBLE
+    the node-hours band (still a tight differential against real
+    divergence) and leaves its peak to the paper grids, while FB stays
+    at the full contract (its peak is exact by construction) and
+    completed-job exactness stays absolute everywhere."""
     import dataclasses
 
     ev = engines["event"]
-    for name in ("scan", "rounds", "rounds_coalesced", "rounds_pallas"):
-        rows = engines[name]
-        for r in rows:
-            assert r["window_overflow"] == 0, (label, name, r["system"])
-            assert r.get("truncated", 0) == 0, (label, name, r["system"])
-        violations = []
-        for r, e in zip(rows, ev):
-            c = CONTRACTS[r["engine"]]
-            if r["system"].startswith("FLB-NUB"):
-                # Double the node-hours band; the FLB peak is checked
-                # across the fast engines instead (below) — the event
-                # comparison for it is a paper-grid contract only
-                # (same carve-out as tests/test_rounds.py).
-                c = dataclasses.replace(
-                    c, node_hours_rel=2 * c.node_hours_rel,
-                    peak_rel=float("inf"))
-            if not c.completed_exact:
-                # The scan's 2 % completed band is calibrated on the
-                # ~2.6k-job paper traces; on an N_JOBS microtrace one
-                # substep-displaced §5.1 kill cascade moves whole jobs,
-                # so allow 3 jobs of slack there. The rounds family
-                # keeps the absolute exactness promise regardless.
-                c = dataclasses.replace(
-                    c, completed_rel=max(
-                        c.completed_rel,
-                        3.0 / max(e["completed_jobs"], 1)))
-            violations += [f"{r['system']}: {v}"
-                           for v in c.check_row(r, e)]
-        assert not violations, (label, name, violations)
-        # The rounds family additionally promises exact completion
-        # counts — assert the integer equality directly (not via the
-        # drift machinery), for the plain AND coalesced variants.
-        if name.startswith("rounds"):
-            for r, e in zip(rows, ev):
-                assert r["completed_jobs"] == e["completed_jobs"], (
-                    label, name, r["system"])
-    # The FLB peak residue is the POLICY approximation, shared by the
-    # fast engines — they must agree with each other about it.
-    for r_plain, r_coal in zip(engines["rounds"],
-                               engines["rounds_coalesced"]):
-        assert r_plain["peak_nodes"] == r_coal["peak_nodes"], (
-            label, r_plain["system"])
-    # The fused Pallas backend is not merely within-contract: it runs
-    # the same _chunk_core math on a float-packed state, so its rows
-    # must equal the unfused rounds rows BIT-FOR-BIT.
-    assert engines["rounds_pallas"] == engines["rounds"], (
-        label, [(i, a, b) for i, (a, b) in
-                enumerate(zip(engines["rounds"],
-                              engines["rounds_pallas"])) if a != b][:2])
+    rows = engines["rounds"]
+    for r in rows:
+        assert r["window_overflow"] == 0, (label, r["system"])
+        assert r.get("truncated", 0) == 0, (label, r["system"])
+    violations = []
+    for r, e in zip(rows, ev):
+        c = CONTRACTS[r["engine"]]
+        if r["system"].startswith("FLB-NUB"):
+            c = dataclasses.replace(
+                c, node_hours_rel=2 * c.node_hours_rel,
+                peak_rel=float("inf"))
+        violations += [f"{r['system']}: {v}" for v in c.check_row(r, e)]
+    assert not violations, (label, violations)
+    # The rounds engine promises exact completion counts — assert the
+    # integer equality directly (not via the drift machinery).
+    for r, e in zip(rows, ev):
+        assert r["completed_jobs"] == e["completed_jobs"], (
+            label, r["system"])
 
 
 @pytest.mark.parametrize("seed", range(4))
 def test_differential_random_traces(seed):
     jobs, ws = scenario(seed)
-    engines = run_engines(jobs, ws)
-    assert_contracts(engines, seed)
-    # Both rounds variants must agree with EACH OTHER on the job
-    # counts exactly; turnaround can carry a small residue at the
-    # default 2-pass first-fit — the plain engine may under-admit for
-    # a round where the coalescer's instants are provably exact or
-    # deferred — and collapses to 1e-9 agreement at ff_passes=8 in
-    # float64 (test_differential_completion_times_bit_match_in_float64).
-    for r_plain, r_coal in zip(engines["rounds"],
-                               engines["rounds_coalesced"]):
-        assert r_plain["completed_jobs"] == r_coal["completed_jobs"]
-        assert r_plain["avg_turnaround"] == pytest.approx(
-            r_coal["avg_turnaround"], rel=0.01)
+    assert_contracts(run_engines(jobs, ws), seed)
 
 
 def test_differential_completion_times_bit_match_in_float64():
-    """The rounds engines' stronger promise: with float64 lanes and
+    """The rounds engine's stronger promise: with float64 lanes and
     enough first-fit passes the completion *times* (through the
-    turnaround/execution sums) match the event engine to round-off —
-    for the coalesced variant too. The WS trace is flat: demand rises
-    trigger §5.1 kills, whose size-class tie-breaking is the one
-    documented divergence from the engine's latest-start order (the
-    same precondition as tests/test_rounds.py's exactness property)."""
+    turnaround/execution sums) match the event engine to round-off.
+    The WS trace is flat: demand rises trigger §5.1 kills, whose
+    size-class tie-breaking is the one documented divergence from the
+    engine's latest-start order (the same precondition as
+    tests/test_rounds.py's exactness property)."""
     import jax
 
     jobs, _ = scenario(97)
     ws = [(0.0, 3)]
     ev = run_sweep(POINTS, jobs, ws, DAY, mode="event")
     with jax.enable_x64(True):
-        for coalesce in (1, 8):
-            rows = run_sweep(
-                POINTS, jobs, ws, DAY, mode="rounds",
-                scan_options=ScanOptions(window=WINDOW, ff_passes=8,
-                                         coalesce=coalesce,
-                                         dtype=np.float64))
-            for r, e in zip(rows, ev):
-                assert r["completed_jobs"] == e["completed_jobs"], (
-                    coalesce, r["system"])
-                assert r["avg_turnaround"] == pytest.approx(
-                    e["avg_turnaround"], rel=1e-9), (coalesce,
-                                                     r["system"])
-                assert r["avg_execution"] == pytest.approx(
-                    e["avg_execution"], rel=1e-9), (coalesce,
-                                                    r["system"])
+        rows = run_sweep(
+            POINTS, jobs, ws, DAY, mode="rounds",
+            scan_options=ScanOptions(window=WINDOW, ff_passes=8,
+                                     dtype=np.float64))
+    for r, e in zip(rows, ev):
+        assert r["completed_jobs"] == e["completed_jobs"], r["system"]
+        assert r["avg_turnaround"] == pytest.approx(
+            e["avg_turnaround"], rel=1e-9), r["system"]
+        assert r["avg_execution"] == pytest.approx(
+            e["avg_execution"], rel=1e-9), r["system"]
 
 
 try:
@@ -226,14 +160,13 @@ if HAVE_HYPOTHESIS:
         assert_contracts(run_engines(jobs, ws), seed)
 
 
-# ------------------------------------------------ coalescer regression
+# ------------------------------------------------ all-contended drain
 
 def crafted_all_contended():
-    """The crafted all-contended trace of the coalescer regression:
-    C nodes, >C equal unit jobs all submitted at t=0, flat WS, a lease
-    longer than the horizon — the queue drains one GENERATION of C
-    simultaneous completions at a time with no reactable WS change,
-    submit or lease boundary in between."""
+    """The crafted all-contended trace: C nodes, >C equal unit jobs all
+    submitted at t=0, flat WS, a lease longer than the horizon — the
+    queue drains one GENERATION of C simultaneous completions at a time
+    with no reactable WS change, submit or lease boundary in between."""
     C, gens, rt = 16, 6, 1000.0
     jobs = [Job(i, 0.0, size=1, runtime=rt) for i in range(C * gens)]
     ws = [(0.0, 0)]
@@ -242,43 +175,33 @@ def crafted_all_contended():
     return jobs, ws, duration, point, C, gens, rt
 
 
-def test_coalescer_all_contended_regression():
-    """One coalesced round absorbs a whole batch of completions plus
-    the head-of-queue starts they admit: per-job completion times
+def test_all_contended_drain_completes_exactly():
+    """Each round of the drain completes a whole generation and starts
+    the next one from the queue head: per-job completion times
     reproduce the event engine bit-exactly (generation k completes at
-    exactly k*rt) and the coalesced round count obeys the
-    ceil(completions / batch) bound — strictly fewer rounds than the
-    uncoalesced engine spends on the same drain."""
+    exactly k*rt), and the drain takes one round per generation: the
+    last folds its completions on the way to the horizon."""
     from repro.sim.engine import build_fb, clone_jobs, run_sim
 
     jobs, ws, duration, point, C, gens, rt = crafted_all_contended()
-    batch = 8
     ref = run_sim(build_fb(C, point.lease_seconds), clone_jobs(jobs), ws,
                   duration)
     assert ref.completed_jobs == C * gens
     assert ref.avg_execution == rt          # every generation runs rt
 
-    plain = run_sweep([point], jobs, ws, duration, mode="rounds",
-                      scan_options=ScanOptions(window=128))[0]
-    coal = run_sweep([point], jobs, ws, duration, mode="rounds",
-                     scan_options=ScanOptions(window=128,
-                                              coalesce=batch))[0]
-    for row in (plain, coal):
-        assert row["window_overflow"] == 0 and row["truncated"] == 0
-        assert row["completed_jobs"] == C * gens
-        # Bit-exact per-job times: generation k completes at k*rt, so
-        # the turnaround mean is exactly rt * (1 + ... + gens) / gens.
-        exact_turn = rt * (gens + 1) / 2.0
-        assert row["avg_turnaround"] == exact_turn
-        assert row["avg_execution"] == rt
-        # The time integrals accumulate in the lane dtype (float32 by
-        # default) — equality up to its round-off, not bit-for-bit.
-        assert row["node_hours"] == pytest.approx(ref.node_hours,
-                                                  rel=1e-6)
-        assert row["peak_nodes"] == ref.peak_nodes == C
-    assert coal["coalesced"] > 0
-    assert coal["rounds"] <= math.ceil(C * gens / batch)
-    assert coal["rounds"] < plain["rounds"]
+    row = run_sweep([point], jobs, ws, duration, mode="rounds",
+                    scan_options=ScanOptions(window=128))[0]
+    assert row["window_overflow"] == 0 and row["truncated"] == 0
+    assert row["completed_jobs"] == C * gens
+    # Bit-exact per-job times: generation k completes at k*rt, so the
+    # turnaround mean is exactly rt * (1 + ... + gens) / gens.
+    assert row["avg_turnaround"] == rt * (gens + 1) / 2.0
+    assert row["avg_execution"] == rt
+    # The time integrals accumulate in the lane dtype (float32 by
+    # default) — equality up to its round-off, not bit-for-bit.
+    assert row["node_hours"] == pytest.approx(ref.node_hours, rel=1e-6)
+    assert row["peak_nodes"] == ref.peak_nodes == C
+    assert row["rounds"] == gens
 
 
 # --------------------------------------- bench gate <-> contract table
@@ -315,16 +238,12 @@ def test_bench_gate_uses_the_contract_table():
 
 
 def test_contract_table_values():
-    """The documented bands: scan 2 %/15 %/15 %, rounds exact/5 %/5 %,
+    """The documented bands: rounds exact/5 %/5 %,
     live exact/10 %/10 % plus the 25 % demand-drift bounds, faults
     ±2-jobs-or-2 %/2 %/2 %, queries' §6 headline bands 40–55 %/28–45 %
     (pinned value-by-value in test_capacity.py). A change here is a
     contract change — update README and the bench note in the same
     commit."""
-    assert SCAN_CONTRACT.completed_rel == 0.02
-    assert SCAN_CONTRACT.node_hours_rel == 0.15
-    assert SCAN_CONTRACT.peak_rel == 0.15
-    assert not SCAN_CONTRACT.completed_exact
     assert ROUNDS_CONTRACT.completed_exact
     assert ROUNDS_CONTRACT.node_hours_rel == 0.05
     assert ROUNDS_CONTRACT.peak_rel == 0.05
@@ -338,8 +257,8 @@ def test_contract_table_values():
     assert FAULT_CONTRACT.completed_rel == 0.02
     assert FAULT_CONTRACT.node_hours_rel == 0.02
     assert FAULT_CONTRACT.peak_rel == 0.02
-    assert set(CONTRACTS) == {"scan", "rounds", "vectorized", "live",
-                              "faults", "queries"}
+    assert set(CONTRACTS) == {"rounds", "vectorized", "live", "faults",
+                              "queries"}
 
 
 def test_check_fidelity_flags_violations():
@@ -349,7 +268,7 @@ def test_check_fidelity_flags_violations():
     assert check_fidelity(good, ev) == []
     bad = [dict(ev[0], engine="rounds", completed_jobs=99)]
     assert any("completed_jobs" in v for v in check_fidelity(bad, ev))
-    drifted = [dict(ev[0], engine="scan", node_hours=120.0)]
+    drifted = [dict(ev[0], engine="rounds", node_hours=106.0)]
     assert any("node_hours" in v for v in check_fidelity(drifted, ev))
-    ok_scan = [dict(ev[0], engine="scan", node_hours=114.0)]
-    assert check_fidelity(ok_scan, ev) == []
+    ok_rounds = [dict(ev[0], engine="rounds", node_hours=104.0)]
+    assert check_fidelity(ok_rounds, ev) == []

@@ -284,16 +284,22 @@ def test_event_vs_rounds_fault_differential():
     """One schedule through both engines: node-hours/peak in the 2 %
     band, completions within ±2 jobs (CONTRACTS['faults'] — the same
     table the bench gate reads)."""
-    from repro.sim.rounds import fb_rounds_row
+    from repro.sim.sweep import SweepPoint, run_sweep_workloads
     capacity, lease, horizon = 12, 3600.0, DAY
     jobs, ws = _chaos_workload(capacity=capacity, horizon=horizon)
+
+    def rounds_row(faults=None):
+        point = SweepPoint("fb", capacity=capacity, lease_seconds=lease)
+        return run_sweep_workloads([point], [(jobs, ws)], horizon,
+                                   mode="rounds", faults=[faults])[0][0]
+
     sched = _chaos_schedule(capacity, horizon)
     assert len(sched) > 4
     sys_ = build_fb(capacity, lease)
     ev_jobs = clone_jobs(jobs)
     ev = run_sim(sys_, ev_jobs, ws, duration=horizon, name="event",
                  faults=sched)
-    rr = fb_rounds_row(jobs, ws, capacity, lease, horizon, faults=sched)
+    rr = rounds_row(sched)
     assert rr["engine"] == "rounds"
     violations = FAULT_CONTRACT.check_row(rr, ev.row())
     assert violations == [], violations
@@ -303,7 +309,7 @@ def test_event_vs_rounds_fault_differential():
     # under the ordinary exact rounds semantics.
     ev0 = run_sim(build_fb(capacity, lease), clone_jobs(jobs), ws,
                   duration=horizon, name="event")
-    rr0 = fb_rounds_row(jobs, ws, capacity, lease, horizon)
+    rr0 = rounds_row()
     assert rr0["completed_jobs"] == ev0.completed_jobs
     # (float32 accumulation in the rounds kernel — not the fault band)
     assert rr0["node_hours"] == pytest.approx(ev0.node_hours, rel=1e-5)

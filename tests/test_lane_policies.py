@@ -1,18 +1,19 @@
-"""FB kill semantics and accounting invariants of the batched scan path.
+"""FB kill semantics and accounting invariants of the batched rounds path.
 
-The scan encoding (repro.sim.scan) replaces the event engine's Python
+The lane encoding (repro.sim.lanes) replaces the event engine's Python
 queue/kill machinery with status lanes over a fixed job window: a kill
 is a masked flag flip and the killed lane *derives* back into the queue.
-These tests pin that encoding down, in the spirit of
-tests/test_pool_accounting.py:
+These tests pin that encoding down through ``mode="rounds"``, in the
+spirit of tests/test_pool_accounting.py:
 
-  * a designed §5.1 demand-spike scenario where completion is only
-    possible if killed jobs re-enter the queue and restart;
   * randomized (jobs, WS) workloads cross-checked against the event
     engine — kill activity, completed jobs and node-hours must agree;
-  * capacity / pool invariants readable from the scan's metrics: an FB
-    site never allocates beyond C, an FLB-NUB site never drops the
-    rigid pool B (§5.2 — it is paid for whether idle or not).
+  * capacity / pool invariants readable from the rows: an FB site never
+    allocates beyond C, an FLB-NUB site never drops the rigid pool B
+    (§5.2 — it is paid for whether idle or not).
+
+The designed §5.1 demand-spike scenarios (kills, re-entry, a partial
+kill) live in tests/test_rounds.py.
 """
 
 import random
@@ -29,8 +30,8 @@ DAY = 24 * 3600.0
 OPTS = ScanOptions(window=32)   # tiny window: these workloads are small
 
 
-def scan_row(point, jobs, ws, duration):
-    return run_sweep([point], jobs, ws, duration, mode="scan",
+def rounds_row(point, jobs, ws, duration):
+    return run_sweep([point], jobs, ws, duration, mode="rounds",
                      scan_options=OPTS)[0]
 
 
@@ -48,35 +49,6 @@ def spike_workload():
     return jobs, ws
 
 
-def test_fb_scan_killed_jobs_reenter_and_finish():
-    jobs, ws = spike_workload()
-    point = SweepPoint("fb", capacity=10)
-    row = scan_row(point, jobs, ws, 8 * 3600.0)
-    ref = run_sim(build_fb(10), clone_jobs(jobs), ws, 8 * 3600.0)
-    assert ref.kills == 2                       # the scenario really kills
-    assert row["kills"] == ref.kills
-    # Re-entry: all three jobs complete in BOTH engines — impossible for
-    # the killed pair unless they re-queued and restarted.
-    assert ref.completed_jobs == 3
-    assert row["completed_jobs"] == 3
-    assert row["peak_nodes"] == ref.peak_nodes == 10
-    assert row["node_hours"] == pytest.approx(ref.node_hours, rel=0.05)
-    assert row["window_overflow"] == 0
-
-
-def test_fb_scan_partial_kill_prefers_fewest_nodes():
-    """A smaller spike (demand 5, free 2 after the small job finished)
-    needs only 3 more nodes — exactly one of the size-4 jobs dies, in
-    both engines."""
-    jobs, ws = spike_workload()
-    ws = [(0.0, 0), (1800.0, 5), (2 * 3600.0, 0)]
-    row = scan_row(SweepPoint("fb", capacity=10), jobs, ws, 8 * 3600.0)
-    ref = run_sim(build_fb(10), clone_jobs(jobs), ws, 8 * 3600.0)
-    assert ref.kills == 1
-    assert row["kills"] == 1
-    assert row["completed_jobs"] == ref.completed_jobs == 3
-
-
 # ------------------------------------------------- randomized cross-checks
 
 def random_workload(seed):
@@ -85,25 +57,27 @@ def random_workload(seed):
                 size=2 ** rng.randrange(0, 4),
                 runtime=rng.uniform(900.0, 2 * 3600.0))
             for i in range(30)]
-    # WS change points on a 900 s grid (>= the scan substep, so both
-    # engines see the same demand signal).
+    # WS change points on a 900 s grid.
     ws = [(k * 900.0, rng.randrange(0, 13)) for k in range(0, 96, 2)]
     return jobs, ws
 
 
 @pytest.mark.parametrize("seed", range(6))
-def test_fb_scan_matches_event_on_random_traces(seed):
+def test_fb_rounds_matches_event_on_random_traces(seed):
     jobs, ws = random_workload(seed)
     C = 12
-    row = scan_row(SweepPoint("fb", capacity=C), jobs, ws, DAY)
+    row = rounds_row(SweepPoint("fb", capacity=C), jobs, ws, DAY)
     ref = run_sim(build_fb(C), clone_jobs(jobs), ws, DAY)
     assert row["window_overflow"] == 0
     # Kill activity agrees (node-weighted timing differences allowed).
     assert (row["kills"] > 0) == (ref.kills > 0)
     assert abs(row["kills"] - ref.kills) <= max(2, 0.5 * ref.kills)
-    # Jobs are conserved: killed jobs re-enter, nothing is lost.
+    # Jobs are conserved: killed jobs re-enter, nothing is lost. The
+    # rounds contract's exact count does not hold here: the size-class
+    # kill order breaks §5.1's ties differently (an open fault), and a
+    # different victim can finish on either side of the horizon.
     assert abs(row["completed_jobs"] - ref.completed_jobs) <= 2
-    assert row["node_hours"] == pytest.approx(ref.node_hours, rel=0.15)
+    assert row["node_hours"] == pytest.approx(ref.node_hours, rel=0.05)
     # Capacity invariant: an FB site can never allocate beyond C (§5.1).
     assert row["peak_nodes"] <= C
     assert row["node_hours"] <= C * DAY / 3600.0 + 1e-6
@@ -116,7 +90,7 @@ def test_flb_nub_never_kills(seed):
     """The §5.2 policy has no force-release path: WS demand is satisfied
     elastically (never by taking PBJ nodes back) and the RSS release
     only ever returns *free* nodes — so FLB-NUB cannot kill, even on the
-    kill-provoking workloads that make FB kill. This is why the scan
+    kill-provoking workloads that make FB kill. This is why the rounds
     path's checkpoint_preempt guard (repro.sim.sweep) rejects only FB
     points: for FLB-NUB the preemption mode is provably a no-op."""
     from repro.core.pbj_manager import PBJPolicyParams
@@ -133,14 +107,14 @@ def test_flb_nub_never_kills(seed):
                    ws, DAY).kills > 0, seed
 
 
-def test_flb_nub_scan_accepts_checkpoint_preempt():
-    """mode="scan" accepts FLB-NUB points with checkpoint_preempt=True
+def test_flb_nub_rounds_accepts_checkpoint_preempt():
+    """mode="rounds" accepts FLB-NUB points with checkpoint_preempt=True
     (deliberate exemption — see test_flb_nub_never_kills) and returns
     the same rows as without the flag, since nothing is ever killed."""
     from repro.core.pbj_manager import PBJPolicyParams
 
     jobs, ws = random_workload(7)
-    rows = [scan_row(SweepPoint("flb_nub", lb_pbj=13, lb_ws=12,
+    rows = [rounds_row(SweepPoint("flb_nub", lb_pbj=13, lb_ws=12,
                                 params=PBJPolicyParams(
                                     checkpoint_preempt=preempt)),
                      jobs, ws, DAY)
@@ -149,36 +123,14 @@ def test_flb_nub_scan_accepts_checkpoint_preempt():
     assert rows[0] == rows[1]
 
 
-def test_pick_dt_caps_flb_substep_by_ws_spacing():
-    """The FLB-NUB substep never exceeds the WS change-point spacing
-    (the U/V/G feedback runs on sampled demand — a finer trace would
-    alias), floored at FLB_MIN_DT; FB keeps its validated coarse grid."""
-    from repro.sim import scan as scanlib
-
-    assert scanlib.pick_dt("fb", [3600.0]) == scanlib.FB_DT
-    assert scanlib.pick_dt("flb_nub", [3600.0]) == scanlib.FLB_DT
-    assert scanlib.pick_dt("flb_nub", [120.0]) == 120.0     # lease cap
-    ws = [(0.0, 1), (150.0, 2), (300.0, 3)]
-    assert scanlib.pick_dt("flb_nub", [3600.0], [ws]) == 150.0
-    ws_fine = [(0.0, 1), (1.0, 2), (2.0, 1)]
-    assert scanlib.pick_dt("flb_nub", [3600.0], [ws_fine]) \
-        == scanlib.FLB_MIN_DT
-    assert scanlib.pick_dt("fb", [3600.0], [ws]) == scanlib.FB_DT
-    # Change points beyond the simulated horizon are never sampled and
-    # must not shrink the substep.
-    ws_late = [(0.0, 1), (9000.0, 2), (9150.0, 3)]
-    assert scanlib.pick_dt("flb_nub", [3600.0], [ws_late],
-                           duration=7200.0) == scanlib.FLB_DT
-    assert scanlib.pick_dt("flb_nub", [3600.0], [ws_late]) == 150.0
-
-
-def test_flb_scan_peak_contract_on_beyond_paper_grid():
+def test_flb_rounds_peak_contract_on_beyond_paper_grid():
     """Regression for the long-lease peak overshoot: on L = 2 h with a
-    2×-scaled World Cup profile (a beyond-paper combo) the scan used to
-    evaluate the U/V/G rules on *pre-start* demand, letting one tick
-    absorb a whole submit burst as a single DR1 request — 57 % peak
-    drift vs the event engine. With the event-faithful tick ordering
-    (grant → first-fit → adjust → first-fit) the 15 % contract holds."""
+    2×-scaled World Cup profile (a beyond-paper combo), evaluating the
+    U/V/G rules on *pre-start* demand lets one tick absorb a whole
+    submit burst as a single DR1 request — 57 % peak drift vs the event
+    engine. With the event-faithful tick ordering of ``flb_actions``
+    (grant → first-fit → adjust → first-fit) the rounds contract
+    holds: completed jobs exact, node-hours and peak within 5 %."""
     from repro.core.profiles import scale_profile
     from repro.sim import traces
 
@@ -188,31 +140,30 @@ def test_flb_scan_peak_contract_on_beyond_paper_grid():
     pts = [SweepPoint("flb_nub", lb_pbj=13, lb_ws=12, lease_seconds=L,
                       label=f"FLB-NUB(L={L:g}s)")
            for L in (7200.0, 14400.0)]
-    scan = run_sweep(pts, jobs, ws, T, mode="scan")
+    rounds = run_sweep(pts, jobs, ws, T, mode="rounds")
     event = run_sweep(pts, jobs, ws, T, mode="event")
-    for p, s, e in zip(pts, scan, event):
+    for p, s, e in zip(pts, rounds, event):
         assert s["window_overflow"] == 0, p
         assert s["peak_nodes"] == pytest.approx(e["peak_nodes"],
-                                                rel=0.15), p
+                                                rel=0.05), p
         assert s["node_hours"] == pytest.approx(e["node_hours"],
-                                                rel=0.15), p
-        assert abs(s["completed_jobs"] - e["completed_jobs"]) \
-            <= max(2, 0.02 * e["completed_jobs"]), p
+                                                rel=0.05), p
+        assert s["completed_jobs"] == e["completed_jobs"], p
 
 
 @pytest.mark.parametrize("seed", (0, 1, 2))
-def test_flb_scan_pool_invariants_on_random_traces(seed):
+def test_flb_rounds_pool_invariants_on_random_traces(seed):
     jobs, ws = random_workload(100 + seed)
     lb_pbj, lb_ws = 13, 12
     B = lb_pbj + lb_ws
-    row = scan_row(SweepPoint("flb_nub", lb_pbj=lb_pbj, lb_ws=lb_ws),
+    row = rounds_row(SweepPoint("flb_nub", lb_pbj=lb_pbj, lb_ws=lb_ws),
                    jobs, ws, DAY)
     ref = run_sim(build_flb_nub(lb_pbj, lb_ws), clone_jobs(jobs), ws, DAY)
     assert row["window_overflow"] == 0
     assert row["kills"] == 0                    # FLB-NUB never kills (§5.2)
-    assert abs(row["completed_jobs"] - ref.completed_jobs) <= 2
-    assert row["node_hours"] == pytest.approx(ref.node_hours, rel=0.15)
-    # Pool invariants (the scan analog of test_pool_accounting P5): the
+    assert row["completed_jobs"] == ref.completed_jobs
+    assert row["node_hours"] == pytest.approx(ref.node_hours, rel=0.05)
+    # Pool invariants (the lane analog of test_pool_accounting P5): the
     # rigid pool B is held for the whole trace, so consumption is at
     # least B node-hours per hour and the peak is at least B.
     assert row["node_hours"] >= B * DAY / 3600.0 - 1e-6

@@ -1,14 +1,12 @@
 """Event-round engine (repro.sim.rounds) vs the discrete-event engine.
 
-The rounds engine's contract is *tighter* than the scan's: jumping
-straight to event times makes completions exact (no substep rounding),
-so on any workload the completed-job count must match the event engine
-exactly and — with enough first-fit passes for the queue to resolve the
-way the engine's sequential scan does — the completion *times* must
-match too, not just within a tolerance. These tests pin that, the §5.1
-kill semantics on the designed spike scenario, the window-overflow
-diagnostic (surfaced as a RuntimeWarning — the satellite of this PR),
-and the pick_dt edge cases of the fixed-dt scan it complements.
+Jumping straight to event times makes completions exact (no substep
+rounding), so on any workload the completed-job count must match the
+event engine exactly and — with enough first-fit passes for the queue
+to resolve the way the engine's sequential scan does — the completion
+*times* must match too, not just within a tolerance. These tests pin
+that, the §5.1 kill semantics on the designed spike scenario and the
+window-overflow diagnostic (surfaced as a RuntimeWarning).
 """
 
 import random
@@ -101,9 +99,8 @@ def test_rounds_fidelity_contract_on_random_traces(seed):
             # FB peak is exact by construction (the §5.1 ratchet makes
             # each lease window's max analytic). FLB-NUB peak carries
             # the shared U/V/G *policy* approximation on adversarial
-            # small traces — the scan path reports the identical value
-            # — so only the paper-grid contract (<= 5 %, gated in the
-            # sweep benchmark) applies to it.
+            # small traces, so only the paper-grid contract (<= 5 %,
+            # gated in the sweep benchmark) applies to it.
             assert row["peak_nodes"] == ref.peak_nodes, (seed, point)
 
 
@@ -174,7 +171,7 @@ def test_rounds_fb_partial_kill():
 def test_rounds_window_overflow_warns():
     """A window too small for the backlog must not fail silently: the
     rows carry ``window_overflow`` and run_sweep emits a
-    RuntimeWarning (this PR's diagnostic satellite)."""
+    RuntimeWarning."""
     rng = random.Random(7)
     jobs = [Job(i, float(i), size=8, runtime=9 * 3600.0)
             for i in range(24)]          # 24 jobs, site fits 1 at a time
@@ -187,20 +184,6 @@ def test_rounds_window_overflow_warns():
     messages = [str(w.message) for w in caught
                 if issubclass(w.category, RuntimeWarning)]
     assert any("backlog outgrew" in m for m in messages), messages
-
-
-def test_scan_window_overflow_warns_too():
-    """Same surface for the fixed-dt scan path."""
-    jobs = [Job(i, float(i), size=8, runtime=9 * 3600.0)
-            for i in range(24)]
-    ws = [(0.0, 0)]
-    with warnings.catch_warnings(record=True) as caught:
-        warnings.simplefilter("always")
-        row = run_sweep([SweepPoint("fb", capacity=8)], jobs, ws, DAY,
-                        mode="scan",
-                        scan_options=ScanOptions(window=8))[0]
-    assert row["window_overflow"] > 0
-    assert any("backlog outgrew" in str(w.message) for w in caught)
 
 
 def test_rounds_rejects_checkpoint_preempt_and_auto_falls_back():
@@ -283,33 +266,6 @@ def test_stacked_workloads_match_single_workload_calls():
     # The workloads end at different depths, so some lanes idled.
     depths = {max(r["rounds"] for r in rows) for rows in stacked}
     assert len(depths) == 3
-
-
-# ------------------------------------------------------ pick_dt edges
-
-def test_pick_dt_edge_cases():
-    """The satellite's pick_dt edge cases: empty WS change-point lists,
-    change spacing below FLB_MIN_DT, and single-lease grids."""
-    from repro.sim import scan as scanlib
-
-    # Empty ws_traces containers: the spacing cap must not fire.
-    assert scanlib.pick_dt("flb_nub", [3600.0], None) == scanlib.FLB_DT
-    assert scanlib.pick_dt("flb_nub", [3600.0], []) == scanlib.FLB_DT
-    assert scanlib.pick_dt("flb_nub", [3600.0], [[]]) == scanlib.FLB_DT
-    assert scanlib.pick_dt("flb_nub", [3600.0],
-                           [[(0.0, 3)]]) == scanlib.FLB_DT
-    # Spacing below the floor clamps at FLB_MIN_DT, never explodes the
-    # substep count.
-    ws_fine = [(float(k), k % 3) for k in range(100)]
-    assert scanlib.pick_dt("flb_nub", [3600.0],
-                           [ws_fine]) == scanlib.FLB_MIN_DT
-    # Single-lease grids: the lease caps the substep for both policies.
-    assert scanlib.pick_dt("fb", [450.0]) == 450.0
-    assert scanlib.pick_dt("flb_nub", [120.0]) == 120.0
-    assert scanlib.pick_dt("fb", [3600.0]) == scanlib.FB_DT
-    # The FB grid ignores WS spacing (its reclaim is demand-driven, not
-    # sampled): even a 1-second trace keeps the coarse substep.
-    assert scanlib.pick_dt("fb", [3600.0], [ws_fine]) == scanlib.FB_DT
 
 
 def test_round_budget_scales_with_inputs():

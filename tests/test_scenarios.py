@@ -312,8 +312,10 @@ def test_generated_sweep_rejects_bad_modes_and_duration():
     points = [SweepPoint("fb", capacity=48)]
     with pytest.raises(ValueError, match="duration is fixed"):
         run_sweep_workloads(points, grid, 3 * DAY, mode="rounds")
-    with pytest.raises(ValueError):
-        run_sweep_workloads(points, grid, mode="scan")
+    # "scan" names no engine: an unknown mode like any other.
+    removed = "scan"
+    with pytest.raises(ValueError, match="unknown mode"):
+        run_sweep_workloads(points, grid, mode=removed)
     with pytest.raises(ValueError):
         run_sweep_workloads([SweepPoint("dcs", prc_pbj=32, prc_ws=32)],
                             grid, mode="rounds")

@@ -13,9 +13,9 @@ import pytest
 pytestmark = pytest.mark.tier1
 
 from repro.sim import traces
+from repro.sim.contracts import CONTRACTS
 from repro.sim.engine import run_sim
-from repro.sim.sweep import (SweepPoint, _build, paper_grid, run_sweep,
-                             run_sweep_workloads)
+from repro.sim.sweep import SweepPoint, _build, paper_grid, run_sweep
 
 # Small trace grid: the first two simulated days of the moment-matched
 # NASA-iPSC + WorldCup pair, including jobs that straddle the horizon.
@@ -115,7 +115,7 @@ def test_paper_grid_shape_and_fallback_routing(workload):
         assert _build(p).lease_seconds == p.lease_seconds
 
 
-# ----------------------------------------------------- mode="scan" fast path
+# --------------------------------------------------- mode="rounds" fast path
 
 def test_sweep_point_rejects_unknown_system():
     with pytest.raises(ValueError, match="unknown system"):
@@ -125,13 +125,18 @@ def test_sweep_point_rejects_unknown_system():
     with pytest.raises(ValueError, match="unknown mode"):
         run_sweep([SweepPoint("dcs", prc_pbj=1)], [], [(0.0, 0)], 10.0,
                   mode="warp")
-    # The scan kill encoding always restarts from scratch — the beyond-
+    # "scan" names no engine: an unknown mode like any other.
+    removed = "scan"
+    with pytest.raises(ValueError, match="unknown mode"):
+        run_sweep([SweepPoint("fb", capacity=8)], [], [(0.0, 0)], 10.0,
+                  mode=removed)
+    # The lane kill encoding always restarts from scratch — the beyond-
     # paper checkpoint-preempt mode must be rejected, not silently run.
     from repro.core.pbj_manager import PBJPolicyParams
     ckpt = SweepPoint("fb", capacity=8,
                       params=PBJPolicyParams(checkpoint_preempt=True))
     with pytest.raises(ValueError, match="checkpoint_preempt"):
-        run_sweep([ckpt], [], [(0.0, 0)], 7200.0, mode="scan")
+        run_sweep([ckpt], [], [(0.0, 0)], 7200.0, mode="rounds")
 
 
 @pytest.fixture(scope="module")
@@ -153,29 +158,28 @@ def scan_grid():
 
 
 def test_scan_mode_fidelity_contract(full_workload, scan_grid):
-    """The documented tolerances of the batched lax.scan path vs the
-    event engine on two-week paper workloads: completed jobs within 2 %,
-    node-hours and peak within 15 %, kill counts the same order."""
+    """The rounds contract (``CONTRACTS["rounds"]``) of the batched
+    event-round path vs the event engine on two-week paper workloads:
+    completed jobs exact, node-hours and peak within 5 %."""
     jobs, ws = full_workload
     T_full = traces.TWO_WEEKS
-    scan_rows = run_sweep(scan_grid, jobs, ws, T_full, mode="scan")
+    contract = CONTRACTS["rounds"]
+    rounds_rows = run_sweep(scan_grid, jobs, ws, T_full, mode="rounds")
     event_rows = run_sweep(scan_grid, jobs, ws, T_full, mode="event")
-    for p, s, e in zip(scan_grid, scan_rows, event_rows):
-        assert s["engine"] == "scan" and e["engine"] == "event"
+    for p, s, e in zip(scan_grid, rounds_rows, event_rows):
+        assert s["engine"] == "rounds" and e["engine"] == "event"
         assert s["window_overflow"] == 0, p
-        assert abs(s["completed_jobs"] - e["completed_jobs"]) \
-            <= max(2, 0.02 * e["completed_jobs"]), p
-        assert s["node_hours"] == pytest.approx(e["node_hours"], rel=0.15), p
-        assert s["peak_nodes"] == pytest.approx(e["peak_nodes"], rel=0.15), p
+        assert s["truncated"] == 0, p
+        assert contract.check_row(s, e) == [], p
 
 
 def test_scan_mode_preserves_sweep_orderings(full_workload, scan_grid):
-    """J1/J2 acceptance: the scan path ranks parameter-sweep points the
+    """J1/J2 acceptance: the rounds path ranks parameter-sweep points the
     same way the event engine does (Fig. 13 capacity → cost, Fig. 14
     B → cost and turnaround, Fig. 18 L → adjust events)."""
     jobs, ws = full_workload
     T_full = traces.TWO_WEEKS
-    scan_rows = run_sweep(scan_grid, jobs, ws, T_full, mode="scan")
+    scan_rows = run_sweep(scan_grid, jobs, ws, T_full, mode="rounds")
     event_rows = run_sweep(scan_grid, jobs, ws, T_full, mode="event")
 
     def order(rows, idx, metric):
@@ -194,30 +198,3 @@ def test_scan_mode_preserves_sweep_orderings(full_workload, scan_grid):
     # Fig. 18: PBJ adjust events fall as the lease unit grows.
     assert order(scan_rows, l_idx, "pbj_adjust_events") \
         == order(event_rows, l_idx, "pbj_adjust_events") == [2, 1, 0]
-
-
-def test_scan_mode_batches_the_trace_axis(workload):
-    """run_sweep_workloads: one scan call serves several workloads, and
-    per-workload rows reflect their own trace."""
-    jobs, ws = workload
-    jobs2 = [j for j in traces.sdsc_blue(seed=3) if j.submit < T]
-    ws2 = [(t, d) for t, d in traces.worldcup98(seed=4, peak_vms=64)
-           if t < T]
-    pts = [SweepPoint("fb", capacity=160),
-           SweepPoint("flb_nub", lb_pbj=13, lb_ws=12),
-           SweepPoint("ec2", lease_seconds=3600.0)]
-    rows = run_sweep_workloads(pts, [(jobs, ws), (jobs2, ws2)], T,
-                               mode="scan")
-    assert len(rows) == 2 and all(len(r) == len(pts) for r in rows)
-    for w, (wl_jobs, _) in enumerate([(jobs, ws), (jobs2, ws2)]):
-        assert rows[w][0]["engine"] == "scan"
-        assert rows[w][1]["engine"] == "scan"
-        assert rows[w][2]["engine"] == "vectorized"
-        ref = run_sweep(pts, *([(jobs, ws), (jobs2, ws2)][w]), T,
-                        mode="event")
-        for i in (0, 1):
-            assert abs(rows[w][i]["completed_jobs"]
-                       - ref[i]["completed_jobs"]) \
-                <= max(5, 0.05 * ref[i]["completed_jobs"])
-    # The two workloads genuinely differ, and so must their rows.
-    assert rows[0][1]["node_hours"] != rows[1][1]["node_hours"]

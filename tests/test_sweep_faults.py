@@ -3,13 +3,15 @@
 
 What is pinned here:
 
-* stacked faulted rows equal one-point ``fb_rounds_row`` rows bit for
-  bit, with workloads with and without a schedule in one device call;
+* stacked faulted rows equal one-point ``run_sweep_workloads`` rows bit
+  for bit, with workloads with and without a schedule in one device
+  call;
 * they hold ``CONTRACTS["faults"]`` against the event engine, and the
   event mode of the same call IS ``run_sim(faults=)``;
 * ``faults=None`` (or no schedule with an event) leaves the pack, the
   lowered program and the rows as they are without the argument;
-* DCS, EC2, FLB-NUB and the scan mode refuse a schedule;
+* DCS, EC2 and FLB-NUB points refuse a schedule, and ``"scan"`` is an
+  unknown mode;
 * the root span's counters and the ``rounds.fault_tables`` spans;
 * ``fault_stops`` fixes the program's shapes across fresh draws.
 """
@@ -30,6 +32,7 @@ from repro.sim.sweep import (ScanOptions, SweepPoint, _pack_rounds,
                              run_sweep_workloads)
 
 DAY = 24 * 3600.0
+REMOVED_MODE = "scan"
 CAP = 12
 LEASES = (900.0, 3600.0, 7200.0)
 
@@ -72,8 +75,8 @@ def _fault_root():
 
 def test_stacked_fault_rows_equal_one_point_rows():
     """W = 3 workloads, one without a schedule, in ONE device call: every
-    row equals the one-point ``fb_rounds_row`` of its (workload, lease,
-    schedule) bit for bit. The unfaulted workload's rows add only the
+    row equals the one-point call of its (workload, lease, schedule) bit
+    for bit. The unfaulted workload's rows add only the
     fault counters, both 0."""
     stacked, root = _fault_root()
     dispatches = [r for r in spans.RECORDER.records
@@ -81,8 +84,9 @@ def test_stacked_fault_rows_equal_one_point_rows():
     assert len(dispatches) == 1
     for w, (jobs, ws) in enumerate(WORKLOADS):
         for i, p in enumerate(POINTS):
-            one = roundslib.fb_rounds_row(jobs, ws, CAP, p.lease_seconds,
-                                          DAY, faults=SCHEDULES[w])
+            one = run_sweep_workloads([p], [(jobs, ws)], DAY,
+                                      mode="rounds",
+                                      faults=[SCHEDULES[w]])[0][0]
             got = dict(stacked[w][i])
             if SCHEDULES[w] is None:
                 assert got.pop("fault_rounds") == 0
@@ -152,8 +156,9 @@ def test_a_schedule_beside_another_system_raises(point):
 
 
 def test_a_schedule_in_scan_mode_or_misaligned_raises():
-    with pytest.raises(ValueError, match="not 'scan'"):
-        run_sweep_workloads(POINTS, WORKLOADS, DAY, mode="scan",
+    # "scan" names no engine: an unknown mode like any other.
+    with pytest.raises(ValueError, match="unknown mode"):
+        run_sweep_workloads(POINTS, WORKLOADS, DAY, mode=REMOVED_MODE,
                             faults=SCHEDULES)
     with pytest.raises(ValueError, match="must align"):
         run_sweep_workloads(POINTS, WORKLOADS, DAY, mode="rounds",

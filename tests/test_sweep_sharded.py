@@ -1,11 +1,12 @@
 """Multi-device sweep backend: sharded vs single-device equality.
 
-The ``devices`` option of ``run_sweep_workloads`` splits the scan path's
-flattened (point × trace) lane axis across host devices via
-``shard_map`` (repro.sim.scan). Because every lane runs the identical
-per-lane program, the sharded backend must reproduce the single-device
-rows BIT-IDENTICALLY — including when the lane count is not divisible by
-the device count, which exercises the pad-and-drop path. The equality
+The ``devices`` option of ``run_sweep_workloads`` splits the rounds
+path's flattened (point × trace) lane axis across host devices via
+``shard_map`` (``repro.sim.lanes.sharded_grid_map``). Because every
+lane runs the identical per-lane program, the sharded backend must
+reproduce the single-device rows BIT-IDENTICALLY — including when the
+lane count is not divisible by the device count, which exercises the
+pad-and-drop path. The equality
 test runs in a subprocess with two forced XLA host devices (the
 test_distributed.py pattern), so it holds regardless of the machine CI
 lands on.
@@ -99,18 +100,8 @@ def test_sharded_matches_single_device_on_odd_lane_count():
                + [SweepPoint("flb_nub", lb_pbj=B - 12, lb_ws=12)
                   for B in (25, 51, 102)]
                + [SweepPoint("ec2", lease_seconds=3600.0)])
-        single = run_sweep_workloads(pts, wls, T, mode="scan")
-        sharded = run_sweep_workloads(pts, wls, T, mode="scan", devices=2)
-        assert sharded == single, [
-            (w, i, a, b)
-            for w, (ra, rb) in enumerate(zip(single, sharded))
-            for i, (a, b) in enumerate(zip(ra, rb)) if a != b][:3]
-        # The scan rows really took the scan engine on both backends.
-        assert all(r["engine"] == "scan" for row in sharded
-                   for r in row[:-1])
-        # Same bit-identity contract for the event-round engine (its
-        # per-workload invocations shard their 3 point-lanes over the
-        # 2 devices - the odd-lane pad-and-drop path again).
+        # Each policy's 9 lanes shard over the 2 devices: the odd-lane
+        # pad-and-drop path.
         single_r = run_sweep_workloads(pts, wls, T, mode="rounds")
         sharded_r = run_sweep_workloads(pts, wls, T, mode="rounds",
                                         devices=2)
@@ -118,25 +109,9 @@ def test_sharded_matches_single_device_on_odd_lane_count():
             (w, i, a, b)
             for w, (ra, rb) in enumerate(zip(single_r, sharded_r))
             for i, (a, b) in enumerate(zip(ra, rb)) if a != b][:3]
+        # The rows really took the rounds engine on both backends.
         assert all(r["engine"] == "rounds" for row in sharded_r
                    for r in row[:-1])
-        # ...and for the contended-stretch COALESCED variant: its bulk
-        # section adds (K, k) intermediates to the per-lane program,
-        # which must shard exactly like the plain one (this is the only
-        # place the coalesce x shard_map combination is exercised — the
-        # bench gate's sharded leg covers plain rounds only).
-        from repro.sim.sweep import ScanOptions
-        co = ScanOptions(coalesce=8)
-        single_c = run_sweep_workloads(pts, wls, T, mode="rounds",
-                                       scan_options=co)
-        sharded_c = run_sweep_workloads(pts, wls, T, mode="rounds",
-                                        scan_options=co, devices=2)
-        assert sharded_c == single_c, [
-            (w, i, a, b)
-            for w, (ra, rb) in enumerate(zip(single_c, sharded_c))
-            for i, (a, b) in enumerate(zip(ra, rb)) if a != b][:3]
-        assert sum(r.get("coalesced", 0) for row in single_c
-                   for r in row) > 0
         print("OK")
     """)
     assert "OK" in out
@@ -158,7 +133,7 @@ def test_devices_request_beyond_visible_raises():
     with pytest.raises(ValueError,
                        match="xla_force_host_platform_device_count"):
         run_sweep([SweepPoint("fb", capacity=64)], jobs, ws, T,
-                  mode="scan", devices=too_many)
+                  mode="rounds", devices=too_many)
 
 
 def test_devices_one_is_the_plain_single_device_path():
@@ -184,5 +159,5 @@ def test_devices_one_is_the_plain_single_device_path():
           if t < T]
     pts = [SweepPoint("fb", capacity=64),
            SweepPoint("flb_nub", lb_pbj=13, lb_ws=12)]
-    assert run_sweep(pts, jobs, ws, T, mode="scan", devices=1) \
-        == run_sweep(pts, jobs, ws, T, mode="scan")
+    assert run_sweep(pts, jobs, ws, T, mode="rounds", devices=1) \
+        == run_sweep(pts, jobs, ws, T, mode="rounds")

@@ -3,9 +3,8 @@
 The TPU compiler is installed with jax, and it compiles for a v5e
 topology that is described rather than attached. These tests hold the
 programs the chip runs to that compiler at the paper's width: the
-rounds engine for each policy over the 45-evaluation paper pack. They
-also pin the fused Pallas round step's status: Mosaic refuses it, and
-``kernel="pallas"`` on a TPU backend raises instead of interpreting.
+rounds engine for each policy over the 45-evaluation paper pack, and
+FB under failures as the ``paper.faults`` cell stacks it.
 
 The topology is described inside a module fixture, never at import:
 only one process may hold the TPU library, and every test worker
@@ -18,13 +17,11 @@ import os
 import pytest
 
 import jax
-import jax.numpy as jnp
 from jax.sharding import SingleDeviceSharding
 
-from repro.kernels import round_step as rsk
 from repro.sim import rounds as roundslib
 from repro.sim import traces
-from repro.sim.sweep import ScanOptions, SweepPoint, _pack_rounds, run_sweep
+from repro.sim.sweep import ScanOptions, SweepPoint, _pack_rounds
 
 DAY = 24 * 3600.0
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -140,40 +137,3 @@ def test_faulted_fb_program_compiles_for_v5e(one_chip, no_persistent_cache):
     out = compiled.out_info["fb"]
     assert out["fault_rounds"].shape == out["fault_kills"].shape == (3, 3)
     assert compiled.memory_analysis().temp_size_in_bytes < 16 * 2 ** 30
-
-
-def test_mosaic_refuses_the_fused_round_step(one_chip, no_persistent_cache):
-    """Mosaic has no lowering for the round body's prefix sums: the
-    compiled kernel (bypassing ``chunk_step``'s guard) is refused."""
-    from jax.experimental import pallas as pl
-    K = 16
-    spec = roundslib.RoundsSpec(duration=2 * DAY, max_rounds=4096, window=K,
-                                kernel="pallas")
-    f = jnp.float32
-    shapes = [((3, 64 + K), f), ((2, 8), f), ((2, 50), f), ((2,), f),
-              ((rsk.SC_SIZE,), f), ((rsk.WIN_ROWS, K), f)]
-    sc, win = (jax.ShapeDtypeStruct(s, d) for s, d in shapes[-2:])
-    call = pl.pallas_call(rsk._chunk_kernel("fb", spec),
-                          out_shape=[sc, win], interpret=False)
-    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes]
-    with pytest.raises(Exception, match="cumsum"):
-        jax.jit(call).lower(*args).compile()
-
-
-def test_pallas_kernel_is_refused_on_a_tpu_backend(monkeypatch):
-    """With the backend reported as TPU, ``kernel="pallas"`` raises a
-    NotImplementedError that names the refusal — it never falls back to
-    interpret mode."""
-    horizon = DAY
-    jobs = [j for j in traces.nasa_ipsc(seed=0) if j.submit < horizon]
-    ws = [(t, d) for t, d in traces.worldcup98(seed=0, peak_vms=64)
-          if t < horizon]
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    with pytest.raises(NotImplementedError, match="Mosaic"):
-        run_sweep([SweepPoint("fb", capacity=96)], jobs, ws, horizon,
-                  mode="rounds", scan_options=ScanOptions(kernel="pallas",
-                                                          window=40))
-    spec = roundslib.RoundsSpec(duration=horizon, max_rounds=8, window=4,
-                                kernel="pallas")
-    with pytest.raises(NotImplementedError, match="kernel=\"xla\""):
-        rsk.chunk_step(*[jnp.zeros(1)] * 6, policy="fb", spec=spec)
